@@ -134,14 +134,15 @@ def trace_analysis(trials: int = 32) -> None:
         small, concretize(small, INTERPRET,
                           tune(small, INTERPRET,
                                AnalyticRunner(INTERPRET), trials=8,
-                               seed=0).best_schedule))).lower(
+                               seed=0).best_schedule),
+        interpret=True)).lower(
         *[jax.ShapeDtypeStruct(a.shape, a.dtype)
           for a in small.example_inputs()]).as_text())
     lib_ir = 0
     from repro.core.schedule import Schedule
     for name in sp["variant"]:
         p = concretize(small, INTERPRET, Schedule.fixed(variant=name))
-        lib_ir += len(jax.jit(kernels.build(small, p)).lower(
+        lib_ir += len(jax.jit(kernels.build(small, p, interpret=True)).lower(
             *[jax.ShapeDtypeStruct(a.shape, a.dtype)
               for a in small.example_inputs()]).as_text())
     emit("trace/code_size_tuned_bytes", float(tuned_ir),
@@ -1079,6 +1080,14 @@ def main() -> None:
                     help="tuning database path for --report "
                          "(default: $REPRO_TUNING_DB)")
     args = ap.parse_args()
+    import jax
+
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"# device: {dev.platform} {dev.device_kind} x{len(jax.devices())}"
+          f"; compile cache: {cache_dir}")
     print("name,us_per_call,derived")
     if args.report:
         report(args.db)

@@ -24,7 +24,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeSpec
-from repro.core import ContinuousTuner, TrafficLog, TuningDatabase, V5E
+from repro.core import (AnalyticRunner, ContinuousTuner, TrafficLog,
+                        TuningDatabase, V5E)
 from repro.models.model_zoo import build
 from repro.runtime.serve_loop import Server, decode_ops
 
@@ -76,7 +77,9 @@ def main() -> None:
 
         print(f"cold dispatch: {mix(res.dispatch)} "
               f"({traffic.pending(hw.name)} miss shape(s) recorded)")
-        tuner = ContinuousTuner(traffic, hw, database=database,
+        # the analytic model of the chip: this example runs on the CPU
+        tuner = ContinuousTuner(traffic, hw, runner=AnalyticRunner(hw),
+                                database=database,
                                 trials_per_shape=args.tune_trials,
                                 max_shapes_per_cycle=len(serve_ops))
         tuner.tune_once()

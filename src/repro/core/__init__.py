@@ -2,7 +2,8 @@
 
 Public API:
     Workload, Schedule, HardwareConfig / V5E, tune(), TuningDatabase,
-    InterpretRunner / AnalyticRunner, best_schedule()/kernel_params().
+    DeviceRunner (the chip) / InterpretRunner / AnalyticRunner,
+    best_schedule()/kernel_params().
 """
 
 from repro.core.hardware import (HardwareConfig, V5E, V5E_VMEM32, V5E_VMEM64,
@@ -21,8 +22,8 @@ from repro.core.static_analysis import (Diagnostic, SpaceReport, analyze,
                                         lint_space, pruned_program)
 from repro.core.cost_model import (RidgeCostModel, features,
                                    pretrain_from_database)
-from repro.core.runner import (InterpretRunner, AnalyticRunner, run_batch,
-                               xla_latency)
+from repro.core.runner import (AnalyticRunner, DeviceRunner,
+                               InterpretRunner, run_batch, xla_latency)
 from repro.core.measure_pool import MeasurePool, SubprocessRunner
 from repro.core.measure_scheduler import (AdaptiveDepthPolicy,
                                           MeasureScheduler, MeasureTicket,
@@ -53,7 +54,8 @@ __all__ = [
     "tile_candidates", "v1_distinct_configs", "TraceSampler",
     "Diagnostic", "SpaceReport", "analyze", "lint_space", "pruned_program",
     "RidgeCostModel", "features", "pretrain_from_database",
-    "InterpretRunner", "AnalyticRunner", "SubprocessRunner", "MeasurePool",
+    "DeviceRunner", "InterpretRunner", "AnalyticRunner", "SubprocessRunner",
+    "MeasurePool",
     "AdaptiveDepthPolicy", "MeasureScheduler", "MeasureTicket",
     "SerialMeasureQueue",
     "Board", "BoardDied", "BoardFarm", "BoardStats", "Fault", "FarmDead",

@@ -252,6 +252,8 @@ class LocalBoard(Board):
     inside the board — per-candidate failures surface as ``INVALID``
     latencies, and only a board-level failure (no worker can be started)
     raises :class:`BoardDied`. ``respawn`` rebuilds the pool from scratch.
+    Not for the chip: it refuses to start when this process's JAX backend
+    is a TPU (one process holds the chip; use ``DeviceRunner``).
     """
 
     def __init__(self, name: str, hw: HardwareConfig, workers: int = 1,
@@ -259,9 +261,12 @@ class LocalBoard(Board):
                  warmup: int = 1, candidate_timeout_s: float = 60.0,
                  mp_context: str = "spawn",
                  task: Callable[[Any], Any] | None = None):
+        from repro.core import measure_pool as mp_lib
+        from repro.core.runner import refuse_child_measurement_on_tpu
+
+        refuse_child_measurement_on_tpu(f"LocalBoard {name!r}")
         super().__init__(name, hw, capacity=max(1, workers),
                          timeout_s=timeout_s)
-        from repro.core import measure_pool as mp_lib
 
         self.repeats = repeats
         self.warmup = warmup

@@ -28,12 +28,15 @@ class HardwareConfig:
     # Memory system.
     hbm_bandwidth: float  # bytes/s
     hbm_capacity: int  # bytes
-    vmem_capacity: int  # bytes  (bounds the block working set, like VLEN)
+    # bytes (bounds the block working set, like VLEN); every kernel asks the
+    # compiler for all of it (``vmem_limit_bytes``)
+    vmem_capacity: int
     # Interconnect (per-link, one direction).
     ici_bandwidth: float  # bytes/s
-    # Fraction of VMEM a kernel's block working set may occupy. The rest is
-    # headroom for compiler-managed spills, semaphores, and double-buffering
-    # slack the footprint model doesn't count. This is the one authoritative
+    # Fraction of VMEM a kernel's buffers may occupy (the footprint model,
+    # ``space.vmem_footprint``, counts every buffer Pallas allocates,
+    # double-buffering included). The rest is headroom for the compiler's
+    # own temporaries and semaphores. This is the one authoritative
     # bound shared by the dynamic postprocessor (``postproc_vmem_fit``) and
     # the static feasibility analyzer (``core/static_analysis.py``) — tuning
     # it per part (or per compiler release) must move both in lockstep.
@@ -71,7 +74,7 @@ class HardwareConfig:
         return self.vpu_lanes
 
 
-# TPU v5e — the production target (constants fixed by the assignment).
+# TPU v5e — the production target.
 V5E = HardwareConfig(
     name="tpu_v5e",
     peak_flops_bf16=197e12,
@@ -116,5 +119,20 @@ SWEEP = (V5E_VMEM32, V5E_VMEM64, V5E)
 _REGISTRY = {hw.name: hw for hw in (V5E, V5E_VMEM32, V5E_VMEM64, V5E_MXU256, INTERPRET)}
 
 
+# ``device_kind`` as JAX reports it -> the configuration of that part.
+_BY_DEVICE_KIND = {"TPU v5 lite": V5E}
+
+
 def get(name: str) -> HardwareConfig:
     return _REGISTRY[name]
+
+
+def for_device_kind(kind: str) -> HardwareConfig:
+    """The configuration of an attached device. A kind with no entry is an
+    error, never a default: its VMEM size and peaks are unknown."""
+    try:
+        return _BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware configuration for device kind {kind!r} "
+            f"(known: {sorted(_BY_DEVICE_KIND)})") from None

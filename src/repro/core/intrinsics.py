@@ -84,7 +84,8 @@ def gemv_variants(hw: HardwareConfig, dtype: str) -> list[IntrinsicVariant]:
     """(bn, bk) ladder — Algorithm 1's (J, VL).
 
     J = VLEN/32 analogue: output-block rows = one VPU tile of lanes;
-    J = 1 fallback registered as well (paper registers both).
+    J = 1 fallback registered as well (paper registers both); it matches
+    single-row outputs only (see ``variants_for``).
     """
     lane = hw.lane_align(dtype)
     budget = hw.vmem_capacity // 2
@@ -164,7 +165,10 @@ def _variants_for_cached(workload: Workload,
         elif workload.op == "gemv":
             n, k = dims
             bn, bk = v.block
-            ok = bn <= round_up(n, 128) and bk <= round_up(k, 128)
+            # a J=1 row kernel tiles the weight as (bk, 1): the TPU lowers
+            # that only when the row is the whole output (n == 1)
+            ok = (bn <= round_up(n, 128) and bk <= round_up(k, 128)
+                  and (bn > 1 or n == 1))
         elif workload.op == "vmacc":
             r, c = dims
             br, bc = v.block

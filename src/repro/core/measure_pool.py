@@ -44,7 +44,7 @@ from collections import deque
 from typing import Any, Callable, Sequence
 
 from repro.core.hardware import HardwareConfig
-from repro.core.runner import INVALID
+from repro.core.runner import INVALID, refuse_child_measurement_on_tpu
 from repro.core.schedule import Schedule
 from repro.core.workload import Workload
 
@@ -425,6 +425,9 @@ class SubprocessRunner:
     each distinct signature is measured once and its latency fanned out by
     submission position. Off by default — reusing a measured latency for a
     duplicate is a semantic choice on a noisy runner (see ``runner.py``).
+
+    Not for the chip: it refuses to start when this process's JAX backend
+    is a TPU (one process holds the chip; use ``DeviceRunner``).
     """
 
     hw: HardwareConfig
@@ -448,6 +451,7 @@ class SubprocessRunner:
     task: Callable[[Any], Any] = _measure_candidate
 
     def __post_init__(self):
+        refuse_child_measurement_on_tpu("SubprocessRunner")
         self._pool: MeasurePool | None = None
 
     def _ensure_pool(self) -> MeasurePool:

@@ -1,24 +1,30 @@
 """Candidate measurement runners.
 
 The paper measures candidates on two kinds of targets: FPGA-implemented SoCs
-(microTVM) and a real board (TVM runtime), plus QEMU for trace analysis. In
-this CPU-only container the corresponding pair is:
+(microTVM) and a real board (TVM runtime), plus QEMU for trace analysis.
+Here the runners are:
 
+- :class:`DeviceRunner` — compiles the candidate Pallas kernel for the
+  attached TPU, checks its first result against the op's reference, and
+  times it on the device (the real-board analogue). It measures in this
+  process, because one process holds the chip, and it refuses to run
+  anywhere but on a TPU.
 - :class:`InterpretRunner` — builds the candidate Pallas kernel with
-  ``interpret=True`` and measures wall-clock on the host. Real, noisy,
-  hardware-in-the-loop measurement (the FPGA analogue at container scale).
+  ``interpret=True`` and measures wall-clock on the host: the CPU path,
+  whose times say nothing about a TPU.
 - :class:`AnalyticRunner` — deterministic TPU-v5e latency model: a roofline
   over {MXU compute, HBM traffic} with per-grid-step overhead and MXU
-  utilization derating. This is the stand-in for real-TPU measurement and
-  the model behind the §Roofline numbers (the QEMU analogue).
+  utilization derating (the QEMU analogue). Uncalibrated against the chip.
 
-A third runner, :class:`~repro.core.measure_pool.SubprocessRunner`, wraps
+A further runner, :class:`~repro.core.measure_pool.SubprocessRunner`, wraps
 the interpret path in a persistent worker-process pool with a true
 per-candidate timeout kill — the isolation a wedged (not merely crashing)
-build needs; see ``measure_pool.py``. A fourth,
+build needs; see ``measure_pool.py``. Another,
 :class:`~repro.core.board_farm.BoardFarm`, shards each batch across
 several measurement boards (the paper's RPC board farm) with fault-tolerant
-work-stealing dispatch; see ``board_farm.py``.
+work-stealing dispatch; see ``board_farm.py``. Both measure in child
+processes, so both refuse to start in a process whose JAX backend is a TPU
+(:func:`refuse_child_measurement_on_tpu`).
 
 All satisfy the same ``Runner`` protocol; ``tuner.tune`` is agnostic. The
 ``overlap_capable`` class attribute tells the tuner whether measurement on
@@ -101,7 +107,7 @@ from typing import Callable, Protocol, Sequence
 import numpy as np
 
 from repro.core import space as space_lib
-from repro.core.hardware import HardwareConfig
+from repro.core.hardware import HardwareConfig, for_device_kind
 from repro.core.schedule import Schedule
 from repro.core.workload import Workload
 
@@ -191,21 +197,12 @@ class InterpretRunner:
             return None
         return fn
 
-    def _measure(self, fn: Callable, inputs) -> float:
-        for _ in range(self.warmup):
-            fn(*inputs).block_until_ready()
-        best = INVALID
-        for _ in range(self.repeats):
-            t0 = time.perf_counter()
-            fn(*inputs).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        return best
-
     def run(self, workload: Workload, schedule: Schedule) -> float:
         fn = self._prepare(workload, schedule)
         if fn is None:
             return INVALID
-        return self._measure(fn, workload.example_inputs())
+        return _best_time(fn, workload.example_inputs(), self.warmup,
+                          self.repeats)
 
     def run_batch(self, workload: Workload,
                   schedules: Sequence[Schedule]) -> list[float]:
@@ -271,7 +268,8 @@ class InterpretRunner:
             ok = finished[i].wait(timeout=max(0.0,
                                               deadline - time.monotonic()))
             if ok and results[i] is not None:
-                latencies[i] = self._measure(results[i], inputs)
+                latencies[i] = _best_time(results[i], inputs, self.warmup,
+                                          self.repeats)
         for i in range(n):
             if rep[i] != i:
                 latencies[i] = latencies[rep[i]]
@@ -346,18 +344,246 @@ class AnalyticRunner:
         return max(t_compute, t_memory) + t_overhead
 
 
-def xla_latency(workload: Workload, repeats: int = 3) -> float:
-    """Measure the XLA default lowering of the op (the paper's
-    GCC/LLVM-autovectorization baseline) with wall-clock on this host."""
-    from repro import kernels
+def place_inputs(workload: Workload) -> tuple:
+    """``workload.example_inputs`` on JAX's default device, in the compute
+    dtype: what a timed call is given, so no timing includes a copy from
+    the host or a dtype conversion."""
+    import jax
+    import jax.numpy as jnp
 
-    fn = kernels.xla_baseline(workload)
-    inputs = workload.example_inputs()
-    out = fn(*inputs)
-    out.block_until_ready()
+    return tuple(jax.device_put(jnp.asarray(a, dtype))
+                 for a, (_, dtype) in zip(workload.example_inputs(),
+                                          workload.input_specs()))
+
+
+def _best_time(fn: Callable, inputs, warmup: int, repeats: int) -> float:
+    """Best of ``repeats`` host-clock timings of ``fn(*inputs)``, each ended
+    by ``block_until_ready``, after ``warmup`` untimed calls."""
+    for _ in range(warmup):
+        fn(*inputs).block_until_ready()
     best = INVALID
     for _ in range(repeats):
         t0 = time.perf_counter()
         fn(*inputs).block_until_ready()
         best = min(best, time.perf_counter() - t0)
     return best
+
+
+def xla_latency(workload: Workload, repeats: int = 3) -> float:
+    """Measure the XLA default lowering of the op (the paper's
+    GCC/LLVM-autovectorization baseline) with wall-clock on this host, on
+    inputs placed on the device beforehand (see :func:`place_inputs`)."""
+    from repro import kernels
+
+    return _best_time(kernels.xla_baseline(workload), place_inputs(workload),
+                      warmup=1, repeats=repeats)
+
+
+# ----------------------------------------------------------- the chip path --
+
+def default_backend() -> str:
+    """JAX's default backend in this process (a seam tests steer)."""
+    import jax
+
+    return jax.default_backend()
+
+
+def refuse_child_measurement_on_tpu(what: str) -> None:
+    """Runners that measure in child processes cannot share a TPU with this
+    process: it holds the chip, and a child that needs it fails or hangs."""
+    if default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} measures in child processes, but this process's JAX "
+            "backend is a TPU and one process holds the chip: measure in "
+            "process with DeviceRunner instead")
+
+
+def attached_device():
+    """The device a :class:`DeviceRunner` measures on: JAX's first (a seam
+    tests steer)."""
+    import jax
+
+    return jax.devices()[0]
+
+
+# Largest |kernel - reference| over the largest |reference| a compiled
+# kernel may show, by the workload's input dtype. The reference runs on the
+# same device inputs at "highest" matmul precision. Integer families
+# (qmatmul, int8 matmul) must match exactly.
+TOLERANCE = {
+    # bf16 results: kernel and reference round nearly the same f32 value to
+    # bf16, which can land one bf16 ulp apart — at most 2^-7 of the largest
+    # magnitude. Two such ulps of slack.
+    "bfloat16": 2.0 ** -6,
+    # f32 results: only the summation order of the f32 accumulation
+    # differs (~1e-6 of the largest magnitude at K = 4096). A contraction
+    # the compiler ran as one bf16 pass (~2^-9 per product) fails it.
+    "float32": 2.0 ** -10,
+}
+
+# Compile refusals are counted by reason; a wrong first result as "wrong".
+FAILURE_REASONS = ("vmem", "alignment", "other", "wrong")
+
+
+def refusal_reason(exc: BaseException) -> str:
+    """Classify a compiler refusal: out of VMEM, a block shape the TPU
+    cannot tile, or anything else."""
+    msg = str(exc)
+    if "divisible" in msg or "align" in msg.lower():
+        return "alignment"
+    if "vmem" in msg.lower():
+        return "vmem"
+    return "other"
+
+
+def _is_integer(dtype) -> bool:
+    return np.issubdtype(np.dtype(dtype), np.integer)
+
+
+class DeviceRunner:
+    """Compiled Pallas kernels measured on the attached TPU, in process.
+
+    - Requires that JAX's first device is a TPU, and derives the hardware
+      configuration from its ``device_kind``; anything else raises. It
+      never falls back to the CPU, to interpret mode or to a default part.
+    - Inputs are made on the device once per workload (``example_inputs``
+      moved there in the compute dtype), so timed calls see device arrays
+      only.
+    - Each distinct kernel (``KernelParams.signature()``) is compiled
+      ahead of time, once. A compiler refusal is counted by reason
+      (:func:`refusal_reason`) and the candidate is ``INVALID``.
+    - The first result of each kernel is checked against the op's
+      reference at ``TOLERANCE``; a wrong kernel is counted as ``"wrong"``,
+      is ``INVALID`` and is never timed.
+    - A valid kernel is warmed up (``WARMUP`` calls) and timed with
+      ``block_until_ready``; the best of ``REPEATS`` is its latency.
+
+    ``failures(workload)`` and ``max_error(workload)`` report per
+    workload; the tuner copies them onto its results.
+    """
+
+    WARMUP = 2
+    REPEATS = 5
+    name = "device"
+    # One device: timing stays in the calling thread, in submission order.
+    overlap_capable = False
+    max_inflight = 1
+
+    def __init__(self):
+        device = attached_device()
+        if device.platform != "tpu":
+            raise RuntimeError(
+                f"DeviceRunner needs a TPU, but JAX's first device is "
+                f"{device.platform} ({device.device_kind})")
+        self.device = device
+        self.hw = for_device_kind(device.device_kind)
+        self._inputs: dict[str, tuple] = {}
+        self._refs: dict[str, object] = {}
+        # signature -> compiled kernel, or None once refused or wrong
+        self._compiled: dict[tuple, Callable | None] = {}
+        self._failures: dict[str, dict[str, int]] = {}
+        self._max_error: dict[str, float] = {}
+        # reason -> the first line of the first refusal of that reason
+        self.first_refusal: dict[str, str] = {}
+
+    # ---- per-workload state ------------------------------------------------
+    def inputs(self, workload: Workload) -> tuple:
+        key = workload.key()
+        if key not in self._inputs:
+            self._inputs[key] = place_inputs(workload)
+        return self._inputs[key]
+
+    def reference_output(self, workload: Workload):
+        import jax
+        from repro import kernels
+
+        key = workload.key()
+        if key not in self._refs:
+            with jax.default_matmul_precision("highest"):
+                self._refs[key] = jax.jit(kernels.reference(workload))(
+                    *self.inputs(workload))
+        return self._refs[key]
+
+    def failures(self, workload: Workload) -> dict[str, int]:
+        """Compile refusals by reason, plus wrong results, of this
+        workload's kernels so far."""
+        counts = self._failures.get(workload.key(), {})
+        return {r: counts.get(r, 0) for r in FAILURE_REASONS}
+
+    def max_error(self, workload: Workload) -> float:
+        """Largest normalized error (see ``TOLERANCE``) among this
+        workload's accepted kernels; NaN before any was checked."""
+        return self._max_error.get(workload.key(), float("nan"))
+
+    def clear(self) -> None:
+        """Drop the device inputs, references and compiled kernels (the
+        counters stay)."""
+        self._inputs.clear()
+        self._refs.clear()
+        self._compiled.clear()
+
+    # ---- measurement -------------------------------------------------------
+    def _count(self, workload: Workload, reason: str) -> None:
+        counts = self._failures.setdefault(workload.key(), {})
+        counts[reason] = counts.get(reason, 0) + 1
+
+    def error(self, workload: Workload, out) -> float:
+        """Normalized error of ``out`` against the reference: max |diff| for
+        integer results, max |diff| / max |reference| for float ones;
+        infinite on a shape mismatch."""
+        import jax.numpy as jnp
+
+        ref = self.reference_output(workload)
+        if out.shape != ref.shape:
+            return INVALID
+        diff = jnp.max(jnp.abs(out.astype(jnp.float32)
+                               - ref.astype(jnp.float32)))
+        if _is_integer(ref.dtype):
+            return float(diff)
+        return float(diff / jnp.max(jnp.abs(ref.astype(jnp.float32))))
+
+    def _prepare(self, workload: Workload,
+                 schedule: Schedule) -> Callable | None:
+        """Compile and check one candidate; ``None`` if it is invalid,
+        refused or wrong."""
+        from repro import kernels
+
+        params = space_lib.concretize(workload, self.hw, schedule)
+        if not params.valid:
+            return None
+        sig = params.signature()
+        if sig in self._compiled:
+            return self._compiled[sig]
+        inputs = self.inputs(workload)
+        self._compiled[sig] = None
+        try:
+            fn = kernels.build(workload, params, interpret=False)
+            compiled = fn.lower(*inputs).compile()
+        except Exception as exc:  # a refusal is a result, and it is counted
+            reason = refusal_reason(exc)
+            self._count(workload, reason)
+            first_line = (str(exc).splitlines() or [""])[0]
+            self.first_refusal.setdefault(
+                reason, f"{type(exc).__name__}: {first_line}")
+            return None
+        err = self.error(workload, compiled(*inputs))
+        tol = 0.0 if _is_integer(self.reference_output(workload).dtype) \
+            else TOLERANCE[workload.dtype]
+        if not err <= tol:
+            self._count(workload, "wrong")
+            return None
+        key = workload.key()
+        self._max_error[key] = max(self._max_error.get(key, 0.0), err)
+        self._compiled[sig] = compiled
+        return compiled
+
+    def run(self, workload: Workload, schedule: Schedule) -> float:
+        fn = self._prepare(workload, schedule)
+        if fn is None:
+            return INVALID
+        return _best_time(fn, self.inputs(workload), self.WARMUP,
+                          self.REPEATS)
+
+    def run_batch(self, workload: Workload,
+                  schedules: Sequence[Schedule]) -> list[float]:
+        return [self.run(workload, s) for s in schedules]

@@ -272,6 +272,10 @@ class SessionResult:
     # trials settled from the database's cross-session measured-latency
     # memo across all workloads (reuse_measured=True only)
     measured_memo: int = 0
+    # compile refusals by reason plus wrong results, summed over every
+    # workload's candidates and library baselines, when the runner reports
+    # them (DeviceRunner.failures); None otherwise
+    failures: dict | None = None
 
     @property
     def overlap_fraction(self) -> float:
@@ -332,6 +336,7 @@ class SessionResult:
             "preemptions": self.preemptions,
             "build_cache": self.build_cache,
             "measured_memo": self.measured_memo,
+            "failures": self.failures,
             "workloads": [{
                 "key": r.workload.key(),
                 "count": r.count,
@@ -639,6 +644,13 @@ class TuningSession:
                    in enumerate(zip(unique, results, baselines))]
 
         measure_s = sum(r.measure_time_s for r in results)
+        failures_fn = getattr(self.runner, "failures", None)
+        failures = None
+        if callable(failures_fn):  # after the baselines: they count too
+            failures = {}
+            for _, wl in unique:
+                for reason, n in failures_fn(wl).items():
+                    failures[reason] = failures.get(reason, 0) + n
         summary_fn = getattr(self.runner, "farm_summary", None)
         board_stats = summary_fn() if callable(summary_fn) else None
         result = SessionResult(
@@ -657,7 +669,8 @@ class TuningSession:
             reallocated_trials=extras.get("reallocated_trials", 0),
             preemptions=(board_stats or {}).get("preemptions", 0),
             build_cache=stats_delta(build_cache_stats(), bc_before),
-            measured_memo=sum(r.measured_memo for r in results))
+            measured_memo=sum(r.measured_memo for r in results),
+            failures=failures)
         if self.database is not None:
             self.database.add_session(result.summary())
             if self.database.path:

@@ -108,6 +108,9 @@ class KernelParams:
     vmem_bytes: int
     valid: bool
     why_invalid: str = ""
+    # scoped-VMEM limit the kernel asks the compiler for
+    # (``vmem_limit_bytes``): the capacity of the part it was concretized for
+    vmem_limit: int = 0
 
     def signature(self) -> tuple:
         """Canonical content key of this concrete kernel instantiation.
@@ -125,7 +128,8 @@ class KernelParams:
         on the hardware beyond the params — e.g. the ``concretize`` memo
         — add ``hw.name`` to their own keys."""
         return (self.op, self.dims, self.padded_dims, self.block, self.grid,
-                self.order, self.accumulate, self.dtype, self.out_dtype)
+                self.order, self.accumulate, self.dtype, self.out_dtype,
+                self.vmem_limit)
 
 
 # =============================================================================
@@ -147,10 +151,12 @@ def postproc_block_alignment(workload: Workload, hw: HardwareConfig,
         bn, bk = params.block
         if bk % lane:
             return f"k-block {bk} not a lane multiple ({lane})"
-        if bn != 1 and bn % lane:
-            # the kernel's (1, bn) output tile: full lanes or the J=1 row
-            # form — nothing ragged in between (see gemv supports_block_shape)
-            return f"n-block {bn} neither 1 nor a lane multiple ({lane})"
+        if bn % lane and bn != params.padded_dims[0]:
+            # the kernel's (bk, bn) weight and (1, bn) output tiles: full
+            # lanes, or the whole output row (the J=1 form at n == 1) —
+            # the TPU lowers nothing ragged in between
+            return (f"n-block {bn} neither a lane multiple ({lane}) nor "
+                    f"the whole output")
     elif params.op == "vmacc":
         br, bc = params.block
         if br % sub:
@@ -168,11 +174,60 @@ def postproc_nonempty_grid(workload: Workload, hw: HardwareConfig,
     return ""
 
 
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of one (rows, cols) buffer as the TPU lays it out: rows
+    padded to the dtype's sublane tile (8 rows of 32-bit words, packed for
+    narrower types), columns padded to the 128 lanes."""
+    return (round_up(rows, 8 * max(1, 4 // itemsize)) * round_up(cols, 128)
+            * itemsize)
+
+
+def vmem_footprint(op: str, block: tuple[int, ...],
+                   padded_dims: tuple[int, ...], accumulate: bool,
+                   ib: int) -> int:
+    """VMEM bytes the kernel of one schedule allocates, as the
+    ``pallas_call`` of each family in ``repro.kernels`` declares them: two
+    buffers for every pipelined (``BlockSpec``) operand — Pallas
+    double-buffers them so the next block's copy overlaps this block's
+    compute — plus the kernel's scratch. ``ib`` is the input itemsize.
+    Monotone nondecreasing in every block dimension, and the store-heavy
+    form (``accumulate=False``) never allocates more than the accumulating
+    one — the static analyzer's floors rely on both."""
+    if op in ("matmul", "qmatmul"):
+        bm, bn, bk = block
+        ins = _tile_bytes(bm, bk, ib) + _tile_bytes(bk, bn, ib)
+        acc = _tile_bytes(bm, bn, 4)  # f32/int32 accumulator or partials
+        if op == "qmatmul":  # int8 output + int32 bias row; scale in SMEM
+            return (2 * (ins + _tile_bytes(bm, bn, 1) + _tile_bytes(1, bn, 4))
+                    + acc)
+        if accumulate:  # the output block is in the accumulator dtype
+            return 2 * (ins + acc) + acc
+        return 2 * ins + acc  # partials live in HBM; one staging block
+    if op == "gemv":
+        bn, bk = block
+        ins = _tile_bytes(1, bk, ib) + _tile_bytes(bk, bn, ib)
+        row = _tile_bytes(1, bn, 4)
+        return 2 * (ins + (row if accumulate else 0)) + row
+    if op == "vmacc":
+        br, bc = block
+        return 2 * 4 * _tile_bytes(br, bc, ib)  # a, b, c and the output
+    if op == "attention":
+        bq, bkv = block
+        pd = padded_dims[-1]
+        blocks = 2 * _tile_bytes(bq, pd, ib) + 2 * _tile_bytes(bkv, pd, ib)
+        # q, o, k, v blocks; f32 acc plus the running max and sum
+        return 2 * blocks + _tile_bytes(bq, pd, 4) + 2 * _tile_bytes(bq, 128, 4)
+    raise ValueError(f"unknown op {op}")
+
+
 def postproc_vmem_fit(workload: Workload, hw: HardwareConfig,
                       params: KernelParams) -> str:
     # The headroom-derated capacity lives on the hardware config
     # (``HardwareConfig.vmem_headroom``) so this dynamic check and the
-    # static analyzer's interval-domain bound can never drift apart.
+    # static analyzer's interval-domain bound can never drift apart. The
+    # footprint counts what Pallas allocates (``vmem_footprint``) and the
+    # kernels ask for the whole capacity, so the headroom is what the
+    # compiler keeps for its own temporaries.
     if params.vmem_bytes > hw.vmem_budget:
         return (f"vmem footprint {params.vmem_bytes} exceeds "
                 f"{hw.vmem_headroom:.0%} of {hw.vmem_capacity}")
@@ -872,7 +927,6 @@ def _concretize(workload: Workload, hw: HardwareConfig, schedule: Schedule,
     """The uncached concretization body (see :func:`concretize`)."""
     op, dims = workload.op, workload.dims
     ib = dtype_bytes(workload.dtype)
-    ob = dtype_bytes(workload.out_dtype)
     lane = hw.lane_align(workload.dtype)
     sub = hw.sublane_align(workload.dtype)
     try:
@@ -904,11 +958,10 @@ def _concretize(workload: Workload, hw: HardwareConfig, schedule: Schedule,
         else:
             grid = (grid_mn[0], grid_mn[1], pk // bk)
         acc = bool(schedule.get("accumulate", True))
-        acc_bytes = bm * bn * 4  # f32 accumulator
-        vmem = bm * bk * ib + bk * bn * ib + bm * bn * ob + acc_bytes
         params = KernelParams(op, dims, (pm, pn, pk), (bm, bn, bk), grid,
                               order, acc, workload.dtype, workload.out_dtype,
-                              vmem, True)
+                              vmem_footprint(op, (bm, bn, bk), (), acc, ib),
+                              True)
     elif op == "gemv":
         n, k = dims
         if schedule.get("bn") is not None:  # v2 program trace: bn split
@@ -924,9 +977,9 @@ def _concretize(workload: Workload, hw: HardwareConfig, schedule: Schedule,
         pn, pk = round_up(n, bn), round_up(k, bk)
         grid = (pn // bn, pk // bk)
         acc = bool(schedule.get("accumulate", True))
-        vmem = bk * ib + bk * bn * ib + bn * ob + bn * 4
         params = KernelParams(op, dims, (pn, pk), (bn, bk), grid, "nk", acc,
-                              workload.dtype, workload.out_dtype, vmem, True)
+                              workload.dtype, workload.out_dtype,
+                              vmem_footprint(op, (bn, bk), (), acc, ib), True)
     elif op == "vmacc":
         r, c = dims
         if schedule.get("br") is not None:  # v2 program trace
@@ -939,9 +992,9 @@ def _concretize(workload: Workload, hw: HardwareConfig, schedule: Schedule,
             bc = _scaled(base[1], 1.0, lane, c)
         pr, pc = round_up(r, br), round_up(c, bc)
         grid = (pr // br, pc // bc)
-        vmem = 4 * br * bc * max(ib, ob)
         params = KernelParams(op, dims, (pr, pc), (br, bc), grid, "rc", True,
-                              workload.dtype, workload.out_dtype, vmem, True)
+                              workload.dtype, workload.out_dtype,
+                              vmem_footprint(op, (br, bc), (), True, ib), True)
     elif op == "attention":
         b, hq, hkv, ql, kl, d = dims
         bq, bkv = base
@@ -950,16 +1003,16 @@ def _concretize(workload: Workload, hw: HardwareConfig, schedule: Schedule,
         pq, pkv = round_up(ql, bq), round_up(kl, bkv)
         pd = round_up(d, lane)
         grid = (b * hq, pq // bq, pkv // bkv)
-        # live blocks: q, k, v, o(f32), running m/l, s (bq x bkv f32)
-        vmem = (bq * pd * ib + 2 * bkv * pd * ib + bq * pd * 4
-                + 2 * bq * 128 * 4 + bq * bkv * 4)
+        padded = (b, hq, hkv, pq, pkv, pd)
         order = "qk_causal" if "causal" in workload.tags else "qk"
-        params = KernelParams(op, dims, (b, hq, hkv, pq, pkv, pd), (bq, bkv),
-                              grid, order, True, workload.dtype,
-                              workload.out_dtype, vmem, True)
+        params = KernelParams(op, dims, padded, (bq, bkv), grid, order, True,
+                              workload.dtype, workload.out_dtype,
+                              vmem_footprint(op, (bq, bkv), padded, True, ib),
+                              True)
     else:
         raise ValueError(f"unknown op {op}")
 
+    params = dataclasses.replace(params, vmem_limit=hw.vmem_capacity)
     return apply_postprocessors(workload, hw, params, postprocessors)
 
 

@@ -189,35 +189,35 @@ def _variant_vmem_floor(workload: Workload, hw: HardwareConfig,
     """Provable lower bound on the VMEM footprint of any completion that
     chose ``variant``, or None when no sound bound is known for this op.
 
-    The tile-split candidate sets are finite divisor sets; the footprint is
-    monotone nondecreasing in every block dimension, so evaluating it at
-    each dimension's domain minimum bounds every completion from below.
-    Only sound for the registered ``space_for`` program shapes (matmul's
-    splits depend on the variant alone, so the bound is exact; gemv/vmacc
-    later splits condition on earlier ones, so their lower bound uses the
-    generator's hard floor — bn >= 1, bc >= lane — and stays sound)."""
+    The tile-split candidate sets are finite divisor sets; the footprint
+    (``space.vmem_footprint``) is monotone nondecreasing in every block
+    dimension and never larger in the store-heavy form than in the
+    accumulating one, so evaluating it at each dimension's domain minimum
+    with ``accumulate=False`` bounds every completion from below. Only
+    sound for the registered ``space_for`` program shapes (matmul's splits
+    depend on the variant alone; gemv/vmacc later splits condition on
+    earlier ones, so their bound uses the generator's hard floor — bn >= 1,
+    bc >= lane — and stays sound)."""
     op = workload.op
     ib = dtype_bytes(workload.dtype)
-    ob = dtype_bytes(workload.out_dtype)
     lane = hw.lane_align(workload.dtype)
     ctx = {"variant": variant}
     try:
         if op in ("matmul", "qmatmul"):
-            bm = min(program.candidates("bm", ctx))
-            bn = min(program.candidates("bn", ctx))
-            bk = min(program.candidates("bk", ctx))
-            return bm * bk * ib + bk * bn * ib + bm * bn * ob + 4 * bm * bn
-        if op == "gemv":
-            bk = min(program.candidates("bk", ctx))
-            bn = 1  # the J=1 row form is the generator's hard floor
-            return bk * ib + bk * bn * ib + bn * ob + 4 * bn
-        if op == "vmacc":
-            br = min(program.candidates("br", ctx))
-            bc = lane  # bc candidates are lane multiples (divisor domain)
-            return 4 * br * bc * max(ib, ob)
+            block = (min(program.candidates("bm", ctx)),
+                     min(program.candidates("bn", ctx)),
+                     min(program.candidates("bk", ctx)))
+        elif op == "gemv":
+            # the J=1 row form is the generator's hard floor
+            block = (1, min(program.candidates("bk", ctx)))
+        elif op == "vmacc":
+            # bc candidates are lane multiples (divisor domain)
+            block = (min(program.candidates("br", ctx)), lane)
+        else:
+            return None
     except (KeyError, ValueError):
         return None
-    return None
+    return space_lib.vmem_footprint(op, block, (), False, ib)
 
 
 def _vmem_dead_variants(workload: Workload, hw: HardwareConfig,

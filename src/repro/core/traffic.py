@@ -15,10 +15,11 @@ offline ("Closer the Gap", PAPERS.md). This module closes the loop:
 
 - :class:`ContinuousTuner` — drains the log on a budget (hottest shapes
   first, hit count weighting the session's trial split), runs them through
-  the existing :class:`~repro.core.session.TuningSession` on whatever
-  runner is attached (the analytic model, an interpret runner, or a
-  :class:`~repro.core.board_farm.BoardFarm` — measurement happens off the
-  serving thread), and persists results via ``TuningDatabase.save``. A
+  the existing :class:`~repro.core.session.TuningSession` on the runner it
+  is given — by default a :class:`~repro.core.runner.DeviceRunner` on a TPU,
+  and off the TPU whatever the caller passes (the analytic model, an
+  interpret runner); measurement happens off the serving thread — and
+  persists results via ``TuningDatabase.save``. A
   server dispatching through ``global_database()`` then hot-swaps to the
   new artifact on the next lookup (mtime/appearance detection in
   ``core/database.py``) — no restart, no ``reset_global_database()`` call.
@@ -217,9 +218,21 @@ class ContinuousTuner:
         self._thread: threading.Thread | None = None
 
     def _ensure_runner(self):
+        """The runner given, else a :class:`DeviceRunner` when this process
+        runs on a TPU. Never a model by default: tuning against the
+        analytic runner is asked for by passing one."""
         if self.runner is None:
-            from repro.core.runner import AnalyticRunner  # lazy: cycles
-            self.runner = AnalyticRunner(self.hw)
+            from repro.core import runner as runner_lib  # lazy: cycles
+
+            if runner_lib.default_backend() != "tpu":
+                raise ValueError(
+                    "ContinuousTuner needs a runner off the TPU: pass one "
+                    "(e.g. AnalyticRunner(hw) for a model of the chip)")
+            self.runner = runner_lib.DeviceRunner()
+        measured = getattr(self.runner, "hw", self.hw)
+        if measured.name != self.hw.name:
+            raise ValueError(f"runner measures {measured.name}, "
+                             f"the tuner tunes for {self.hw.name}")
         return self.runner
 
     # ---- one synchronous cycle ---------------------------------------------
@@ -228,6 +241,7 @@ class ContinuousTuner:
         the :class:`SessionResult`, or None when nothing was pending."""
         from repro.core.session import TuningSession  # lazy: import cycle
 
+        runner = self._ensure_runner()  # first, so a failure drains nothing
         entries = self.traffic.drain(
             max_shapes if max_shapes is not None else
             self.max_shapes_per_cycle, hw_name=self.hw.name)
@@ -236,7 +250,7 @@ class ContinuousTuner:
         # hit counts become op multiplicities: the session splits its trial
         # budget by count * flops, so observed demand steers the search
         ops = [(entry.hits, entry.workload) for entry in entries]
-        session = TuningSession(self.hw, self._ensure_runner(),
+        session = TuningSession(self.hw, runner,
                                 database=self.database, log=self.log,
                                 **self.session_kwargs)
         result = session.tune_model(

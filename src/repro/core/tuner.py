@@ -130,6 +130,10 @@ class TuneResult:
     # trials settled from the database's cross-session measured-latency
     # memo instead of being re-measured (reuse_measured=True only)
     measured_memo: int = 0
+    # compile refusals by reason plus wrong results ("vmem", "alignment",
+    # "other", "wrong") of this workload's candidates, when the runner
+    # reports them (DeviceRunner.failures); None otherwise
+    failures: dict | None = None
 
     @property
     def mean_proposal_entropy(self) -> float:
@@ -495,6 +499,7 @@ class TuneDriver:
         if self._in_flight:
             raise RuntimeError("finish() with batches still in flight")
         summary = getattr(self.runner, "farm_summary", None)
+        failures = getattr(self.runner, "failures", None)
         # authoritative wall-time span: first propose() -> last reconcile()
         # (zero if the driver never ran — construction time is not activity)
         if self.t_start is None or self._t_last is None:
@@ -523,7 +528,9 @@ class TuneDriver:
             budget_granted=self.budget_granted,
             build_cache=stats_delta(build_cache_stats(),
                                     self._build_cache_before),
-            measured_memo=self.measured_memo)
+            measured_memo=self.measured_memo,
+            failures=(failures(self.workload) if callable(failures)
+                      else None))
 
 
 def timed_run_batch(runner: Runner, driver: TuneDriver,

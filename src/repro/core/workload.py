@@ -109,8 +109,31 @@ class Workload:
         return self.flops() / max(self.min_bytes(), 1.0)
 
     # ---- instantiation helpers ---------------------------------------------
+    def input_specs(self) -> tuple[tuple[tuple[int, ...], str], ...]:
+        """``(shape, dtype)`` of each kernel input, in call order, with
+        float inputs in the compute dtype (``self.dtype``)."""
+        if self.op == "matmul":
+            m, n, k = self.dims
+            return ((m, k), self.dtype), ((k, n), self.dtype)
+        if self.op == "qmatmul":
+            m, n, k = self.dims
+            return ((m, k), "int8"), ((k, n), "int8"), ((n,), "int32")
+        if self.op == "gemv":
+            n, k = self.dims
+            return ((1, k), self.dtype), ((k, n), self.dtype)
+        if self.op == "vmacc":
+            r, c = self.dims
+            return (((r, c), self.dtype),) * 3
+        if self.op == "attention":
+            b, hq, hkv, ql, kl, d = self.dims
+            return (((b, hq, ql, d), self.dtype),
+                    ((b, hkv, kl, d), self.dtype),
+                    ((b, hkv, kl, d), self.dtype))
+        raise ValueError(f"unknown op {self.op}")
+
     def example_inputs(self, seed: int = 0) -> tuple[np.ndarray, ...]:
-        """Concrete numpy inputs for measurement / correctness checks."""
+        """Concrete numpy inputs for measurement / correctness checks on the
+        host (bfloat16 inputs are made as float32: numpy has no bfloat16)."""
         rng = np.random.default_rng(seed)
 
         def rand(shape, dtype):
@@ -121,26 +144,7 @@ class Workload:
             return (rng.standard_normal(shape) * 0.5).astype(
                 "float32" if dtype == "bfloat16" else dtype)
 
-        if self.op == "matmul":
-            m, n, k = self.dims
-            return rand((m, k), self.dtype), rand((k, n), self.dtype)
-        if self.op == "qmatmul":
-            m, n, k = self.dims
-            return (rand((m, k), "int8"), rand((k, n), "int8"),
-                    rand((n,), "int32"))
-        if self.op == "gemv":
-            n, k = self.dims
-            return rand((1, k), self.dtype), rand((k, n), self.dtype)
-        if self.op == "vmacc":
-            r, c = self.dims
-            return (rand((r, c), self.dtype), rand((r, c), self.dtype),
-                    rand((r, c), self.dtype))
-        if self.op == "attention":
-            b, hq, hkv, ql, kl, d = self.dims
-            return (rand((b, hq, ql, d), self.dtype),
-                    rand((b, hkv, kl, d), self.dtype),
-                    rand((b, hkv, kl, d), self.dtype))
-        raise ValueError(f"unknown op {self.op}")
+        return tuple(rand(shape, dtype) for shape, dtype in self.input_specs())
 
 
 def matmul(m: int, n: int, k: int, dtype: str = "float32") -> Workload:

@@ -51,13 +51,16 @@ def _build_uncached(params: KernelParams, interpret: bool):
     raise ValueError(f"no kernel registered for op {params.op}")
 
 
-def build(workload: Workload, params: KernelParams, interpret: bool = True,
+def build(workload: Workload, params: KernelParams, *, interpret,
           cache: BuildCache | bool | None = None):
     """Concrete schedule -> jitted callable over ``workload.example_inputs``.
 
-    Served from the process-wide build cache by default (see the module
-    docstring); ``cache=False`` bypasses it, an explicit
-    :class:`BuildCache` replaces it."""
+    ``interpret`` has no default: False compiles for the TPU, True runs the
+    Pallas interpreter on any backend (see
+    :func:`~repro.kernels.matmul.kernel.matmul_pallas`). Served from the
+    process-wide build cache by default (see the module docstring);
+    ``cache=False`` bypasses it, an explicit :class:`BuildCache` replaces
+    it."""
     if cache is False:
         return _build_uncached(params, interpret)
     bc = cache if isinstance(cache, BuildCache) else global_build_cache()

@@ -18,6 +18,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.space import KernelParams
+from repro.kernels.matmul.kernel import compiler_params
 
 NEG_INF = -1e30
 
@@ -73,7 +74,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention_pallas(q, k, v, params: KernelParams,
-                           interpret: bool = True):
+                           interpret=True):
     """q (BH, pq, pd); k, v (BHkv, pkv, pd) -> (BH, pq, pd).
 
     ``params.padded_dims = (b, hq, hkv, pq, pkv, d_padded)``; the true KV
@@ -104,5 +105,6 @@ def flash_attention_pallas(q, k, v, params: KernelParams,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
+        compiler_params=compiler_params(params),
         interpret=interpret,
     )(q, k, v)

@@ -17,9 +17,11 @@ def supports_block_shape(bn: int, bk: int, lane: int) -> bool:
     the padded extents exactly. That lowers for any positive ``bk`` that is
     a lane multiple and any ``bn`` that is either a lane multiple (a full
     output tile per step) or exactly 1 (the paper's J=1 fallback row
-    kernel). Ragged ``bn`` between 1 and a lane would leave a partially
-    masked last-dim store the kernel does not implement — the design-space
-    program consults this before offering a ``bn`` split candidate.
+    kernel, which the TPU compiles only when the output is that one row:
+    the alignment postprocessor enforces it). Ragged ``bn`` between 1 and
+    a lane would leave a partially masked last-dim store the kernel does
+    not implement — the design-space program consults this before offering
+    a ``bn`` split candidate.
     """
     if bn < 1 or bk < 1:
         return False

@@ -6,8 +6,16 @@ instructions). The TPU translation: a f32 accumulator living in VMEM scratch
 across the K-grid, with the HBM store issued only on the last K step
 (``accumulate=True``). The contrasting store-heavy schedule (muRISCV-NN-like,
 and what a naive XLA tiling does when K doesn't fit) makes K the outer grid
-dimension so partial sums round-trip through the output buffer
-(``accumulate=False``); the tuner picks between them per workload×hardware.
+dimension so partial sums round-trip through HBM (``accumulate=False``); the
+tuner picks between them per workload×hardware.
+
+The store-heavy form keeps its output in HBM (``memory_space=pl.ANY``) and
+moves each partial block with synchronous copies: the first K step writes
+it, every later one reads it back, adds, and writes it again. Pallas's
+output pipelining would not do: with K outermost an output block is
+revisited out of order, and the compiled kernel never reads a revisited
+output block back from HBM. Every kernel asks the compiler for the scoped
+VMEM the schedule was concretized against (``params.vmem_limit``).
 """
 
 from __future__ import annotations
@@ -39,23 +47,39 @@ def _acc_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _noacc_kernel(x_ref, w_ref, o_ref, *, acc_dtype) -> None:
+def _noacc_kernel(x_ref, w_ref, o_hbm, part_ref, *, bm: int, bn: int,
+                  acc_dtype) -> None:
     """K-outer grid: the output block is revisited ``k_steps`` times with
     full HBM write-back in between (the store-heavy baseline schedule)."""
-    k = pl.program_id(0)
+    k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    block = o_hbm.at[pl.ds(pl.multiple_of(i * bm, bm), bm),
+                     pl.ds(pl.multiple_of(j * bn, bn), bn)]
+    prod = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=acc_dtype)
 
     @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _first():
+        part_ref[...] = prod
 
-    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                          preferred_element_type=acc_dtype).astype(o_ref.dtype)
+    @pl.when(k > 0)
+    def _revisit():
+        pltpu.sync_copy(block, part_ref)
+        part_ref[...] += prod
+
+    pltpu.sync_copy(part_ref, block)
+
+
+def compiler_params(params: KernelParams):
+    """The scoped-VMEM limit of the part ``params`` was concretized for."""
+    return pltpu.CompilerParams(vmem_limit_bytes=params.vmem_limit or None)
 
 
 def matmul_pallas(x: jax.Array, w: jax.Array, params: KernelParams,
-                  interpret: bool = True) -> jax.Array:
+                  interpret=True) -> jax.Array:
     """``x @ w`` with the schedule in ``params``. Shapes already padded to
-    ``params.padded_dims``; returns the padded (pm, pn) product."""
+    ``params.padded_dims``; returns the padded (pm, pn) product.
+    ``interpret`` is passed to ``pallas_call`` as is: False compiles for the
+    TPU, True runs the legacy interpreter, a ``pltpu.InterpretParams`` the
+    interpreter that keeps TPU memory semantics."""
     pm, pn, pk = params.padded_dims
     bm, bn, bk = params.block
     gm, gn, gk = pm // bm, pn // bn, pk // bk
@@ -83,18 +107,21 @@ def matmul_pallas(x: jax.Array, w: jax.Array, params: KernelParams,
             out_specs=pl.BlockSpec((bm, bn), o_map),
             out_shape=jax.ShapeDtypeStruct((pm, pn), acc_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+            compiler_params=compiler_params(params),
             interpret=interpret,
         )(x, w)
 
-    # store-heavy: K outermost
-    grid = (gk, gm, gn)
-    kernel = functools.partial(_noacc_kernel, acc_dtype=acc_dtype)
+    # store-heavy: K outermost, partial sums through HBM
+    kernel = functools.partial(_noacc_kernel, bm=bm, bn=bn,
+                               acc_dtype=acc_dtype)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(gk, gm, gn),
         in_specs=[pl.BlockSpec((bm, bk), lambda k, i, j: (i, k)),
                   pl.BlockSpec((bk, bn), lambda k, i, j: (k, j))],
-        out_specs=pl.BlockSpec((bm, bn), lambda k, i, j: (i, j)),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((pm, pn), acc_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        compiler_params=compiler_params(params),
         interpret=interpret,
     )(x, w)

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.space import KernelParams
+from repro.kernels.matmul.kernel import compiler_params
 
 
 def _qmm_kernel(x_ref, w_ref, bias_ref, scale_ref, o_ref, acc_ref,
@@ -41,7 +42,7 @@ def _qmm_kernel(x_ref, w_ref, bias_ref, scale_ref, o_ref, acc_ref,
 
 
 def qmatmul_pallas(x, w, bias, scale, params: KernelParams,
-                   interpret: bool = True):
+                   interpret=True):
     """int8 (pm,pk) @ (pk,pn) + bias(pn,) -> requantized int8 (pm,pn)."""
     pm, pn, pk = params.padded_dims
     bm, bn, bk = params.block
@@ -59,5 +60,6 @@ def qmatmul_pallas(x, w, bias, scale, params: KernelParams,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params(params),
         interpret=interpret,
     )(x, w, bias, scale)

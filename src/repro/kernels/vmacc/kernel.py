@@ -15,13 +15,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.space import KernelParams
+from repro.kernels.matmul.kernel import compiler_params
 
 
 def _vmacc_kernel(a_ref, b_ref, c_ref, o_ref) -> None:
     o_ref[...] = a_ref[...] * b_ref[...] + c_ref[...]
 
 
-def vmacc_pallas(a, b, c, params: KernelParams, interpret: bool = True):
+def vmacc_pallas(a, b, c, params: KernelParams, interpret=True):
     pr, pc = params.padded_dims
     br, bc = params.block
     spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
@@ -31,5 +32,6 @@ def vmacc_pallas(a, b, c, params: KernelParams, interpret: bool = True):
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((pr, pc), a.dtype),
+        compiler_params=compiler_params(params),
         interpret=interpret,
     )(a, b, c)

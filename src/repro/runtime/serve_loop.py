@@ -35,6 +35,9 @@ class GenerationResult:
     # ("tuned"/"bucketed"/"fixed"/"xla"); None when the server was built
     # without a dispatch layer (hw=None)
     dispatch: dict[str, int] | None = None
+    # the (B, V) logits each generated token was taken from, on the device:
+    # the prefill's last position first, then one per decode step
+    logits: list = dataclasses.field(default_factory=list)
 
 
 def decode_ops(cfg, batch: int) -> list[tuple[int, Workload]]:
@@ -75,12 +78,14 @@ class Server:
     into ``traffic`` — the serving side of the continuous-tuning loop.
 
     ``build_kernels=True`` additionally builds each resolved schedule's
-    Pallas kernel (interpret mode) during the dispatch pass. Builds go
-    through the content-addressed process-wide
-    :class:`~repro.core.build_cache.BuildCache`, so only the *first*
-    resolution of each distinct concrete lowering pays the build — steady
-    state (the same ops resolving to the same schedules, generate after
-    generate) performs zero builds, which ``--suite cache`` asserts."""
+    Pallas kernel during the dispatch pass, for the backend the server runs
+    on (compiled on a TPU, interpret mode elsewhere); failed builds are
+    counted in ``build_failures``. Builds go through the content-addressed
+    process-wide :class:`~repro.core.build_cache.BuildCache`, so only the
+    *first* resolution of each distinct concrete lowering pays the build —
+    steady state (the same ops resolving to the same schedules, generate
+    after generate) performs zero builds, which ``--suite cache`` asserts.
+    Prefill and decode are each one jitted program."""
 
     def __init__(self, bundle: ModelBundle, params, max_len: int = 256,
                  hw=None, serve_ops=None, traffic=None, database=None,
@@ -93,6 +98,9 @@ class Server:
         self.traffic = traffic
         self.database = database
         self.build_kernels = build_kernels
+        self.build_failures = 0  # resolved schedules whose build raised
+        self._prefill = jax.jit(
+            lambda p, batch: bundle.prefill_fn(p, batch, max_len))
         self._decode = jax.jit(
             lambda p, c, t, pos: bundle.decode_fn(p, c, t, pos))
 
@@ -119,18 +127,22 @@ class Server:
     def _build_kernel(self, wl: Workload, sched) -> None:
         """Build one resolved op's kernel through the process-wide build
         cache (a repeat of an already-built signature is a cache hit, no
-        build). An "xla" resolution never reaches here (sched is None) and
-        a schedule that doesn't concretize on this shape is skipped — the
-        dispatch pass must keep serving even when a kernel can't build."""
+        build), compiled when the server runs on a TPU and in interpret
+        mode elsewhere. An "xla" resolution never reaches here (sched is
+        None) and a schedule that doesn't concretize on this shape is
+        skipped. A build that raises is counted in ``build_failures`` and
+        the dispatch pass goes on serving."""
         from repro import kernels
         from repro.core import space as space_lib
 
+        params = space_lib.concretize(wl, self.hw, sched)
+        if not params.valid:
+            return
         try:
-            params = space_lib.concretize(wl, self.hw, sched)
-            if params.valid:
-                kernels.build(wl, params, interpret=True)
-        except Exception:
-            pass
+            kernels.build(wl, params,
+                          interpret=jax.default_backend() != "tpu")
+        except Exception:  # counted, not hidden
+            self.build_failures += 1
 
     def generate(self, prompts: np.ndarray, n_steps: int,
                  extra_batch: dict | None = None) -> GenerationResult:
@@ -141,8 +153,8 @@ class Server:
             batch.update({k: jnp.asarray(v) for k, v in extra_batch.items()})
 
         t0 = time.perf_counter()
-        logits, cache = self.bundle.prefill_fn(self.params, batch,
-                                               self.max_len)
+        logits, cache = self._prefill(self.params, batch)
+        step_logits = [logits[:, -1]]
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         jax.block_until_ready(next_tok)
         prefill_s = time.perf_counter() - t0
@@ -156,6 +168,7 @@ class Server:
             pos = jnp.int32(s + i)
             logits, cache = self._decode(self.params, cache,
                                          next_tok[:, None], pos)
+            step_logits.append(logits)
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out.append(np.asarray(next_tok))
         jax.block_until_ready(next_tok)
@@ -164,4 +177,5 @@ class Server:
         gen = (np.stack(out, axis=1) if out
                else np.zeros((b, 0), dtype=prompts.dtype))
         return GenerationResult(np.concatenate([prompts, gen], axis=1),
-                                prefill_s, decode_s, n_steps, dispatch)
+                                prefill_s, decode_s, n_steps, dispatch,
+                                step_logits[:n_steps])

@@ -25,18 +25,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Version-compatible ``AbstractMesh`` constructor.
-
-    jax >= 0.5 takes ``AbstractMesh(axis_sizes, axis_names)``; jax 0.4.x
-    takes a single ``((name, size), ...)`` shape tuple. Sharding rules only
-    need mesh *shape*, so AbstractMesh works without devices on both.
-    """
+    """An ``AbstractMesh`` of this shape: sharding rules only need the mesh
+    shape, so no devices are needed."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:  # jax 0.4.x single-argument signature
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+
 
 # Ordered (path-regex, spec-template) rules. Templates name mesh axes per
 # dim; "_" = replicated. Matched against "/".join(path keys).

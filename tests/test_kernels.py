@@ -1,8 +1,11 @@
 """Per-kernel correctness: shape/dtype sweeps + hypothesis properties, all
 validated in interpret mode against the pure-jnp oracles in ref.py."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -49,13 +52,63 @@ def test_matmul_property(m, n, k, seed):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
+# The legacy interpreter, and the one that keeps TPU memory semantics: it
+# refuses an output block that is revisited out of order, which the
+# compiled kernel would not read back from HBM.
+INTERPRETERS = pytest.mark.parametrize(
+    "interpret", [True, pltpu.InterpretParams()],
+    ids=["legacy", "tpu_semantics"])
+
+
+def _store_heavy(wl, blocks):
+    """A store-heavy (accumulate=False) schedule: sampled when ``blocks`` is
+    None, else on the widest variant with those tile splits pinned."""
+    space = space_for(wl, HW)
+    if blocks is None:
+        s = TraceSampler(0).sample(space)
+    else:
+        names = ("bm", "bn", "bk") if wl.op == "matmul" else ("bn", "bk")
+        pinned = dict(zip(names, blocks), variant=space["variant"][0])
+        s = space.replay(pinned, TraceSampler(0).rng)
+        assert tuple(s[n] for n in names) == blocks
+    p = concretize(wl, HW, s.replace("accumulate", False))
+    assert p.valid and not p.accumulate, p
+    return p
+
+
 def test_matmul_store_heavy_schedule_matches():
     """accumulate=False (k-outer, partials via HBM) must stay correct."""
     wl = W.matmul(64, 96, 160, "float32")
-    space = space_for(wl, HW)
-    s = TraceSampler(0).sample(space).replace("accumulate", False)
-    p = concretize(wl, HW, s)
-    fn = kernels.build(wl, p, interpret=True)
+    fn = kernels.build(wl, _store_heavy(wl, None), interpret=True)
+    x, w = wl.example_inputs()
+    np.testing.assert_allclose(np.asarray(fn(x, w)), x @ w, rtol=1e-4,
+                               atol=1e-3)
+
+
+@INTERPRETERS
+@pytest.mark.parametrize("m,n,blocks", [(64, 96, (16, 32, 32)),
+                                        (64, 64, (64, 64, 32))],
+                         ids=["12_out_blocks", "1_out_block"])
+def test_matmul_store_heavy_blocks_match(interpret, m, n, blocks):
+    """The store-heavy matmul with many output blocks (each revisited out
+    of order) and with one (revisits back to back)."""
+    wl = W.matmul(m, n, 160, "float32")
+    p = _store_heavy(wl, blocks)
+    fn = kernels.build(wl, p, interpret=interpret, cache=False)
+    x, w = wl.example_inputs()
+    np.testing.assert_allclose(np.asarray(fn(x, w)), x @ w, rtol=1e-4,
+                               atol=1e-3)
+
+
+@INTERPRETERS
+@pytest.mark.parametrize("n,blocks", [(96, (32, 32)), (64, (64, 32))],
+                         ids=["3_out_blocks", "1_out_block"])
+def test_gemv_store_heavy_schedule_matches(interpret, n, blocks):
+    """The gemv counterpart: partial output rows through HBM."""
+    wl = W.gemv(n, 160)
+    p = _store_heavy(wl, blocks)
+    assert p.grid[1] > 1  # several k steps: the partials are revisited
+    fn = kernels.build(wl, p, interpret=interpret, cache=False)
     x, w = wl.example_inputs()
     np.testing.assert_allclose(np.asarray(fn(x, w)), x @ w, rtol=1e-4,
                                atol=1e-3)
@@ -103,14 +156,19 @@ def test_gemv_bn_split_kernel_correct(bn_tiles):
 
 
 def test_gemv_j1_variant():
-    """The paper's J=1 fallback intrinsic must be registered and correct."""
+    """The paper's J=1 fallback intrinsic must be registered and correct.
+    It matches single-row outputs only: the TPU lowers a (bk, 1) weight
+    tile only when that row is the whole output."""
     from repro.core import intrinsics
-    wl = W.gemv(96, 256)
+    assert "j1" not in [v.name for v in
+                        intrinsics.variants_for(W.gemv(96, 256), HW)]
+    wl = W.gemv(1, 256)
     names = [v.name for v in intrinsics.variants_for(wl, HW)]
     assert "j1" in names
     space = space_for(wl, HW)
     s = TraceSampler(0).sample(space).replace("variant", "j1")
     p = concretize(wl, HW, s)
+    assert p.valid and p.block[0] == 1, p
     fn = kernels.build(wl, p, interpret=True)
     x, w = wl.example_inputs()
     np.testing.assert_allclose(np.asarray(fn(x, w)),
@@ -177,3 +235,93 @@ def test_xla_baseline_matches_reference(op):
     np.testing.assert_allclose(np.asarray(fn(*inputs)),
                                np.asarray(ref(*inputs)), rtol=1e-5,
                                atol=1e-5)
+
+
+# ------------------------------------------------------------- chip path ----
+# DeviceRunner and the child-process runners decide from the backend and the
+# device JAX reports; the tests steer those two seams of core/runner.py.
+
+def _fake_v5e(monkeypatch):
+    from repro.core import runner as runner_lib
+
+    monkeypatch.setattr(runner_lib, "attached_device", lambda: SimpleNamespace(
+        platform="tpu", device_kind="TPU v5 lite"))
+
+
+def test_device_runner_refuses_cpu_backend():
+    from repro.core import DeviceRunner
+
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        DeviceRunner()
+
+
+def test_unknown_device_kind_raises(monkeypatch):
+    from repro.core import V5E, DeviceRunner
+    from repro.core import runner as runner_lib
+    from repro.core.hardware import for_device_kind
+
+    assert for_device_kind("TPU v5 lite") is V5E
+    monkeypatch.setattr(runner_lib, "attached_device", lambda: SimpleNamespace(
+        platform="tpu", device_kind="TPU v99"))
+    with pytest.raises(ValueError, match="no hardware configuration"):
+        DeviceRunner()
+
+
+def test_subprocess_measurement_refused_under_tpu_backend(monkeypatch):
+    from repro.core import LocalBoard, SubprocessRunner
+    from repro.core import runner as runner_lib
+
+    monkeypatch.setattr(runner_lib, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process holds the chip"):
+        SubprocessRunner(HW)
+    with pytest.raises(RuntimeError, match="one process holds the chip"):
+        LocalBoard("board0", HW)
+
+
+def test_device_runner_checks_counts_and_never_times_a_wrong_kernel(
+        monkeypatch):
+    """The measurement logic, with kernels built in interpret mode: a
+    correct kernel is timed and its error recorded; a refused build is
+    counted by reason; a wrong result is counted and INVALID."""
+    import math
+
+    import jax
+
+    from repro.core import (V5E, DeviceRunner, Schedule,
+                            fixed_library_schedule)
+    from repro.core.runner import INVALID, TOLERANCE
+
+    _fake_v5e(monkeypatch)
+    build = kernels.build
+    monkeypatch.setattr(kernels, "build", lambda wl, p, interpret=True,
+                        cache=None: build(wl, p, interpret=True, cache=cache))
+    runner = DeviceRunner()
+    assert runner.hw is V5E
+    wl = W.gemv(256, 512, "bfloat16")
+    assert math.isfinite(runner.run(wl, fixed_library_schedule(wl, V5E)))
+    assert 0.0 <= runner.max_error(wl) <= TOLERANCE["bfloat16"]
+
+    def sched(bk, bn=128):  # kernels other than the library's
+        return Schedule.fixed(variant="vl_512", bk=bk, bn=bn,
+                              accumulate=True)
+
+    def refuse(msg):
+        def fail(*a, **k):
+            raise RuntimeError(msg)
+        return fail
+
+    monkeypatch.setattr(kernels, "build", refuse(
+        "Ran out of memory in memory space vmem"))
+    assert runner.run(wl, sched(128)) == INVALID
+    monkeypatch.setattr(kernels, "build", refuse(
+        "block shape must be divisible by 8 and 128"))
+    assert runner.run(wl, sched(256)) == INVALID
+    monkeypatch.setattr(kernels, "build", lambda wl_, p, **k: jax.jit(
+        lambda x, w: build(wl_, p, interpret=True)(x, w) + 1.0))
+    assert runner.run(wl, sched(512, bn=256)) == INVALID
+    assert runner.failures(wl) == {"vmem": 1, "alignment": 1, "other": 0,
+                                   "wrong": 1}
+    assert runner.first_refusal == {
+        "vmem": "RuntimeError: Ran out of memory in memory space vmem",
+        "alignment": "RuntimeError: block shape must be divisible by 8 and "
+                     "128"}

@@ -269,6 +269,36 @@ def test_server_generates_consistent_with_forward():
     np.testing.assert_array_equal(out.tokens[:, 8:], greedy)
 
 
+def test_serve_launcher_smoke_checks_logits(monkeypatch, capsys):
+    """The launcher serves the published config unless asked for the smoke
+    one, prints a depth cut, and checks its logits against the f32
+    reference."""
+    from repro.launch import serve
+
+    cfg = serve.serving_config("yi_6b", layers=8)
+    published = get_config("yi_6b")
+    assert cfg.n_layers == 8 and cfg.d_model == published.d_model
+    assert serve.serving_config("yi_6b") == published
+    monkeypatch.setattr("sys.argv", [
+        "serve", "--smoke", "--batch", "2", "--prompt-len", "6",
+        "--gen-steps", "4", "--check"])
+    serve.main()
+    out = capsys.readouterr().out
+    assert "arch=yi-6b-smoke depth=2 layers" in out
+    assert "logit error vs f32 reference" in out
+
+
+def test_check_logits_catches_wrong_logits():
+    from repro.launch import serve
+
+    cfg = serve.serving_config("yi_6b", smoke=True)
+    server, prompts, _ = serve.build_server(cfg, 2, 6, 4)
+    res = server.generate(prompts, 4)
+    assert max(serve.check_logits(server, res, 6)) <= serve.LOGIT_TOLERANCE
+    res.logits[1] = res.logits[1][:, ::-1]  # logits of the wrong tokens
+    assert max(serve.check_logits(server, res, 6)) > serve.LOGIT_TOLERANCE
+
+
 @pytest.mark.slow
 def test_train_step_perf_knobs_numerics():
     """The §Perf train knobs (bf16 cast-once, explicit ZeRO-3 gather specs)

@@ -105,9 +105,13 @@ def test_gemv_bn_split_is_kernel_gated_and_variant_conditioned():
     for c in cands:
         assert supports_block_shape(c, ctx["bk"], lane)
         assert c == 1 or c % lane == 0
+    # J=1 matches single-row outputs only: the TPU cannot lower a (bk, 1)
+    # weight tile of a wider output
+    assert "j1" not in prog["variant"]
+    row = space_for(W.gemv(1, 12288, "bfloat16"), V5E)
     j1 = {"variant": "j1"}
-    j1["bk"] = prog.candidates("bk", j1)[0]
-    assert prog.candidates("bn", j1) == (1,)
+    j1["bk"] = row.candidates("bk", j1)[0]
+    assert row.candidates("bn", j1) == (1,)
 
 
 def test_gemv_bn_split_concretizes_perfect_tiles():

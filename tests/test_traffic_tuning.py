@@ -217,6 +217,16 @@ def test_tune_once_empty_log_is_a_noop(fresh):
     assert tuner.cycles == 0
 
 
+def test_tuner_never_defaults_to_the_analytic_model(fresh):
+    """Off the TPU a tuner must be given its runner: the analytic model is
+    tuned against only when asked for."""
+    log = TrafficLog()
+    log.record(W.matmul(8, 64, 64), V5E.name)
+    with pytest.raises(ValueError, match="needs a runner"):
+        ContinuousTuner(log, V5E).tune_once()
+    assert log.pending(V5E.name) == 1  # nothing was drained
+
+
 def test_tune_once_prioritizes_hottest_shape(fresh):
     log = TrafficLog()
     hot, cold = W.matmul(8, 64, 64), W.matmul(16, 64, 64)
@@ -349,6 +359,34 @@ def test_server_dispatch_counts_and_continuous_tuning(fresh):
     # a dispatch-less server keeps the old contract
     plain = Server(bundle, params, max_len=32)
     assert plain.generate(prompts, n_steps=2).dispatch is None
+
+
+def test_server_counts_kernel_build_failures(monkeypatch):
+    """A resolved schedule whose kernel cannot be built is counted, and the
+    server goes on serving."""
+    import jax
+    import numpy as np
+    from repro import kernels
+    from repro.configs import get_config
+    from repro.configs.base import ShapeSpec
+    from repro.models.model_zoo import build
+    from repro.runtime.serve_loop import Server, decode_ops
+
+    def refuse(*a, **k):
+        raise RuntimeError("refused")
+
+    monkeypatch.setattr(kernels, "build", refuse)
+    cfg = get_config("yi_6b").reduced()
+    bundle = build(cfg, remat="none")
+    ops = decode_ops(cfg, batch=2)
+    server = Server(bundle, bundle.init(jax.random.key(2)), max_len=16,
+                    hw=V5E, serve_ops=ops, database=TuningDatabase(),
+                    build_kernels=True)
+    prompts = np.asarray(bundle.make_batch(
+        0, ShapeSpec("p", 4, 2, "decode"), train=False)["tokens"])
+    out = server.generate(prompts, n_steps=2)
+    assert out.tokens.shape == (2, 6)
+    assert server.build_failures == len(ops)
 
 
 def test_decode_ops_shapes():
