@@ -107,6 +107,7 @@ from typing import Callable, Protocol, Sequence
 import numpy as np
 
 from repro.core import space as space_lib
+from repro.core import tracing
 from repro.core.hardware import HardwareConfig, for_device_kind
 from repro.core.schedule import Schedule
 from repro.core.workload import Workload
@@ -460,6 +461,11 @@ class DeviceRunner:
 
     ``failures(workload)`` and ``max_error(workload)`` report per
     workload; the tuner copies them onto its results.
+
+    Each candidate is a ``repro.runner.run`` span of ``core/tracing.py``,
+    split into ``inputs``, ``compile`` (refusals included), ``reference``,
+    ``check`` and ``time``; a signature compiled before counts as
+    ``repro.runner.reused``.
     """
 
     WARMUP = 2
@@ -490,7 +496,8 @@ class DeviceRunner:
     def inputs(self, workload: Workload) -> tuple:
         key = workload.key()
         if key not in self._inputs:
-            self._inputs[key] = place_inputs(workload)
+            with tracing.span("repro.runner.inputs"):
+                self._inputs[key] = place_inputs(workload)
         return self._inputs[key]
 
     def reference_output(self, workload: Workload):
@@ -499,9 +506,11 @@ class DeviceRunner:
 
         key = workload.key()
         if key not in self._refs:
-            with jax.default_matmul_precision("highest"):
+            inputs = self.inputs(workload)
+            with tracing.span("repro.runner.reference"), \
+                    jax.default_matmul_precision("highest"):
                 self._refs[key] = jax.jit(kernels.reference(workload))(
-                    *self.inputs(workload))
+                    *inputs)
         return self._refs[key]
 
     def failures(self, workload: Workload) -> dict[str, int]:
@@ -553,12 +562,14 @@ class DeviceRunner:
             return None
         sig = params.signature()
         if sig in self._compiled:
+            tracing.count("repro.runner.reused")
             return self._compiled[sig]
         inputs = self.inputs(workload)
         self._compiled[sig] = None
         try:
-            fn = kernels.build(workload, params, interpret=False)
-            compiled = fn.lower(*inputs).compile()
+            with tracing.span("repro.runner.compile"):
+                fn = kernels.build(workload, params, interpret=False)
+                compiled = fn.lower(*inputs).compile()
         except Exception as exc:  # a refusal is a result, and it is counted
             reason = refusal_reason(exc)
             self._count(workload, reason)
@@ -566,9 +577,10 @@ class DeviceRunner:
             self.first_refusal.setdefault(
                 reason, f"{type(exc).__name__}: {first_line}")
             return None
-        err = self.error(workload, compiled(*inputs))
-        tol = 0.0 if _is_integer(self.reference_output(workload).dtype) \
-            else TOLERANCE[workload.dtype]
+        ref = self.reference_output(workload)
+        with tracing.span("repro.runner.check"):
+            err = self.error(workload, compiled(*inputs))
+        tol = 0.0 if _is_integer(ref.dtype) else TOLERANCE[workload.dtype]
         if not err <= tol:
             self._count(workload, "wrong")
             return None
@@ -578,11 +590,13 @@ class DeviceRunner:
         return compiled
 
     def run(self, workload: Workload, schedule: Schedule) -> float:
-        fn = self._prepare(workload, schedule)
-        if fn is None:
-            return INVALID
-        return _best_time(fn, self.inputs(workload), self.WARMUP,
-                          self.REPEATS)
+        with tracing.span("repro.runner.run"):
+            fn = self._prepare(workload, schedule)
+            if fn is None:
+                return INVALID
+            inputs = self.inputs(workload)
+            with tracing.span("repro.runner.time"):
+                return _best_time(fn, inputs, self.WARMUP, self.REPEATS)
 
     def run_batch(self, workload: Workload,
                   schedules: Sequence[Schedule]) -> list[float]:

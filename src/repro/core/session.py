@@ -91,7 +91,7 @@ import math
 import time
 from typing import Callable, Sequence
 
-from repro.core import tuner
+from repro.core import tracing, tuner
 from repro.core.build_cache import build_cache_stats, stats_delta
 from repro.core.database import TuningDatabase
 from repro.core.hardware import HardwareConfig
@@ -597,6 +597,11 @@ class TuningSession:
 
     def tune_model(self, ops: ModelConfig, total_trials: int = 256,
                    seed: int = 0, model: str = "") -> SessionResult:
+        with tracing.span("repro.session.tune_model"):
+            return self._tune_model(ops, total_trials, seed, model)
+
+    def _tune_model(self, ops: ModelConfig, total_trials: int, seed: int,
+                    model: str) -> SessionResult:
         if self.stop_policy not in ("none", "entropy"):
             raise ValueError(
                 f"unknown stop_policy {self.stop_policy!r} "
@@ -638,7 +643,8 @@ class TuningSession:
             # path has no scheduler to adapt and no shared ledger
             results, overlap_s, span_s, extras = self._tune_serial(
                 unique, budgets, seed)
-        baselines = self._measure_baselines(unique)
+        with tracing.span("repro.session.baselines"):
+            baselines = self._measure_baselines(unique)
         reports = [self._report_for(i, len(unique), count, wl, res, fixed)
                    for i, ((count, wl), res, fixed)
                    in enumerate(zip(unique, results, baselines))]
@@ -671,6 +677,7 @@ class TuningSession:
             build_cache=stats_delta(build_cache_stats(), bc_before),
             measured_memo=sum(r.measured_memo for r in results),
             failures=failures)
+        tracing.count("repro.session.trials", result.total_trials)
         if self.database is not None:
             self.database.add_session(result.summary())
             if self.database.path:

@@ -72,6 +72,7 @@ from collections import deque
 from typing import Callable, Mapping, Sequence
 
 from repro.core import space as space_lib
+from repro.core import tracing
 from repro.core.build_cache import build_cache_stats, stats_delta
 from repro.core.cost_model import (RidgeCostModel, features,
                                    pretrain_from_database)
@@ -683,9 +684,15 @@ def tune(workload: Workload, hw: HardwareConfig, runner: Runner,
                         reuse_measured=reuse_measured)
     depth = effective_pipeline_depth(runner, pipeline_depth)
     if pipeline_depth <= 1:
-        while (batch_s := driver.propose()) is not None:
-            latencies = timed_run_batch(runner, driver, batch_s)
-            driver.reconcile(batch_s, latencies)
+        while True:
+            with tracing.span("repro.tuner.propose"):
+                batch_s = driver.propose()
+            if batch_s is None:
+                break
+            with tracing.span("repro.tuner.measure"):
+                latencies = timed_run_batch(runner, driver, batch_s)
+            with tracing.span("repro.tuner.reconcile"):
+                driver.reconcile(batch_s, latencies)
         driver.wait_time_s = driver.measure_time_s  # nothing overlapped
         driver.overlap_span_s = 0.0
         driver.note_depth(1)

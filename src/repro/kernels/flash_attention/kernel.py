@@ -106,5 +106,6 @@ def flash_attention_pallas(q, k, v, params: KernelParams,
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
         compiler_params=compiler_params(params),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

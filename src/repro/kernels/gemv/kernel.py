@@ -71,6 +71,7 @@ def gemv_pallas(x, w, params: KernelParams, interpret=True):
             out_shape=jax.ShapeDtypeStruct((1, pn), jnp.float32),
             scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
             compiler_params=compiler_params(params),
+            name="gemv",
             interpret=interpret,
         )(x, w)
     return pl.pallas_call(
@@ -82,5 +83,6 @@ def gemv_pallas(x, w, params: KernelParams, interpret=True):
         out_shape=jax.ShapeDtypeStruct((1, pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
         compiler_params=compiler_params(params),
+        name="gemv",
         interpret=interpret,
     )(x, w)

@@ -108,6 +108,7 @@ def matmul_pallas(x: jax.Array, w: jax.Array, params: KernelParams,
             out_shape=jax.ShapeDtypeStruct((pm, pn), acc_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
             compiler_params=compiler_params(params),
+            name="matmul",
             interpret=interpret,
         )(x, w)
 
@@ -123,5 +124,6 @@ def matmul_pallas(x: jax.Array, w: jax.Array, params: KernelParams,
         out_shape=jax.ShapeDtypeStruct((pm, pn), acc_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         compiler_params=compiler_params(params),
+        name="matmul",
         interpret=interpret,
     )(x, w)

@@ -61,5 +61,6 @@ def qmatmul_pallas(x, w, bias, scale, params: KernelParams,
         out_shape=jax.ShapeDtypeStruct((pm, pn), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=compiler_params(params),
+        name="qmatmul",
         interpret=interpret,
     )(x, w, bias, scale)

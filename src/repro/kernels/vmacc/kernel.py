@@ -33,5 +33,6 @@ def vmacc_pallas(a, b, c, params: KernelParams, interpret=True):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((pr, pc), a.dtype),
         compiler_params=compiler_params(params),
+        name="vmacc",
         interpret=interpret,
     )(a, b, c)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.workload import Workload, gemv, matmul
 from repro.models.model_zoo import ModelBundle
 
@@ -99,6 +100,7 @@ class Server:
         self.database = database
         self.build_kernels = build_kernels
         self.build_failures = 0  # resolved schedules whose build raised
+        self.batches = 0  # generate calls so far: each one's batch id
         self._prefill = jax.jit(
             lambda p, batch: bundle.prefill_fn(p, batch, max_len))
         self._decode = jax.jit(
@@ -114,14 +116,15 @@ class Server:
         from repro.core.dispatch import best_schedule  # lazy: jax-free core
 
         counts: dict[str, int] = {}
-        for count, wl in self.serve_ops:
-            sched, provenance = best_schedule(wl, self.hw,
-                                              database=self.database,
-                                              traffic=self.traffic,
-                                              count=count)
-            counts[provenance] = counts.get(provenance, 0) + count
-            if self.build_kernels and sched is not None:
-                self._build_kernel(wl, sched)
+        with tracing.span("repro.serve.resolve_dispatch"):
+            for count, wl in self.serve_ops:
+                sched, provenance = best_schedule(wl, self.hw,
+                                                  database=self.database,
+                                                  traffic=self.traffic,
+                                                  count=count)
+                counts[provenance] = counts.get(provenance, 0) + count
+                if self.build_kernels and sched is not None:
+                    self._build_kernel(wl, sched)
         return counts
 
     def _build_kernel(self, wl: Workload, sched) -> None:
@@ -146,18 +149,32 @@ class Server:
 
     def generate(self, prompts: np.ndarray, n_steps: int,
                  extra_batch: dict | None = None) -> GenerationResult:
+        """Prefill ``prompts`` and decode ``n_steps`` tokens greedily.
+        Traced (``core/tracing.py``), the call is a ``repro.serve.generate``
+        span with the batch id ``batch``, split into
+        ``repro.serve.resolve_dispatch``, ``repro.serve.prefill`` and, per
+        decode step, ``repro.serve.decode_dispatch`` (the host enqueues the
+        step and its argmax) and ``repro.serve.token_fetch`` (the host waits
+        for the token)."""
+        self.batches += 1
+        with tracing.span("repro.serve.generate", batch=self.batches):
+            return self._generate(prompts, n_steps, extra_batch)
+
+    def _generate(self, prompts: np.ndarray, n_steps: int,
+                  extra_batch: dict | None) -> GenerationResult:
         dispatch = self.resolve_dispatch()
         b, s = prompts.shape
         batch = {"tokens": jnp.asarray(prompts)}
         if extra_batch:
             batch.update({k: jnp.asarray(v) for k, v in extra_batch.items()})
 
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, batch)
-        step_logits = [logits[:, -1]]
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        jax.block_until_ready(next_tok)
-        prefill_s = time.perf_counter() - t0
+        with tracing.span("repro.serve.prefill"):
+            t0 = time.perf_counter()
+            logits, cache = self._prefill(self.params, batch)
+            step_logits = [logits[:, -1]]
+            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            jax.block_until_ready(next_tok)
+            prefill_s = time.perf_counter() - t0
 
         # the prefill argmax is the *first* generated token, so it counts
         # against n_steps: n_steps=0 emits nothing (tokens == prompts) and
@@ -165,12 +182,14 @@ class Server:
         out = [np.asarray(next_tok)] if n_steps > 0 else []
         t0 = time.perf_counter()
         for i in range(n_steps - 1):
-            pos = jnp.int32(s + i)
-            logits, cache = self._decode(self.params, cache,
-                                         next_tok[:, None], pos)
-            step_logits.append(logits)
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(np.asarray(next_tok))
+            with tracing.span("repro.serve.decode_dispatch"):
+                pos = jnp.int32(s + i)
+                logits, cache = self._decode(self.params, cache,
+                                             next_tok[:, None], pos)
+                step_logits.append(logits)
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with tracing.span("repro.serve.token_fetch"):
+                out.append(np.asarray(next_tok))
         jax.block_until_ready(next_tok)
         decode_s = time.perf_counter() - t0
 

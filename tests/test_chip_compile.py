@@ -76,6 +76,28 @@ def test_library_kernel_compiles(one_chip, name):
                     one_chip)
 
 
+# one workload of each kernel family, by the name its pallas_call carries
+FAMILY_WORKLOADS = {
+    "gemv": "gemv_ffn_up",
+    "matmul": "matmul_ffn_down",
+    "qmatmul": "qmatmul",
+    "vmacc": "vmacc",
+    "flash_attention": "attention",
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_WORKLOADS))
+def test_kernel_is_named_by_its_family(one_chip, family):
+    """A profile names the kernel: the lowered program carries the family's
+    name on its Pallas call."""
+    wl = LIBRARY_WORKLOADS[FAMILY_WORKLOADS[family]]
+    params = concretize(wl, V5E, fixed_library_schedule(wl, V5E))
+    fn = kernels.build(wl, params, interpret=False, cache=False)
+    specs = [jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+             for shape, dtype in wl.input_specs()]
+    assert f'kernel_name = "{family}"' in jax.jit(fn).lower(*specs).as_text()
+
+
 def _largest_valid(wl, accumulate):
     """The space-valid candidate of one accumulate form with the largest
     modelled VMEM footprint."""

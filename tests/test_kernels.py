@@ -325,3 +325,44 @@ def test_device_runner_checks_counts_and_never_times_a_wrong_kernel(
         "vmem": "RuntimeError: Ran out of memory in memory space vmem",
         "alignment": "RuntimeError: block shape must be divisible by 8 and "
                      "128"}
+
+
+def test_device_runner_spans_split_each_candidate(monkeypatch):
+    """Traced, a valid candidate's run is split into inputs, compile,
+    reference, check and time; a refused one records only its compile; a
+    signature compiled before is counted as reused."""
+    from repro.core import V5E, DeviceRunner, Schedule, fixed_library_schedule
+    from repro.core import tracing
+
+    _fake_v5e(monkeypatch)
+    build = kernels.build
+    monkeypatch.setattr(kernels, "build", lambda wl, p, interpret=True,
+                        cache=None: build(wl, p, interpret=True, cache=cache))
+    runner = DeviceRunner()
+    wl = W.gemv(256, 512, "bfloat16")
+    library = fixed_library_schedule(wl, V5E)
+    with tracing.enabled() as valid:
+        runner.run(wl, library)
+    spans = valid.summary()["spans"]
+    phases = ["repro.runner.inputs", "repro.runner.compile",
+              "repro.runner.reference", "repro.runner.check",
+              "repro.runner.time"]
+    assert sorted(spans) == sorted(phases + ["repro.runner.run"])
+    assert all(spans[n]["count"] == 1 for n in spans)
+    run = spans["repro.runner.run"]
+    assert run["self_s"] == pytest.approx(
+        run["total_s"] - sum(spans[n]["total_s"] for n in phases))
+
+    def refuse(*a, **k):
+        raise RuntimeError("Ran out of memory in memory space vmem")
+
+    monkeypatch.setattr(kernels, "build", refuse)
+    with tracing.enabled() as refused:
+        runner.run(wl, Schedule.fixed(variant="vl_512", bk=128, bn=128,
+                                      accumulate=True))
+        runner.run(wl, library)
+    s = refused.summary()
+    assert {n: v["count"] for n, v in s["spans"].items()} == {
+        "repro.runner.run": 2, "repro.runner.compile": 1,
+        "repro.runner.time": 1}
+    assert s["counters"] == {"repro.runner.reused": 1}

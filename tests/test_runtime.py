@@ -269,6 +269,40 @@ def test_server_generates_consistent_with_forward():
     np.testing.assert_array_equal(out.tokens[:, 8:], greedy)
 
 
+def test_server_spans_split_a_batch_into_prefill_dispatch_and_fetch():
+    from repro.core import tracing
+
+    cfg = get_config("yi_6b").reduced()
+    bundle = build(cfg, remat="none")
+    server = Server(bundle, bundle.init(jax.random.key(3)), max_len=32)
+    prompts = np.zeros((2, 8), np.int32)
+    server.generate(prompts, n_steps=2)  # compiles
+    with tracing.enabled() as rec:
+        res = server.generate(prompts, n_steps=6)
+    spans = rec.summary()["spans"]
+    assert {n: v["count"] for n, v in spans.items()} == {
+        "repro.serve.generate": 1, "repro.serve.prefill": 1,
+        "repro.serve.decode_dispatch": 5, "repro.serve.token_fetch": 5}
+    batch = {r.attrs["batch"] for r in rec.spans}
+    assert batch == {server.batches}  # one batch id, shared by its children
+    assert spans["repro.serve.prefill"]["total_s"] >= res.prefill_s
+    steps = (spans["repro.serve.decode_dispatch"]["total_s"]
+             + spans["repro.serve.token_fetch"]["total_s"])
+    assert steps <= res.decode_s
+
+    from repro.core import V5E, TuningDatabase
+    from repro.runtime.serve_loop import decode_ops
+
+    dispatching = Server(bundle, server.params, max_len=32, hw=V5E,
+                         serve_ops=decode_ops(cfg, batch=2),
+                         database=TuningDatabase())
+    with tracing.enabled() as rec:
+        dispatching.generate(prompts, n_steps=2)
+    resolve, = [r for r in rec.spans
+                if r.name == "repro.serve.resolve_dispatch"]
+    assert resolve.parent.name == "repro.serve.generate"
+
+
 def test_serve_launcher_smoke_checks_logits(monkeypatch, capsys):
     """The launcher serves the published config unless asked for the smoke
     one, prints a depth cut, and checks its logits against the f32
