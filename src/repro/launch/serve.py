@@ -5,7 +5,8 @@ By default the launcher serves the architecture's published configuration.
 CPU tests and CI), and ``--layers N`` cuts the depth to N layers with every
 width as published, so that a model fits one chip; the cut is printed.
 ``--check`` compares the served logits against a plain forward pass of the
-same tokens in f32 at "highest" matmul precision (:func:`check_logits`).
+same tokens in f32 at "highest" matmul precision, over the float32 master
+weights (:func:`check_logits`), not the server's compute-dtype copy of them.
 
 With ``--continuous-tune`` the launcher closes the serving↔tuning loop the
 way a production deployment would: the server resolves each decode step's
@@ -60,14 +61,18 @@ def serving_config(arch: str, smoke: bool = False,
     return cfg
 
 
+def master_params(cfg: ArchConfig, seed: int = 0):
+    """The seeded float32 master weights :func:`build_server` serves."""
+    return jax.jit(build(cfg, remat="none").init)(jax.random.key(seed))
+
+
 def build_server(cfg: ArchConfig, batch: int, prompt_len: int,
                  gen_steps: int, seed: int = 0, **server_kwargs):
-    """A :class:`Server` over seeded random weights and a seeded batch of
-    prompts: ``(server, prompts, extra_batch)``."""
+    """A :class:`Server` over the seeded :func:`master_params` and a
+    seeded batch of prompts: ``(server, prompts, extra_batch)``."""
     bundle = build(cfg, remat="none")
-    params = jax.jit(bundle.init)(jax.random.key(seed))
-    server = Server(bundle, params, max_len=prompt_len + gen_steps + 1,
-                    **server_kwargs)
+    server = Server(bundle, master_params(cfg, seed),
+                    max_len=prompt_len + gen_steps + 1, **server_kwargs)
     inputs = bundle.make_batch(
         seed, ShapeSpec("serve", prompt_len, batch, "decode"), train=False)
     prompts = np.asarray(inputs.pop("tokens"))
@@ -75,17 +80,20 @@ def build_server(cfg: ArchConfig, batch: int, prompt_len: int,
 
 
 def check_logits(server: Server, result: GenerationResult,
-                 prompt_len: int) -> list[float]:
+                 prompt_len: int, seed: int = 0) -> list[float]:
     """Normalized logit errors of the prefill's last position and the first
     ``CHECKED_DECODE_STEPS`` decode steps, against a plain forward pass of
-    the same tokens in f32 at "highest" matmul precision (same weights)."""
+    the same tokens in f32 at "highest" matmul precision over the float32
+    :func:`master_params` of ``seed``, from which :func:`build_server` made
+    the server (which keeps them cast to its compute dtype, so its own
+    tree would hide their rounding)."""
     cfg = dataclasses.replace(server.bundle.cfg, dtype="float32")
     steps = min(len(result.logits), CHECKED_DECODE_STEPS + 1)
     tokens = jnp.asarray(result.tokens[:, :prompt_len + steps - 1])
     ref_bundle = build(cfg, remat="none")
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(lambda p, t: ref_bundle.forward(p, {"tokens": t}))(
-            server.params, tokens)
+            master_params(cfg, seed), tokens)
     v = cfg.vocab_size
     errors = []
     for i in range(steps):
@@ -173,7 +181,7 @@ def main() -> None:
               f"{tuner.shapes_tuned} shape(s) -> {tuner.database.path}")
     print("sample:", res.tokens[0, : args.prompt_len + 8].tolist())
     if args.check:
-        errors = check_logits(server, res, args.prompt_len)
+        errors = check_logits(server, res, args.prompt_len, args.seed)
         print("logit error vs f32 reference (prefill, then decode steps): "
               + " ".join(f"{e:.4f}" for e in errors)
               + f" (tolerance {LOGIT_TOLERANCE})")

@@ -9,6 +9,11 @@ every architecture identically:
     prefill_fn(params, batch, max_len) -> (logits, cache)
     decode_fn(params, cache, tokens, pos) -> (logits (B,V), cache)
     init_cache(batch_size, max_len) -> cache pytree
+
+and ``serving_params(params) -> params``, the tree a server keeps: for the
+dense and VLM families the weights read only in the compute dtype are cast
+to it once (:func:`repro.models.transformer.serving_params`); every other
+family serves the tree it is given.
 """
 
 from __future__ import annotations
@@ -34,6 +39,7 @@ class ModelBundle:
     decode_fn: Callable
     init_cache: Callable
     make_batch: Callable
+    serving_params: Callable
 
 
 def _lm_loss_from_logits(logits, tokens):
@@ -43,6 +49,7 @@ def _lm_loss_from_logits(logits, tokens):
 
 def build(cfg: ArchConfig, remat: str = "full") -> ModelBundle:
     fam = cfg.family
+    serving_params = lambda params: params  # the dense families override
 
     if fam in ("dense", "vlm"):
         mod = transformer
@@ -71,6 +78,7 @@ def build(cfg: ArchConfig, remat: str = "full") -> ModelBundle:
 
         init_cache = lambda b, t: mod.init_cache(cfg, b, t)
         init = lambda key: mod.init_params(key, cfg)
+        serving_params = lambda params: mod.serving_params(params, cfg)
 
     elif fam == "moe":
         mod = moe
@@ -179,4 +187,4 @@ def build(cfg: ArchConfig, remat: str = "full") -> ModelBundle:
         return batch
 
     return ModelBundle(cfg, init, loss_fn, forward, prefill_fn, decode_fn,
-                       init_cache, make_batch)
+                       init_cache, make_batch, serving_params)

@@ -40,6 +40,39 @@ def init_params(key, cfg: ArchConfig):
     return params
 
 
+# The leaves the model only ever reads through ``.astype(x.dtype)``: the
+# projections, the embedding and the output head. Norm scales are read in
+# float32 by ``rms_norm`` and stay as they are.
+_SERVED_IN_COMPUTE_DTYPE = frozenset(
+    [("embedding",), ("lm_head",)]
+    + [("layers", "attn", w) for w in ("wq", "wk", "wv", "wo")]
+    + [("layers", "mlp", w) for w in ("w_up", "w_gate", "w_down")])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_leaves(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def serving_params(params, cfg: ArchConfig):
+    """``params`` with the projection, embedding and output-head leaves
+    cast to ``cfg.dtype`` in one jitted call, for serving: the model casts
+    them to that dtype at every use, so the cast copy gives the same
+    logits and is read at half the bytes of float32 masters. Every other
+    leaf, and a leaf already at ``cfg.dtype``, is passed through as it is."""
+    dtype = jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    todo = [i for i, (path, leaf) in enumerate(flat)
+            if tuple(k.key for k in path) in _SERVED_IN_COMPUTE_DTYPE
+            and leaf.dtype != dtype]
+    if not todo:
+        return params
+    leaves = [leaf for _, leaf in flat]
+    for i, cast in zip(todo, _cast_leaves([leaves[i] for i in todo], dtype)):
+        leaves[i] = cast
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 def window_array(cfg: ArchConfig):
     return jnp.asarray([cfg.window_for_layer(i) for i in range(cfg.n_layers)],
                        jnp.int32)

@@ -92,7 +92,15 @@ class Server:
                  hw=None, serve_ops=None, traffic=None, database=None,
                  build_kernels: bool = False):
         self.bundle = bundle
-        self.params = params
+        # the weights read only in the compute dtype, cast to it once here
+        # rather than in every prefill and decode program; no reference to
+        # ``params`` is kept, so the caller's float32 masters can be freed
+        with tracing.span("repro.serve.prepare_params"):
+            self.params = jax.block_until_ready(bundle.serving_params(params))
+        tracing.count("repro.serve.params_cast_bytes", sum(
+            new.nbytes for old, new in zip(jax.tree.leaves(params),
+                                           jax.tree.leaves(self.params))
+            if new is not old))
         self.max_len = max_len
         self.hw = hw
         self.serve_ops = list(serve_ops or ())

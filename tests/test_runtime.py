@@ -322,15 +322,139 @@ def test_serve_launcher_smoke_checks_logits(monkeypatch, capsys):
     assert "logit error vs f32 reference" in out
 
 
-def test_check_logits_catches_wrong_logits():
+def test_check_logits_catches_wrong_logits(monkeypatch):
+    import dataclasses
+
     from repro.launch import serve
 
-    cfg = serve.serving_config("yi_6b", smoke=True)
+    # a bfloat16 server keeps its weights in bfloat16: the reference must
+    # still run over the float32 masters, or it would not see their rounding
+    cfg = dataclasses.replace(serve.serving_config("yi_6b", smoke=True),
+                              dtype="bfloat16")
     server, prompts, _ = serve.build_server(cfg, 2, 6, 4)
+    assert server.params["layers"]["attn"]["wq"].dtype == jnp.bfloat16
     res = server.generate(prompts, 4)
+
+    ref_dtypes = set()
+
+    def recording_build(ref_cfg, **kwargs):
+        bundle = build(ref_cfg, **kwargs)
+        forward = bundle.forward
+
+        def recorded(params, batch):
+            ref_dtypes.update(leaf.dtype for leaf in jax.tree.leaves(params))
+            return forward(params, batch)
+
+        bundle.forward = recorded
+        return bundle
+
+    monkeypatch.setattr(serve, "build", recording_build)
     assert max(serve.check_logits(server, res, 6)) <= serve.LOGIT_TOLERANCE
+    assert ref_dtypes == {jnp.dtype(jnp.float32)}
     res.logits[1] = res.logits[1][:, ::-1]  # logits of the wrong tokens
     assert max(serve.check_logits(server, res, 6)) > serve.LOGIT_TOLERANCE
+
+
+def _bf16_yi_smoke():
+    import dataclasses
+
+    return dataclasses.replace(get_config("yi_6b").reduced(),
+                               dtype="bfloat16")
+
+
+_CAST_LEAVES = {"embedding", "lm_head", "wq", "wk", "wv", "wo", "w_up",
+                "w_gate", "w_down"}
+
+
+def _leaf_name(path):
+    return path[-1].key
+
+
+def test_server_on_f32_masters_serves_what_the_model_does_on_them():
+    """Weights cast once to bfloat16 when the server is built give the
+    tokens and per-step logits the model gives on the float32 masters,
+    which it casts at every use."""
+    cfg = _bf16_yi_smoke()
+    bundle = build(cfg, remat="none")
+    params = bundle.init(jax.random.key(4))
+    server = Server(bundle, params, max_len=32)
+    prompts = np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (2, 8)).astype(np.int32)
+    res = server.generate(prompts, n_steps=6)
+
+    prefill = jax.jit(lambda p, t: bundle.prefill_fn(p, {"tokens": t}, 32))
+    decode = jax.jit(bundle.decode_fn)
+    logits, cache = prefill(params, jnp.asarray(prompts))
+    want_logits = [logits[:, -1]]
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    want_tokens = [tok]
+    for i in range(5):
+        logits, cache = decode(params, cache, tok[:, None], jnp.int32(8 + i))
+        want_logits.append(logits)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        want_tokens.append(tok)
+
+    np.testing.assert_array_equal(res.tokens[:, 8:], np.stack(want_tokens, 1))
+    assert len(res.logits) == len(want_logits)
+    for got, want in zip(res.logits, want_logits):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_serving_params_casts_projections_and_keeps_norms_f32():
+    cfg = _bf16_yi_smoke()
+    bundle = build(cfg, remat="none")
+    params = bundle.init(jax.random.key(0))
+    served = bundle.serving_params(params)
+    names = set()
+    for (path, leaf), orig in zip(
+            jax.tree_util.tree_flatten_with_path(served)[0],
+            jax.tree.leaves(params)):
+        name = _leaf_name(path)
+        names.add(name)
+        if name in _CAST_LEAVES:
+            assert leaf.dtype == jnp.bfloat16, name
+            np.testing.assert_array_equal(
+                np.asarray(leaf, np.float32),
+                np.asarray(orig.astype(jnp.bfloat16), np.float32))
+        else:  # the norm scales, read in float32 by rms_norm
+            assert name in {"ln1", "ln2", "final_norm"}, name
+            assert leaf is orig and leaf.dtype == jnp.float32
+    assert _CAST_LEAVES <= names
+    # a tree already served passes through leaf for leaf
+    again = bundle.serving_params(served)
+    assert all(a is b for a, b in zip(jax.tree.leaves(again),
+                                      jax.tree.leaves(served)))
+
+
+@pytest.mark.parametrize("arch,dtype", [("yi_6b", "float32"),
+                                        ("mamba2_780m", "bfloat16")])
+def test_serving_params_is_identity_for_f32_and_other_families(arch, dtype):
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype=dtype)
+    bundle = build(cfg, remat="none")
+    params = bundle.init(jax.random.key(0))
+    assert bundle.serving_params(params) is params
+
+
+def test_server_traces_prepare_params_and_cast_bytes():
+    from repro.core import tracing
+
+    cfg = _bf16_yi_smoke()
+    bundle = build(cfg, remat="none")
+    params = bundle.init(jax.random.key(5))
+    with tracing.enabled() as rec:
+        server = Server(bundle, params, max_len=32)
+    summary = rec.summary()
+    assert summary["spans"]["repro.serve.prepare_params"]["count"] == 1
+    want = sum(leaf.size * 2 for path, leaf in
+               jax.tree_util.tree_flatten_with_path(params)[0]
+               if _leaf_name(path) in _CAST_LEAVES)
+    assert want > 0
+    assert summary["counters"]["repro.serve.params_cast_bytes"] == want
+    assert want == sum(leaf.nbytes for leaf in jax.tree.leaves(server.params)
+                       if leaf.dtype == jnp.bfloat16)
 
 
 @pytest.mark.slow
