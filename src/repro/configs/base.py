@@ -33,7 +33,23 @@ class ArchConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the training loss's dispatch only
+    # "softmax": softmax over the top-k logits (qwen2-moe). "sigmoid":
+    # select the top k of sigmoid(logit) + a correction bias, weight each
+    # by its unbiased score normalised over the k, times routed_scaling
+    # (DeepSeek-V3's noaux_tc with one group)
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    n_dense_layers: int = 0  # leading layers with a dense d_ff FFN
+    # The experts this program holds: experts_held of them from
+    # expert_offset on (expert parallelism's share); 0 = all n_experts
+    experts_held: int = 0
+    expert_offset: int = 0
+    # --- multi-head latent attention (MLA; 0 = standard attention) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (mamba2) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -70,6 +86,25 @@ class ArchConfig:
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
 
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def _attn_params(self) -> int:
+        d = self.d_model
+        if self.mla:
+            h, r = self.n_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (d * h * qk  # wq
+                    + d * (r + self.qk_rope_head_dim) + r  # wkv_a, kv_norm
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)  # wkv_b, wo
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+
     def window_for_layer(self, i: int) -> int:
         if not self.window_pattern:
             return -1
@@ -81,13 +116,17 @@ class ArchConfig:
         p = v * d  # embedding
         if not self.tie_embeddings:
             p += v * d
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = self._attn_params()
         mlp = 3 * d * f if self.act == "silu" else 2 * d * f
         if self.family == "moe":
             fe = self.moe_d_ff
-            moe = (self.n_experts * 3 * d * fe
-                   + self.n_shared_experts * 3 * d * fe + d * self.n_experts)
-            p += self.n_layers * (attn + moe + 2 * d)
+            router = d * self.n_experts + (
+                self.n_experts if self.router == "sigmoid" else 0)
+            moe = (self.held_experts * 3 * d * fe
+                   + self.n_shared_experts * 3 * d * fe + router)
+            n_moe = self.n_layers - self.n_dense_layers
+            p += (n_moe * (attn + moe + 2 * d)
+                  + self.n_dense_layers * (attn + mlp + 2 * d))
         elif self.family == "ssm":
             d_in = self.ssm_expand * d
             n = self.ssm_state
@@ -114,15 +153,18 @@ class ArchConfig:
         return p
 
     def active_params(self) -> int:
-        """Per-token active parameters (MoE counts top_k + shared only)."""
+        """Per-token active parameters (MoE counts top_k + shared only;
+        leading dense layers count whole)."""
         if self.family != "moe":
             return self.num_params()
         d, fe = self.d_model, self.moe_d_ff
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = self._attn_params()
         active_moe = ((self.top_k + self.n_shared_experts) * 3 * d * fe
                       + d * self.n_experts)
-        p = self.vocab_size * d + self.n_layers * (attn + active_moe + 2 * d)
-        return p
+        n_moe = self.n_layers - self.n_dense_layers
+        dense = attn + 3 * d * self.d_ff + 2 * d
+        return (self.vocab_size * d + n_moe * (attn + active_moe + 2 * d)
+                + self.n_dense_layers * dense)
 
     def block_kind(self, i: int) -> str:
         if not self.block_pattern:
@@ -136,7 +178,10 @@ class ArchConfig:
 
     # ------------------------------------------------------------- reduced --
     def reduced(self) -> "ArchConfig":
-        """Small same-family config for CPU smoke tests."""
+        """Small same-family config for CPU smoke tests: an expert share
+        becomes all of the smaller expert set, a latent attention keeps
+        its kind at small ranks, and a leading dense layer is kept ahead
+        of two expert layers."""
         kw = dict(
             name=self.name + "-smoke",
             n_layers=min(self.n_layers, 2 if not self.block_pattern
@@ -155,7 +200,13 @@ class ArchConfig:
             # prefill/decode consistency checks are exact
             kw.update(n_experts=4, top_k=2,
                       n_shared_experts=min(self.n_shared_experts, 1),
-                      moe_d_ff=32, capacity_factor=8.0)
+                      moe_d_ff=32, capacity_factor=8.0, experts_held=0,
+                      expert_offset=0,
+                      n_dense_layers=min(self.n_dense_layers, 1))
+            kw["n_layers"] = min(self.n_layers, kw["n_dense_layers"] + 2)
+        if self.mla:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                      qk_rope_head_dim=8, v_head_dim=16)
         if self.family == "ssm":
             kw.update(ssm_state=16, ssm_head_dim=16)
         if self.family == "hybrid":
@@ -190,7 +241,7 @@ SHAPES: dict[str, ShapeSpec] = {
 ARCH_IDS = (
     "granite_3_2b", "gemma3_1b", "yi_6b", "h2o_danube_1_8b",
     "recurrentgemma_2b", "whisper_tiny", "qwen2_vl_7b", "qwen2_moe_a2_7b",
-    "moonshot_v1_16b_a3b", "mamba2_780m",
+    "moonlight_16b_a3b", "mamba2_780m",
 )
 
 # Paper's own evaluation networks, also exposed as configs.

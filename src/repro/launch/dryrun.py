@@ -87,7 +87,7 @@ def model_flops(cfg: ArchConfig, shape: ShapeSpec, kind: str) -> float:
 
 # Per-arch microbatching (gradient accumulation): the standard knob for the
 # largest train cells; the global batch is unchanged.
-GRAD_ACCUM = {"qwen2_vl_7b": 2, "moonshot_v1_16b_a3b": 2}
+GRAD_ACCUM = {"qwen2_vl_7b": 2, "moonlight_16b_a3b": 2}
 
 
 def build_cell(arch_id: str, shape_name: str, mesh, remat: str = "full",

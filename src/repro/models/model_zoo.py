@@ -11,9 +11,10 @@ every architecture identically:
     init_cache(batch_size, max_len) -> cache pytree
 
 and ``serving_params(params) -> params``, the tree a server keeps: for the
-dense and VLM families the weights read only in the compute dtype are cast
-to it once (:func:`repro.models.transformer.serving_params`); every other
-family serves the tree it is given.
+dense, VLM and MoE families the weights read only in the compute dtype are
+cast to it once (:func:`repro.models.transformer.serving_params`, with the
+MoE family's leaves from :mod:`repro.models.moe`); every other family
+serves the tree it is given.
 """
 
 from __future__ import annotations
@@ -49,7 +50,7 @@ def _lm_loss_from_logits(logits, tokens):
 
 def build(cfg: ArchConfig, remat: str = "full") -> ModelBundle:
     fam = cfg.family
-    serving_params = lambda params: params  # the dense families override
+    serving_params = lambda params: params  # dense, VLM, MoE override
 
     if fam in ("dense", "vlm"):
         mod = transformer
@@ -99,6 +100,7 @@ def build(cfg: ArchConfig, remat: str = "full") -> ModelBundle:
 
         init_cache = lambda b, t: mod.init_cache(cfg, b, t)
         init = lambda key: mod.init_params(key, cfg)
+        serving_params = lambda params: mod.serving_params(params, cfg)
 
     elif fam == "ssm":
         mod = ssm

@@ -54,16 +54,18 @@ def _cast_leaves(leaves, dtype):
     return [leaf.astype(dtype) for leaf in leaves]
 
 
-def serving_params(params, cfg: ArchConfig):
+def serving_params(params, cfg: ArchConfig,
+                   leaves=_SERVED_IN_COMPUTE_DTYPE):
     """``params`` with the projection, embedding and output-head leaves
-    cast to ``cfg.dtype`` in one jitted call, for serving: the model casts
-    them to that dtype at every use, so the cast copy gives the same
-    logits and is read at half the bytes of float32 masters. Every other
-    leaf, and a leaf already at ``cfg.dtype``, is passed through as it is."""
+    (the paths in ``leaves``) cast to ``cfg.dtype`` in one jitted call, for
+    serving: the model casts them to that dtype at every use, so the cast
+    copy gives the same logits and is read at half the bytes of float32
+    masters. Every other leaf, and a leaf already at ``cfg.dtype``, is
+    passed through as it is."""
     dtype = jnp.dtype(cfg.dtype)
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     todo = [i for i, (path, leaf) in enumerate(flat)
-            if tuple(k.key for k in path) in _SERVED_IN_COMPUTE_DTYPE
+            if tuple(k.key for k in path) in leaves
             and leaf.dtype != dtype]
     if not todo:
         return params

@@ -42,7 +42,7 @@ class GenerationResult:
 
 
 def decode_ops(cfg, batch: int) -> list[tuple[int, Workload]]:
-    """The per-decode-step tensor workloads of an ArchConfig, as
+    """The per-decode-step dense projections of an ArchConfig, as
     ``[(count, Workload), ...]`` at the benchmarks/nets.py granularity (one
     entry per projection family, repeat counts for the layer stack).
 
@@ -50,6 +50,15 @@ def decode_ops(cfg, batch: int) -> list[tuple[int, Workload]]:
     edge-decode shape the paper tunes — larger batches to skinny matmuls.
     This is what a dispatch-aware :class:`Server` resolves every step, and
     what :func:`repro.core.dispatch.ensure_tuned` pre-tunes offline.
+
+    Only dense projections are modelled. For a mixture of experts the FFN
+    entries are the shared experts' (``n_shared_experts * moe_d_ff`` wide,
+    one expert's width where it has none; the leading dense layers' ``d_ff``
+    FFN is not listed); the router, the
+    held experts' grouped matmul (``jax.lax.ragged_dot`` over a routed row
+    count known only at run time) and its dispatch have no workload
+    family. Latent attention's projections (``wq``, ``wkv_a``, ``wkv_b``,
+    ``wo``) and the attention over any cache are not listed either.
     """
     dtype = cfg.dtype if cfg.dtype in ("float32", "bfloat16") else "bfloat16"
 
@@ -57,7 +66,9 @@ def decode_ops(cfg, batch: int) -> list[tuple[int, Workload]]:
         return (gemv(n, k, dtype) if batch == 1
                 else matmul(batch, n, k, dtype))
 
-    ff = cfg.moe_d_ff if (cfg.family == "moe" and cfg.moe_d_ff) else cfg.d_ff
+    ff = cfg.d_ff
+    if cfg.family == "moe":
+        ff = cfg.n_shared_experts * cfg.moe_d_ff or cfg.moe_d_ff
     n_up = 2 if cfg.act == "silu" else 1  # gated acts: up + gate projections
     return [
         (cfg.n_layers, proj(cfg.q_dim + 2 * cfg.kv_dim, cfg.d_model)),  # QKV
@@ -101,6 +112,8 @@ class Server:
             new.nbytes for old, new in zip(jax.tree.leaves(params),
                                            jax.tree.leaves(self.params))
             if new is not old))
+        if bundle.cfg.family == "moe":
+            tracing.count("repro.moe.experts_held", bundle.cfg.held_experts)
         self.max_len = max_len
         self.hw = hw
         self.serve_ops = list(serve_ops or ())
@@ -179,6 +192,8 @@ class Server:
         with tracing.span("repro.serve.prefill"):
             t0 = time.perf_counter()
             logits, cache = self._prefill(self.params, batch)
+            tracing.count("repro.serve.cache_bytes",
+                          sum(a.nbytes for a in jax.tree.leaves(cache)))
             step_logits = [logits[:, -1]]
             next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             jax.block_until_ready(next_tok)
