@@ -226,10 +226,14 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig):
         h, h2, c2 = _rec_decode(h, unit["rec2"], cfg, h2, c2)
         h = h[:, None]
         hn = L.rms_norm(h, unit["attn"]["ln1"], cfg.norm_eps)
-        out, k_c, v_c = L.attention_decode(hn, unit["attn"]["attn"], cfg,
-                                           k_c, v_c, pos, window,
-                                           static_window=window,
-                                           ring=window > 0)
+        out, k, v = L.attention_decode(hn, unit["attn"]["attn"], cfg, k_c,
+                                       v_c, pos, window,
+                                       static_window=window, ring=window > 0)
+        slot = L.cache_slot(pos, k_c.shape[1], window > 0)
+        k_c = jax.lax.dynamic_update_slice(k_c, k.astype(k_c.dtype),
+                                           (0, slot, 0, 0))
+        v_c = jax.lax.dynamic_update_slice(v_c, v.astype(v_c.dtype),
+                                           (0, slot, 0, 0))
         h = h + out
         hn = L.rms_norm(h, unit["attn"]["ln2"], cfg.norm_eps)
         h = h + L.mlp(hn, unit["attn"]["mlp"], cfg.act)

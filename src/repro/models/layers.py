@@ -325,15 +325,63 @@ def ring_store(k, cfg, max_len: int):
     return out.at[:, slots].set(tail)
 
 
+def cache_slot(pos, t: int, ring: bool = False):
+    """The slot of a T-long cache that position ``pos`` is written to:
+    ``pos``, or ``pos % T`` in a ring buffer."""
+    return jnp.mod(pos, t) if ring else pos
+
+
+def cache_layer(cache, layer):
+    """Layer ``layer`` of a stacked (L, B, T, ...) cache, for a decode step
+    to read inside its layer loop.
+
+    On the CPU the cache and the index pass an optimization barrier first.
+    That backend converts bf16 dot operands to f32, and XLA would otherwise
+    take the slice of a loop-invariant f32 copy of the whole stack, made
+    once before the loop: twice the cache's memory. Fenced, the slice and
+    its conversion stay inside the loop. On the TPU, which feeds bf16 to
+    the MXU, there is no fence, so the slice fuses into the attention that
+    reads it. The branch is taken for the platform the program is lowered
+    for."""
+    def fenced(cache, layer):
+        return jax.lax.optimization_barrier((cache, layer))
+
+    cache, layer = jax.lax.platform_dependent(
+        cache, layer, cpu=fenced, default=lambda c, i: (c, i))
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def with_row(cache, row, slot):
+    """``cache`` (B, T, ...) as it reads once ``row`` (B, 1, ...) is written
+    at ``slot``: the same values a write gives, selected where they are
+    read, so attention sees the new token without the cache being copied
+    to write it."""
+    at = jnp.arange(cache.shape[1]) == slot
+    at = at.reshape((1, -1) + (1,) * (cache.ndim - 2))
+    return jnp.where(at, row.astype(cache.dtype), cache)
+
+
+def write_rows(cache, rows, slot):
+    """Write every layer's new row, ``rows`` (L, B, 1, ...), into the
+    stacked (L, B, T, ...) ``cache`` at ``slot``: one update of L x B rows,
+    nothing else read or written, in place when the cache is donated."""
+    return jax.lax.dynamic_update_slice(
+        cache, rows.astype(cache.dtype), (0, 0, slot) + (0,) * (cache.ndim - 3))
+
+
 def attention_decode(x, p, cfg: ArchConfig, k_cache, v_cache, pos, window=-1,
                      mrope_positions=None, static_window: int | None = None,
                      ring: bool = False):
-    """Single-token decode. x (B,1,D); caches (B,T,Hkv,D); pos () int32.
+    """Single-token decode that leaves the cache to its caller. x (B,1,D);
+    caches (B,T,Hkv,D), read and not written; pos () int32.
 
+    Attention sees the token's own k and v at its slot (:func:`cache_slot`)
+    as a write there would leave them (:func:`with_row`).
     ``ring``: the cache is a ring buffer of length T (= the static window);
-    writes land at ``pos % T`` and key positions are reconstructed per slot.
+    the slot is ``pos % T`` and key positions are reconstructed per slot.
 
-    Returns (out, new_k_cache, new_v_cache)."""
+    Returns (out, k, v): k and v are the (B,1,Hkv,D) rows to write at the
+    slot."""
     b, s, _ = x.shape
     q, k, v = _qkv(x, p, cfg)
     positions = jnp.full((b, s), pos, jnp.int32)
@@ -344,17 +392,10 @@ def attention_decode(x, p, cfg: ArchConfig, k_cache, v_cache, pos, window=-1,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     t = k_cache.shape[1]
-    write_pos = jnp.mod(pos, t) if ring else pos
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
-                                           (0, write_pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
-                                           (0, write_pos, 0, 0))
+    slot = cache_slot(pos, t, ring)
+    k_use = with_row(k_cache, k, slot)
+    v_use = with_row(v_cache, v, slot)
     rows = jnp.full((s,), pos, jnp.int32)
-    # barriers pin the (CPU-backend) bf16->f32 dot-operand conversion inside
-    # the layer-scan body; without them XLA materializes a whole-stack f32
-    # copy of the (L, B, T, H, hd) cache around the loop (2x cache memory).
-    # On TPU bf16 feeds the MXU directly and the barriers are free.
-    k_use, v_use = jax.lax.optimization_barrier((k_cache, v_cache))
     if ring:
         cols = ring_positions(pos, t)
         cols = jnp.where(cols >= 0, cols, _COL_SENTINEL)
@@ -369,8 +410,7 @@ def attention_decode(x, p, cfg: ArchConfig, k_cache, v_cache, pos, window=-1,
         cols = jnp.arange(t, dtype=jnp.int32)
     out = _sdpa(q, k_use.astype(x.dtype), v_use.astype(x.dtype),
                 rows=rows, cols=cols, window=window, causal=True)
-    k_cache, v_cache = jax.lax.optimization_barrier((k_cache, v_cache))
-    return out @ p["wo"].astype(x.dtype), k_cache, v_cache
+    return out @ p["wo"].astype(x.dtype), k, v
 
 
 # ---------------------------------------------------------------------- mlp --

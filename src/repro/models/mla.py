@@ -42,6 +42,14 @@ from repro.models import layers as L
 # the batch and prompt a server prefills.
 PREFILL_ROWS = 8192
 
+# The cache's positions are allocated in multiples of the TPU tile's 128
+# lanes. At such a length the chip's default layouts of both leaves are the
+# ones the decode step's attention reads (the rope key's positions lie
+# along the lanes), so a step reads each layer's cache where it lies rather
+# than through a copy to another layout. The positions past ``max_len``
+# are never written and never visible.
+CACHE_ALIGN = 128
+
 
 def init(key, cfg: ArchConfig):
     d, h, c = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
@@ -57,8 +65,10 @@ def init(key, cfg: ArchConfig):
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=None):
+    """The latent ``ckv`` (L, B, T, C) and rope key ``kpe`` (L, B, T, r),
+    with T ``max_len`` rounded up to a multiple of CACHE_ALIGN."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    lead = (cfg.n_layers, batch, max_len)
+    lead = (cfg.n_layers, batch, -(-max_len // CACHE_ALIGN) * CACHE_ALIGN)
     return {"ckv": jnp.zeros(lead + (cfg.kv_lora_rank,), dtype),
             "kpe": jnp.zeros(lead + (cfg.qk_rope_head_dim,), dtype)}
 
@@ -130,20 +140,17 @@ def prefill(x, p, cfg: ArchConfig, positions):
 
 def decode(x, p, cfg: ArchConfig, ckv_cache, kpe_cache, pos):
     """One-token MLA, absorbed, over the latent cache. x (B, 1, D); caches
-    (B, T, C) and (B, T, r); pos () int32, the position written. Returns
-    (out (B, 1, D), ckv_cache, kpe_cache)."""
+    (B, T, C) and (B, T, r), read and not written, attended with the
+    token's own latent and rope key at ``pos`` as a write there would
+    leave them (``layers.with_row``); pos () int32. Returns (out (B, 1, D),
+    ckv (B, 1, C), kpe (B, 1, r)): the entries to write at ``pos``."""
     b = x.shape[0]
     h, v = cfg.n_heads, cfg.v_head_dim
     positions = jnp.full((b, 1), pos, jnp.int32)
     q_nope, q_pe = _query(x, p, cfg, positions)
     ckv, kpe = _latent(x, p, cfg, positions)
-    ckv_cache = jax.lax.dynamic_update_slice(
-        ckv_cache, ckv.astype(ckv_cache.dtype), (0, pos, 0))
-    kpe_cache = jax.lax.dynamic_update_slice(
-        kpe_cache, kpe.astype(kpe_cache.dtype), (0, pos, 0))
-    # as in layers.attention_decode: keep the (CPU backend's) operand
-    # conversion inside the layer loop, not around a whole-stack copy
-    c_use, k_use = jax.lax.optimization_barrier((ckv_cache, kpe_cache))
+    c_use = L.with_row(ckv_cache, ckv, pos)
+    k_use = L.with_row(kpe_cache, kpe, pos)
     with jax.named_scope("repro.mla.decode"):
         w_uk, w_uv = _up_weights(p, cfg, x.dtype)
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk)
@@ -157,6 +164,4 @@ def decode(x, p, cfg: ArchConfig, ckv_cache, kpe_cache, pos):
         w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
         o_lat = jnp.einsum("bht,btc->bhc", w, c_use.astype(x.dtype))
         o = jnp.einsum("bhc,chv->bhv", o_lat, w_uv).reshape(b, 1, h * v)
-    ckv_cache, kpe_cache = jax.lax.optimization_barrier(
-        (ckv_cache, kpe_cache))
-    return o @ p["wo"].astype(x.dtype), ckv_cache, kpe_cache
+    return o @ p["wo"].astype(x.dtype), ckv, kpe

@@ -355,7 +355,8 @@ def _attend(h, attn, cfg: ArchConfig, positions):
 
 
 def _attend_decode(h, attn, cfg: ArchConfig, c, pos):
-    """One-token attention over one layer's cache entries ``c``."""
+    """One-token attention over one layer's cache entries ``c``, read and
+    not written: (out, the token's entries {name: (B, 1, ...)})."""
     if cfg.mla:
         out, ckv, kpe = mla.decode(h, attn, cfg, c["ckv"], c["kpe"], pos)
         return out, {"ckv": ckv, "kpe": kpe}
@@ -410,29 +411,31 @@ def _serve_ffns(cfg: ArchConfig):
 
 
 def decode_step(params, cache, tokens, pos, cfg: ArchConfig):
-    """One-token decode through the dropless held-expert path. The stacked
-    cache rides in the scan carry and is updated in place per layer (see
-    :func:`transformer.decode_step`). Returns (logits (B, V), cache)."""
+    """One-token decode through the dropless held-expert path. Each layer
+    reads its slice of the stacked cache and returns its token's entries,
+    which one update writes after the layers (as
+    :func:`transformer.decode_step` does), so a donated cache is updated
+    in place. Returns (logits (B, V), cache)."""
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed(tokens, params, cfg, dtype)
 
+    rows = []
     for stack, ffn, first in _stacks(params, cfg, *_serve_ffns(cfg)):
-        def body(carry, per_layer, ffn=ffn):
-            x_c, c_all = carry
+        def body(x_c, per_layer, ffn=ffn):
             lp, li = per_layer
-            c = {name: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
-                 for name, a in c_all.items()}
+            c = {name: L.cache_layer(a, li) for name, a in cache.items()}
             h = L.rms_norm(x_c, lp["ln1"], cfg.norm_eps)
-            attn_out, c = _attend_decode(h, lp["attn"], cfg, c, pos)
+            attn_out, new = _attend_decode(h, lp["attn"], cfg, c, pos)
             x2 = x_c + attn_out
             h = L.rms_norm(x2, lp["ln2"], cfg.norm_eps)
-            c_all = {name: jax.lax.dynamic_update_index_in_dim(
-                a, c[name], li, 0) for name, a in c_all.items()}
-            return (x2 + ffn(h, lp), c_all), None
+            return x2 + ffn(h, lp), new
 
         n = jax.tree.leaves(stack)[0].shape[0]
         ids = first + jnp.arange(n, dtype=jnp.int32)
-        (x, cache), _ = jax.lax.scan(body, (x, cache), (stack, ids))
+        x, new = jax.lax.scan(body, x, (stack, ids))
+        rows.append(new)
+    cache = {name: L.write_rows(a, jnp.concatenate([r[name] for r in rows]),
+                                pos) for name, a in cache.items()}
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return L.unembed(x, params, cfg)[:, 0], cache
 

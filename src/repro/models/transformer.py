@@ -133,9 +133,12 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig, *,
                 mrope_positions=None):
     """One-token decode. tokens (B, 1); pos () int32 — write position.
 
-    The stacked (L, B, T, H, hd) cache rides in the scan *carry* and is
-    updated in place per layer (donation-aliased end to end) — scanning it
-    as xs/ys would stack a second full-cache copy per step.
+    Each layer reads its slice of the stacked (L, B, T, H, hd) cache, with
+    its token's k and v seen at their slot (``layers.with_row``), and
+    returns those rows; after the layer scan one update writes the L rows
+    at the slot (``layers.write_rows``). Nothing else of the cache is
+    written, so a cache donated to the compiled step is updated in place
+    (``Server`` donates it).
 
     Returns (logits (B, V), new_cache)."""
     dtype = jnp.dtype(cfg.dtype)
@@ -146,29 +149,27 @@ def decode_step(params, cache, tokens, pos, cfg: ArchConfig, *,
     if (cfg.window_pattern and cfg.window_pattern[0] > 0
             and all(w == cfg.window_pattern[0] for w in cfg.window_pattern)):
         uniform_w = cfg.window_pattern[0]
+    ring = uniform_w is not None
 
-    def body(carry, per_layer):
-        x_c, k_all, v_all = carry
+    def body(x_c, per_layer):
         lp, window, li = per_layer
-        k_c = jax.lax.dynamic_index_in_dim(k_all, li, 0, keepdims=False)
-        v_c = jax.lax.dynamic_index_in_dim(v_all, li, 0, keepdims=False)
         h = L.rms_norm(x_c, lp["ln1"], cfg.norm_eps)
-        attn_out, k_c, v_c = L.attention_decode(
-            h, lp["attn"], cfg, k_c, v_c, pos, window, mrope_positions,
-            static_window=uniform_w, ring=uniform_w is not None)
+        attn_out, k, v = L.attention_decode(
+            h, lp["attn"], cfg, L.cache_layer(cache["k"], li),
+            L.cache_layer(cache["v"], li), pos, window, mrope_positions,
+            static_window=uniform_w, ring=ring)
         x2 = x_c + attn_out
         h = L.rms_norm(x2, lp["ln2"], cfg.norm_eps)
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, k_c, li, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, v_c, li, 0)
-        return (x2 + L.mlp(h, lp["mlp"], cfg.act), k_all, v_all), None
+        return x2 + L.mlp(h, lp["mlp"], cfg.act), (k, v)
 
     layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], window_array(cfg), layer_ids))
+    x, (ks, vs) = jax.lax.scan(
+        body, x, (params["layers"], window_array(cfg), layer_ids))
+    slot = L.cache_slot(pos, cache["k"].shape[2], ring)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(x, params, cfg)
-    return logits[:, 0], {"k": new_k, "v": new_v}
+    return logits[:, 0], {"k": L.write_rows(cache["k"], ks, slot),
+                          "v": L.write_rows(cache["v"], vs, slot)}
 
 
 def prefill(params, tokens, cfg: ArchConfig, max_len: int, *,

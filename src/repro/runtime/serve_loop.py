@@ -124,8 +124,11 @@ class Server:
         self.batches = 0  # generate calls so far: each one's batch id
         self._prefill = jax.jit(
             lambda p, batch: bundle.prefill_fn(p, batch, max_len))
+        # the cache is donated: each step writes its token's row into it
+        # in place, and ``generate`` rebinds ``cache`` to every result
         self._decode = jax.jit(
-            lambda p, c, t, pos: bundle.decode_fn(p, c, t, pos))
+            lambda p, c, t, pos: bundle.decode_fn(p, c, t, pos),
+            donate_argnums=(1,))
 
     def resolve_dispatch(self) -> dict[str, int] | None:
         """One dispatch pass over the serve ops: provenance -> op count.
@@ -176,7 +179,10 @@ class Server:
         ``repro.serve.resolve_dispatch``, ``repro.serve.prefill`` and, per
         decode step, ``repro.serve.decode_dispatch`` (the host enqueues the
         step and its argmax) and ``repro.serve.token_fetch`` (the host waits
-        for the token)."""
+        for the token). Counters: ``repro.serve.cache_bytes``, the cache the
+        prefill returns, and ``repro.serve.cache_donated_bytes``, the part
+        of it the first decode step consumed as its donated buffer (0 when
+        donation did not engage)."""
         self.batches += 1
         with tracing.span("repro.serve.generate", batch=self.batches):
             return self._generate(prompts, n_steps, extra_batch)
@@ -203,6 +209,7 @@ class Server:
         # against n_steps: n_steps=0 emits nothing (tokens == prompts) and
         # the result always has exactly prompt + n_steps columns
         out = [np.asarray(next_tok)] if n_steps > 0 else []
+        prefilled = jax.tree.leaves(cache)
         t0 = time.perf_counter()
         for i in range(n_steps - 1):
             with tracing.span("repro.serve.decode_dispatch"):
@@ -211,6 +218,10 @@ class Server:
                                              next_tok[:, None], pos)
                 step_logits.append(logits)
                 next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if prefilled:  # what of the prefill's cache the step took over
+                tracing.count("repro.serve.cache_donated_bytes", sum(
+                    a.nbytes for a in prefilled if a.is_deleted()))
+                prefilled = None
             with tracing.span("repro.serve.token_fetch"):
                 out.append(np.asarray(next_tok))
         jax.block_until_ready(next_tok)

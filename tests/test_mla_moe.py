@@ -74,8 +74,9 @@ def test_absorbed_decode_matches_decompressed(prompt):
     pad = ((0, 0), (0, s - prompt), (0, 0))
     ckv, kpe = jnp.pad(ckv, pad), jnp.pad(kpe, pad)
     for t in range(prompt, s):
-        out, ckv, kpe = mla.decode(x[:, t:t + 1], p, cfg, ckv, kpe,
-                                   jnp.int32(t))
+        out, c, k = mla.decode(x[:, t:t + 1], p, cfg, ckv, kpe,
+                               jnp.int32(t))
+        ckv, kpe = ckv.at[:, t].set(c[:, 0]), kpe.at[:, t].set(k[:, 0])
         _close(out[:, 0], full[:, t], 1e-5)
 
 
@@ -180,5 +181,6 @@ def test_server_counts_experts_held_and_cache_bytes():
     counters = rec.summary()["counters"]
     assert counters["repro.moe.experts_held"] == 2
     latent = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    t_alloc = mla.CACHE_ALIGN  # 12 positions, rounded up to the tile
     assert counters["repro.serve.cache_bytes"] == (
-        cfg["num_hidden_layers"] * 2 * 12 * latent * 4)
+        cfg["num_hidden_layers"] * 2 * t_alloc * latent * 4)
