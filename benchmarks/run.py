@@ -29,11 +29,11 @@ sys.path.insert(0, _ROOT)
 sys.path.insert(0, os.path.join(_ROOT, "src"))
 
 from benchmarks import nets
-from repro.core import (AnalyticRunner, Fault, InterpretRunner,
-                        TuningDatabase, TuningSession, V5E, V5E_MXU256,
-                        V5E_VMEM32, V5E_VMEM64, INTERPRET, concretize,
-                        fixed_library_schedule, simulated_farm, space_for,
-                        tune, v1_distinct_configs, xla_latency)
+from repro.core import (AnalyticRunner, InterpretRunner, TuningDatabase,
+                        TuningSession, V5E, V5E_MXU256, V5E_VMEM32,
+                        V5E_VMEM64, INTERPRET, concretize,
+                        fixed_library_schedule, space_for, tune,
+                        v1_distinct_configs, xla_latency)
 from repro.core.space import instruction_census
 from repro.core import workload as W
 
@@ -323,11 +323,11 @@ def static_suite() -> None:
              f"diagnostics={len(diags)} hard=0")
 
 
-# ------------------------------------------------------------- board farm ----
+# ---------------------------------------------------- candidate batches ----
 
 def _candidate_population(wl, hw, limit=16):
     """Up to ``limit`` distinct valid schedules for one workload (the
-    candidate batch its tuning task would send to the boards)."""
+    candidate batch its tuning task would measure)."""
     from repro.core import TraceSampler
 
     space = space_for(wl, hw)
@@ -341,112 +341,6 @@ def _candidate_population(wl, hw, limit=16):
             sigs.add(s.signature())
             out.append(s)
     return out
-
-
-def farm_suite(trials: int = 4) -> None:
-    """Measurement-farm scaling on the net-interp suite models (bert-tiny +
-    anomaly-detection). Simulated boards with a 50 ms per-candidate delay
-    stand in for the paper's 9-12 s FPGA measurements; latencies are
-    deterministic (analytic), so every farm size measures identical
-    candidates and the wall-time delta is pure dispatch.
-
-    Rows: (1) per-task batch measurement of each workload's candidate
-    population — the farm's core operation; wall-time must fall >= 1.5x
-    at 4 boards vs 1 (the CI farm smoke asserts it); (2) the full
-    TuningSession through the farm (wall / utilization / requeues /
-    overlap); (2b) the same heterogeneous-speed 4-board session driven
-    multi-queue (every driver's batches in flight across the farm at once)
-    vs single-FIFO (one measurement thread, the pre-scheduler path) — the
-    session must run >= 1.3x faster multi-queue with bit-identical
-    per-workload results (the CI farm smoke asserts both); (3) the same
-    session with one board dying mid-run."""
-    from repro.core import dedup_workloads
-
-    ops = (list(nets.NETWORKS["bert-tiny"]())
-           + list(nets.NETWORKS["anomaly-detection"]()))
-    unique = dedup_workloads(ops)
-    delay_s = 0.05
-    pops = [(wl, _candidate_population(wl, V5E)) for _, wl in unique]
-    n_cands = sum(len(p) for _, p in pops)
-    # (1) batch measurement of the candidate populations, per board count
-    walls: dict[int, float] = {}
-    for n_boards in (1, 2, 4):
-        farm = simulated_farm(n_boards, V5E, delay_s=delay_s,
-                              straggler_timeout_s=30.0)
-        t0 = time.perf_counter()
-        for wl, pop in pops:
-            farm.run_batch(wl, pop)
-        walls[n_boards] = time.perf_counter() - t0
-        summary = farm.farm_summary()
-        utils = [b["utilization"] for b in summary["boards"].values()]
-        emit(f"farm/boards{n_boards}/measure_wall",
-             walls[n_boards] * 1e6,
-             f"speedup_vs_1board={walls[1] / walls[n_boards]:.2f}x "
-             f"candidates={n_cands} mean_util={np.mean(utils):.2f}")
-    assert walls[1] / walls[4] >= 1.5, (
-        f"farm scaling regressed: 4 boards only "
-        f"{walls[1] / walls[4]:.2f}x faster than 1")
-    # (2) end-to-end tuning session through the farm
-    budget = trials * len(unique)
-    for n_boards in (1, 4):
-        farm = simulated_farm(n_boards, V5E, delay_s=delay_s,
-                              straggler_timeout_s=30.0)
-        res = TuningSession(V5E, farm, database=TuningDatabase()).tune_model(
-            ops, total_trials=budget, seed=0, model="farm-net-interp")
-        summary = res.board_stats
-        utils = [b["utilization"] for b in summary["boards"].values()]
-        emit(f"farm/session_boards{n_boards}/tune_wall",
-             res.wall_time_s * 1e6,
-             f"trials={res.total_trials} mean_util={np.mean(utils):.2f} "
-             f"overlap={res.overlap_fraction:.2f} "
-             f"requeues={summary['requeues']}")
-    # (2b) multi-queue vs single-FIFO sessions on a heterogeneous farm:
-    # board speeds vary 4x (the real-RVV-silicon situation), so the
-    # single-FIFO path pays a barrier at every batch boundary while the
-    # multi-queue scheduler keeps every board pulling shards from any
-    # in-flight batch. Same seed, same candidates — the wall delta is
-    # pure scheduling, and the per-workload results must agree exactly.
-    # Delays are scaled up vs (1)/(2) so measurement dominates host-side
-    # search, the paper's FPGA regime (9-12 s per candidate there).
-    hetero = [0.08, 0.16, 0.24, 0.32]
-    sessions = {}
-    for mode, multi_queue in (("single_fifo", False), ("multi_queue", True)):
-        farm = simulated_farm(4, V5E, delay_s=hetero,
-                              straggler_timeout_s=30.0)
-        res = TuningSession(V5E, farm, database=TuningDatabase(), batch=4,
-                            multi_queue=multi_queue).tune_model(
-            ops, total_trials=budget, seed=0, model=f"farm-{mode}")
-        sessions[mode] = res
-        utils = [b["utilization"]
-                 for b in res.board_stats["boards"].values()]
-        emit(f"farm/session4_hetero_{mode}/tune_wall", res.wall_time_s * 1e6,
-             f"trials={res.total_trials} mean_util={np.mean(utils):.2f} "
-             f"overlap={res.overlap_fraction:.2f}")
-    for a, b in zip(sessions["single_fifo"].reports,
-                    sessions["multi_queue"].reports):
-        assert (a.best_schedule == b.best_schedule
-                and a.best_latency == b.best_latency
-                and a.trials == b.trials), (
-            f"multi-queue session diverged from single-FIFO on "
-            f"{a.workload.key()}")
-    gain = (sessions["single_fifo"].wall_time_s
-            / sessions["multi_queue"].wall_time_s)
-    emit("farm/session4_hetero/multi_queue_speedup", gain, f"{gain:.2f}x")
-    assert gain >= 1.3, (
-        f"multi-queue session only {gain:.2f}x faster than single-FIFO "
-        f"at 4 heterogeneous boards (>= 1.3x required)")
-    # (3) fault tolerance at benchmark scale: one of four boards dies
-    # mid-run, the survivors absorb its candidates, results stay complete
-    farm = simulated_farm(4, V5E, delay_s=delay_s,
-                          faults={0: [Fault(batch=3, kind="die")]},
-                          straggler_timeout_s=30.0)
-    res = TuningSession(V5E, farm, database=TuningDatabase()).tune_model(
-        ops, total_trials=budget, seed=0, model="farm-faulty")
-    summary = res.board_stats
-    emit("farm/session_boards4_one_dies/tune_wall", res.wall_time_s * 1e6,
-         f"trials={res.total_trials} "
-         f"requeues={summary['requeues']} "
-         f"invalid_after_retries={summary['invalid_after_retries']}")
 
 
 # ------------------------------------------------------ learned proposals ----
@@ -510,71 +404,18 @@ def learn_suite(trials: int = 48) -> None:
         "count on any workload")
 
 
-# ------------------------------------------------- adaptive scheduling ----
+# ------------------------------------------------- budget reallocation ----
 
-def sched_suite(trials: int = 12) -> None:
-    """Adaptive measurement scheduling (ISSUE 8): utilization-driven
-    speculation depth, entropy-gated budget reallocation, and priority
-    preemption. Doubles as the CI sched smoke; every claim is asserted.
+def sched_suite() -> None:
+    """Entropy-gated budget reallocation. Doubles as the CI sched smoke;
+    every claim is asserted.
 
-    Rows: (1) interleaved session on a heterogeneous 4-board farm, fixed
-    depth 1 vs ``adaptive_depth=True`` — the depth policy must buy >= 1.1x
-    wall on its own (same budget, same seed; trajectories legitimately
-    differ because speculation measures different candidates); plus a
-    single-workload ``tune(adaptive_depth=True)`` whose
-    ``TuneResult.depth_trace`` must show the depth actually growing.
-    (2) entropy stop policy at deterministic depth 1: vs the no-policy
-    baseline it must spend strictly fewer total measurements, fewer on at
-    least one workload, and reach equal-or-better best latency on *every*
+    At deterministic depth 1, vs the no-policy baseline, the entropy stop
+    policy must spend strictly fewer total measurements, fewer on at least
+    one workload, and reach equal-or-better best latency on *every*
     workload (curtailed searches release budget; still-improving ones draw
-    it back through the shared ledger at ``reallocate_fraction=0.5``).
-    (3) farm priority preemption: a small high-priority batch submitted
-    behind a large backlog must complete in well under half the backlog's
-    wall (queued low-priority shards yield; in-flight shards finish), with
-    preemptions counted and per-candidate results identical to an
-    unprioritized run."""
-    # (1) adaptive speculation depth on a heterogeneous farm: at fixed
-    # depth 1 each driver keeps at most one batch in flight, so fast
-    # boards idle at every reconcile boundary; the policy grows depth
-    # per-driver while the farm's busy-fraction is below target.
-    ops = [(1, W.matmul(512, 512, 512, "bfloat16")),
-           (1, W.gemv(2048, 4096, "bfloat16"))]
-    hetero = [0.02, 0.04, 0.06, 0.08]
-    budget = max(trials, 8) * len(ops)
-    sessions = {}
-    for mode, adaptive in (("fixed_depth", False), ("adaptive_depth", True)):
-        farm = simulated_farm(4, V5E, delay_s=hetero,
-                              straggler_timeout_s=30.0)
-        res = TuningSession(V5E, farm, database=TuningDatabase(), batch=2,
-                            adaptive_depth=adaptive, max_depth=4,
-                            depth_window_s=1.0).tune_model(
-            ops, total_trials=budget, seed=0, model=f"sched-{mode}")
-        sessions[mode] = res
-        utils = [b["utilization"] for b in res.board_stats["boards"].values()]
-        emit(f"sched/session4_hetero_{mode}/tune_wall",
-             res.wall_time_s * 1e6,
-             f"trials={res.total_trials} mean_util={np.mean(utils):.2f} "
-             f"overlap={res.overlap_fraction:.2f} "
-             f"adaptive={res.adaptive_depth}")
-    gain = (sessions["fixed_depth"].wall_time_s
-            / sessions["adaptive_depth"].wall_time_s)
-    emit("sched/session4_hetero/adaptive_depth_speedup", gain, f"{gain:.2f}x")
-    assert gain >= 1.1, (
-        f"adaptive depth only {gain:.2f}x faster than fixed depth 1 on a "
-        f"heterogeneous 4-board farm (>= 1.1x required)")
-    # depth-trace observability: one workload, one farm — the trace must
-    # show the policy actually raising the effective depth beyond base
-    farm = simulated_farm(4, V5E, delay_s=hetero, straggler_timeout_s=30.0)
-    res = tune(W.matmul(512, 512, 512, "bfloat16"), V5E, farm,
-               trials=max(trials, 8) * 2, seed=0, batch=2,
-               pipeline_depth=2, adaptive_depth=True, max_depth=4)
-    peak = max(d for _, d in res.depth_trace)
-    emit("sched/depth_trace/peak_depth", float(peak),
-         f"trace={res.depth_trace}")
-    assert peak > 2, (
-        f"adaptive depth never grew past the base depth: {res.depth_trace}")
-
-    # (2) entropy-gated budget reallocation, deterministic regime: equal
+    it back through the shared ledger at ``reallocate_fraction=0.5``)."""
+    # entropy-gated budget reallocation, deterministic regime: equal
     # per-workload budgets (floor = share), analytic latencies, forced
     # interleave at depth 1 so histories depend only on each driver's own
     # reconcile order. The policy curtails converged searches and re-grants
@@ -619,32 +460,6 @@ def sched_suite(trials: int = 12) -> None:
         f"{base.total_trials}: must be strictly fewer")
     assert fewer >= 1, (
         "entropy policy never spent fewer measurements on any workload")
-
-    # (3) priority preemption on the farm: 2 boards, a 16-candidate
-    # backlog, then a 2-candidate priority-5 batch. Queued backlog shards
-    # yield to it (counted as preemptions); results match a plain run.
-    wl = W.matmul(256, 256, 256, "bfloat16")
-    pop = _candidate_population(wl, V5E, limit=18)
-    bulk_pop, hi_pop = pop[:16], pop[16:]
-    farm = simulated_farm(2, V5E, delay_s=0.02, straggler_timeout_s=30.0)
-    t0 = time.perf_counter()
-    bulk = farm.submit_batch(wl, bulk_pop, priority=0)
-    hi = farm.submit_batch(wl, hi_pop, priority=5)
-    hi_lats = hi.result()
-    t_hi = time.perf_counter() - t0
-    bulk_lats = bulk.result()
-    t_all = time.perf_counter() - t0
-    preempts = farm.farm_summary()["preemptions"]
-    emit("sched/priority/hipri_wall", t_hi * 1e6,
-         f"backlog_wall={t_all * 1e6:.0f} preemptions={preempts}")
-    assert t_hi < 0.5 * t_all, (
-        f"high-priority batch took {t_hi:.3f}s of the backlog's "
-        f"{t_all:.3f}s wall: the priority queue is not preempting")
-    assert preempts >= 1, "no preemption was counted for the priority jump"
-    plain = simulated_farm(2, V5E, delay_s=0.02, straggler_timeout_s=30.0)
-    assert (plain.run_batch(wl, bulk_pop) == bulk_lats
-            and plain.run_batch(wl, hi_pop) == hi_lats), (
-        "priorities changed measured results (must only change order)")
 
 
 # ---------------------------------------------------- cross-hw transfer ----
@@ -721,13 +536,12 @@ def session_report(db: TuningDatabase) -> list[tuple[str, float, str]]:
             entropy = s.get("proposal_entropy")
             entropy_txt = (f"{entropy:.2f}"
                            if isinstance(entropy, (int, float)) else "n/a")
-            # adaptation column: curtailed searches / reallocated trials /
-            # priority preemptions (all 0 for non-adaptive sessions, n/a
-            # for summaries recorded before the adaptation layer existed)
+            # adaptation column: curtailed searches / reallocated trials
+            # (0 for non-adaptive sessions, n/a for summaries recorded
+            # before the adaptation layer existed)
             if "stopped_early" in s:
                 adapt_txt = (f"stops={s.get('stopped_early', 0)}"
-                             f"/realloc={s.get('reallocated_trials', 0)}"
-                             f"/preempt={s.get('preemptions', 0)}")
+                             f"/realloc={s.get('reallocated_trials', 0)}")
             else:
                 adapt_txt = "stops=n/a"
             # build-cache hit rate of the session's kernel builds (n/a for
@@ -817,26 +631,6 @@ def tuning_cost() -> None:
          f"overlap={inter.overlap_fraction:.4f} "
          f"wall_vs_serial={serial.wall_time_s / inter.wall_time_s:.2f}x "
          f"(same candidates)")
-    # multi-queue scheduler smoke (default suite): the same interleaved
-    # session through a simulated board farm, single-FIFO vs multi-queue —
-    # per-workload results must be bit-identical (the determinism contract
-    # of the MeasureScheduler; the farm suite asserts the wall-time win).
-    farm_ops = [(1, W.matmul(128, 128, 128, "bfloat16")), (2, W.vmacc(64, 256))]
-    smoke = {}
-    for mode, mq in (("single_fifo", False), ("multi_queue", True)):
-        farm = simulated_farm(3, V5E, delay_s=[0.002, 0.004, 0.006],
-                              straggler_timeout_s=30.0)
-        smoke[mode] = TuningSession(
-            V5E, farm, database=TuningDatabase(),
-            multi_queue=mq).tune_model(farm_ops, total_trials=16, seed=0)
-        emit(f"tuning_cost/scheduler_smoke/{mode}_wall",
-             smoke[mode].wall_time_s * 1e6,
-             f"overlap={smoke[mode].overlap_fraction:.2f}")
-    for a, b in zip(smoke["single_fifo"].reports,
-                    smoke["multi_queue"].reports):
-        assert (a.best_schedule == b.best_schedule
-                and a.best_latency == b.best_latency), (
-            f"scheduler smoke: multi-queue diverged on {a.workload.key()}")
 
 
 # ------------------------------------------------- continuous tuning ----
@@ -1058,7 +852,6 @@ SUITES = {
     "trace": trace_analysis,
     "networks": networks,
     "tuning_cost": tuning_cost,
-    "farm": farm_suite,
     "transfer": transfer_study,
     "learn": learn_suite,
     "sched": sched_suite,
@@ -1066,7 +859,7 @@ SUITES = {
     "cache": cache_suite,
 }
 
-_NO_TRIALS_ARG = ("tuning_cost", "space", "static")
+_NO_TRIALS_ARG = ("tuning_cost", "space", "static", "sched")
 
 
 def main() -> None:

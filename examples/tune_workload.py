@@ -35,9 +35,9 @@ def main() -> None:
           f"library {t_fixed * 1e6:.1f} us "
           f"-> {(1 - t_tuned / t_fixed) * 100:.0f}% latency improvement")
     # The analytic runner measures instantaneously, so this session runs
-    # serially; on an overlap-capable runner (InterpretRunner /
-    # SubprocessRunner) the session interleaves one workload's measurement
-    # with another's evolution and reports the hidden fraction here.
+    # serially; on an overlap-capable runner (InterpretRunner) the session
+    # interleaves one workload's measurement with another's evolution and
+    # reports the hidden fraction here.
     print(f"session wall time: {result.wall_time_s:.1f}s "
           f"(interleaved={result.interleaved}, "
           f"overlap {result.overlap_fraction:.0%})")

@@ -24,13 +24,9 @@ from repro.core.cost_model import (RidgeCostModel, features,
                                    pretrain_from_database)
 from repro.core.runner import (AnalyticRunner, DeviceRunner,
                                InterpretRunner, run_batch, xla_latency)
-from repro.core.measure_pool import MeasurePool, SubprocessRunner
 from repro.core.measure_scheduler import (AdaptiveDepthPolicy,
                                           MeasureScheduler, MeasureTicket,
                                           SerialMeasureQueue)
-from repro.core.board_farm import (Board, BoardDied, BoardFarm, BoardStats,
-                                   Fault, FarmDead, LocalBoard,
-                                   SimulatedBoard, simulated_farm)
 from repro.core.database import (TuningDatabase, default_db_path,
                                  global_database, reset_global_database)
 from repro.core.tuner import tune, TuneDriver, TuneResult
@@ -54,12 +50,9 @@ __all__ = [
     "tile_candidates", "v1_distinct_configs", "TraceSampler",
     "Diagnostic", "SpaceReport", "analyze", "lint_space", "pruned_program",
     "RidgeCostModel", "features", "pretrain_from_database",
-    "DeviceRunner", "InterpretRunner", "AnalyticRunner", "SubprocessRunner",
-    "MeasurePool",
+    "DeviceRunner", "InterpretRunner", "AnalyticRunner",
     "AdaptiveDepthPolicy", "MeasureScheduler", "MeasureTicket",
     "SerialMeasureQueue",
-    "Board", "BoardDied", "BoardFarm", "BoardStats", "Fault", "FarmDead",
-    "LocalBoard", "SimulatedBoard", "simulated_farm",
     "run_batch", "xla_latency",
     "TuningDatabase", "default_db_path", "global_database",
     "reset_global_database",

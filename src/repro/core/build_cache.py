@@ -16,24 +16,21 @@ wiring:
 
 - ``InterpretRunner._prepare`` builds through ``kernels.build`` (and keys
   its own validated-kernel fast path off the same signature);
-- ``MeasurePool`` workers are persistent spawn processes — module state
-  survives across tasks, so each worker's global cache warms up once and
-  serves every later candidate with the same signature;
-- ``LocalBoard`` feeds its pool per-candidate and inherits the worker-side
-  cache the same way;
+- ``DeviceRunner._prepare`` builds through it before compiling ahead of
+  time (the tune cell empties it between rounds with
+  :func:`clear_build_cache`);
 - the serving path (``dispatch.kernel_params`` →
   ``runtime.serve_loop.Server``) reuses one built kernel per distinct
   signature across generate calls — steady state performs zero builds.
 
 Counters (hits/misses/evictions) are value-typed and cheap; they surface
-through ``TuneResult.build_cache``, ``BoardFarm.farm_summary()``, and
-``SessionResult.summary()``. The cache never changes what a build returns —
-only whether the builder runs — so fixed-seed tuning histories are
-bit-identical with it enabled (tested). Invalidation: the cache holds
-callables, not results, and the builder is deterministic per signature, so
-nothing in normal operation invalidates it; :func:`clear_build_cache`
-exists for tests that monkeypatch kernel modules and for bounding memory
-explicitly.
+through ``TuneResult.build_cache`` and ``SessionResult.summary()``. The
+cache never changes what a build returns — only whether the builder runs —
+so fixed-seed tuning histories are bit-identical with it enabled (tested).
+Invalidation: the cache holds callables, not results, and the builder is
+deterministic per signature, so nothing in normal operation invalidates
+it; :func:`clear_build_cache` exists for tests that monkeypatch kernel
+modules and for bounding memory explicitly.
 """
 
 from __future__ import annotations
@@ -133,7 +130,7 @@ def global_build_cache() -> BuildCache:
 
 def build_cache_stats() -> dict:
     """Counter snapshot of the process-wide cache (the ``TuneResult`` /
-    ``farm_summary`` / session-report feed)."""
+    session-report feed)."""
     return _GLOBAL.stats()
 
 
@@ -144,7 +141,7 @@ def clear_build_cache() -> None:
 
 def stats_delta(after: dict, before: dict) -> dict:
     """Counter delta between two :func:`build_cache_stats` snapshots —
-    what one tuning run / farm session contributed. Size/capacity report
+    what one tuning run / session contributed. Size/capacity report
     the ``after`` state (they are levels, not counters)."""
     out = {k: after.get(k, 0) - before.get(k, 0)
            for k in ("hits", "misses", "evictions")}

@@ -48,8 +48,8 @@ database hot-swap reuses every kernel whose concrete lowering didn't
 change. Those caches are value-keyed and never go stale on a database
 swap, so ``invalidate_dispatch_caches`` deliberately leaves them alone.
 Measurement-side batch dedup (the ``dedup`` knob on
-``InterpretRunner``/``SubprocessRunner``/``BoardFarm``) is the tuning-path
-sibling of the same signature key — off by default, see ``runner.py``.
+``InterpretRunner``/``AnalyticRunner``) is the tuning-path sibling of the
+same signature key — off by default, see ``runner.py``.
 """
 
 from __future__ import annotations

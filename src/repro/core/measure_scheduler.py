@@ -1,78 +1,55 @@
-"""Adaptive multi-queue measurement scheduling.
+"""Measurement scheduling for the pipelined and interleaved tuner.
 
-On the paper's board farm, measurement wall-time dominates tuning; PR 4's
-:class:`~repro.core.board_farm.BoardFarm` parallelized *within* one candidate
-batch, and the multi-queue scheduler here (PR 5) keeps batches from many
-drivers in flight at once so boards never idle at batch boundaries. This
-module now also owns the *adaptation* layer (PR 8): how deep each driver
-speculates is a policy decision driven by observed farm utilization, and
-batches carry priority classes so interactive work preempts bulk sweeps.
+The tuner measures synchronously (``runner.run_batch``) on runners that are
+not ``overlap_capable`` — the analytic model and ``DeviceRunner``, the chip
+path. This module is the other path: for ``overlap_capable`` runners
+(``InterpretRunner``) the tuner keeps proposed batches in flight while it
+evolves the next generation, and a :class:`~repro.core.session.
+TuningSession` feeds every workload's driver through one queue. The
+session's fixed-library baselines go through it too, on every runner.
 
 The pieces:
 
-- **Async submission protocol** (duck-typed on ``Runner``): a runner may
-  expose ``submit_batch(workload, schedules) -> ticket`` returning a
-  :class:`MeasureTicket` (a future: ``done()``/``result()``) plus a
-  ``max_inflight`` capacity hint — how many submitted batches can make
-  *physical* progress concurrently (1 for a single measurement target; a
-  board farm reports its board count). Backends that additionally declare
-  ``supports_priority`` accept a ``priority=`` keyword on ``submit_batch``
-  and dispatch higher-priority batches first.
-- :class:`SerialMeasureQueue` — the default adapter wrapping any synchronous
-  ``run_batch`` runner behind one measurement thread, so
-  ``AnalyticRunner``/``InterpretRunner``/``SubprocessRunner`` need no
-  changes. The queue is priority-ordered (FIFO within a priority class), so
-  even single-target runners let an interactive job jump a bulk backlog;
-  with every submission at the default priority it is exactly the old
-  single-FIFO pipeline (the determinism baseline).
-- :class:`MeasureScheduler` — holds many tickets from many submitters
-  (drivers) in flight at once, hands back completed batches **per-submitter
-  FIFO** (the determinism contract: each driver reconciles its own batches
-  in submission order; *which* driver reconciles next may follow completion
-  and priority, which never leaks into any driver's trajectory), and tracks
-  real busy/wait *intervals* — per submitter — so measurement/search
-  overlap, utilization, and per-driver wait attribution are span-accurate
-  under concurrency instead of estimated from summed totals.
+- :class:`MeasureTicket` — a future for one submitted batch
+  (``done()``/``result()``), carrying the interval the runner actually
+  spent measuring it.
+- :class:`SerialMeasureQueue` — one measurement thread over a synchronous
+  ``run_batch`` runner. Batches are measured in submission order, one at a
+  time; a runner error fails its ticket and ``result()`` re-raises it.
+- :class:`MeasureScheduler` — holds batches from several submitters
+  (drivers) in flight on that queue and hands them back **per-submitter
+  FIFO**: each driver reconciles its own batches in submission order,
+  which is what keeps a fixed seed's history independent of measurement
+  timing. It records real busy/wait *intervals* per submitter, so overlap
+  and per-driver wait attribution are span-accurate.
 - :class:`AdaptiveDepthPolicy` — the utilization-driven speculation-depth
   controller ``tuner.run_scheduled`` consults when adaptation is enabled.
   It grows a driver's effective depth beyond the requested
-  ``pipeline_depth`` (bounded by ``max_depth`` and the backend's
-  ``max_inflight`` hint) while the farm's busy-fraction over a sliding
+  ``pipeline_depth`` (bounded by ``max_depth`` and the runner's
+  ``max_inflight`` hint) while the queue's busy-fraction over a sliding
   window sits below target, and shrinks it back toward the base depth when
   reconciliation lag — batches evolved against constant-liar predictions
   that were later corrected — exceeds a threshold. The policy never reads a
   clock: its "now" is derived from the scheduler's recorded span intervals
-  (:meth:`MeasureScheduler.busy_fraction`), so an adaptive run is
-  reproducible given a scripted clock (simulated boards with scripted
-  delays), and ``tools/lint_invariants.py`` structurally forbids wall-clock
-  reads inside policy classes. Adaptation is **off by default**: with it
-  disabled, fixed-seed histories are bit-identical to the non-adaptive
-  scheduler.
+  (:meth:`MeasureScheduler.busy_fraction`), and ``tools/lint_invariants.py``
+  structurally forbids wall-clock reads inside policy classes. Adaptation
+  is **off by default**: with it disabled, fixed-seed histories are
+  bit-identical to the non-adaptive scheduler.
 
-``tuner.run_scheduled`` (and through it ``tune`` and
-``TuningSession``) is built on this scheduler; ``BoardFarm`` implements the
-protocol natively with a persistent cross-batch work-stealing dispatcher
-whose pull order is priority-aware with an anti-starvation aging credit.
-
-Statically-invalid work is refused before it reaches a backend: schedules
+Statically-invalid work is refused before it reaches the runner: schedules
 the feasibility analyzer (``core/static_analysis.py``) proves can never
 validate come back ``INVALID`` from a screened ticket without occupying the
-measurement thread or a board (``static_rejected`` counts them). Backends
-that screen natively (``BoardFarm.static_screens``) are left to do it
-themselves so rejections are counted exactly once.
+measurement thread (``static_rejected`` counts them).
 
 Caching and dedup live *below* this layer: the content-addressed build
-cache (``core/build_cache.py``) and the per-batch signature dedup knobs
-belong to the backends (``InterpretRunner``/``SubprocessRunner``/
-``BoardFarm``), which always fulfil tickets position-aligned with the
-submitted schedules — so the scheduler's per-submitter FIFO reconciliation
-and determinism contract are untouched by whether a backend measured every
-candidate or fanned a representative's latency out to duplicates.
+cache (``core/build_cache.py``) and the runners' per-batch ``dedup`` knobs
+always return latencies position-aligned with the submitted schedules, so
+per-submitter FIFO reconciliation is untouched by whether a runner measured
+every candidate or fanned a representative's latency out to duplicates.
 """
 
 from __future__ import annotations
 
-import itertools
 import queue
 import threading
 import time
@@ -95,8 +72,7 @@ class MeasureTicket:
     batch (first dispatch to completion), not when it sat queued — the raw
     material for span-accurate overlap accounting. Backends fulfil a ticket
     with :meth:`_complete` (latencies aligned with the submitted schedules)
-    or :meth:`_fail` (an exception ``result()`` re-raises, e.g.
-    :class:`~repro.core.board_farm.FarmDead`).
+    or :meth:`_fail` (an exception ``result()`` re-raises).
     """
 
     def __init__(self, workload: Workload, schedules: Sequence[Schedule]):
@@ -105,7 +81,6 @@ class MeasureTicket:
         self.t_start: float | None = None  # measurement actually began
         self.t_end: float | None = None
         self._event = threading.Event()
-        self._listeners: list[threading.Event] = []
         self._latencies: list[float] | None = None
         self._error: BaseException | None = None
 
@@ -114,33 +89,24 @@ class MeasureTicket:
         if self.t_start is None:
             self.t_start = time.monotonic()
 
-    def _notify(self) -> None:
-        self._event.set()
-        for listener in list(self._listeners):
-            listener.set()
-
     def _complete(self, latencies: Sequence[float]) -> None:
         self._mark_started()
         self.t_end = time.monotonic()
         self._latencies = list(latencies)
-        self._notify()
+        self._event.set()
 
     def _fail(self, error: BaseException) -> None:
         self.t_end = time.monotonic()
         self._error = error
-        self._notify()
-
-    def subscribe(self, event: threading.Event) -> None:
-        """Register a shared wake-up event set on completion (the
-        scheduler's wait-for-any primitive). Consumers must tolerate a
-        spurious or slightly-late wake (they re-scan on wake anyway)."""
-        self._listeners.append(event)
-        if self._event.is_set():
-            event.set()
+        self._event.set()
 
     # ---- consumer side ---------------------------------------------------------
     def done(self) -> bool:
         return self._event.is_set()
+
+    def wait(self) -> None:
+        """Block until the ticket is fulfilled, without raising its error."""
+        self._event.wait()
 
     def result(self, timeout: float | None = None) -> list[float]:
         if not self._event.wait(timeout):
@@ -179,16 +145,14 @@ class _ScreenedTicket(MeasureTicket):
         if inner is None:
             self._complete([_INVALID] * len(self.schedules))
 
-    def subscribe(self, event: threading.Event) -> None:
-        if self._inner is None:
-            super().subscribe(event)
-        else:
-            self._inner.subscribe(event)
-
     def done(self) -> bool:
         if self._inner is None:
             return super().done()
         return self._inner.done()
+
+    def wait(self) -> None:
+        if self._inner is not None:
+            self._inner.wait()
 
     def result(self, timeout: float | None = None) -> list[float]:
         if self._inner is None:
@@ -212,27 +176,15 @@ class _ScreenedTicket(MeasureTicket):
 
 
 class SerialMeasureQueue:
-    """Default async adapter: one measurement thread over a synchronous
-    runner, packaged behind the submission protocol so runners without a
-    native ``submit_batch`` need no changes. ``max_inflight = 1``: extra
-    submissions queue behind the single measurement thread.
-
-    The queue is priority-ordered: a later high-priority submission is
-    measured before earlier default-priority backlog (FIFO within a
-    priority class, so all-default-priority traffic reproduces the old
-    single-FIFO pipeline exactly — the determinism baseline the multi-queue
-    benchmarks compare against). An in-progress batch is never interrupted;
-    preemption is at batch granularity."""
-
-    max_inflight = 1
-    supports_priority = True
+    """One measurement thread over a synchronous runner, behind the
+    ticket protocol: batches are measured one at a time, in submission
+    order."""
 
     def __init__(self, runner):
         self.runner = runner
-        # entries: (-priority, submission seq, ticket); the close sentinel
-        # sorts last so pending work drains before the thread exits
-        self._q: queue.PriorityQueue = queue.PriorityQueue()
-        self._seq = itertools.count()
+        # tickets in submission order; the close sentinel (None) is queued
+        # after pending work, so that work drains before the thread exits
+        self._q: queue.Queue = queue.Queue()
         self._thread: threading.Thread | None = None
         self._closed = False
 
@@ -252,8 +204,8 @@ class SerialMeasureQueue:
         from repro.core.runner import run_batch as _run_batch
 
         while True:
-            _, _, ticket = self._q.get()
-            if ticket is None:  # close sentinel (sorts after pending work)
+            ticket = self._q.get()
+            if ticket is None:  # close sentinel
                 return
             ticket._mark_started()
             try:
@@ -265,19 +217,18 @@ class SerialMeasureQueue:
                 ticket._complete(lats)
 
     def submit_batch(self, workload: Workload,
-                     schedules: Sequence[Schedule],
-                     priority: int = 0) -> MeasureTicket:
+                     schedules: Sequence[Schedule]) -> MeasureTicket:
         if self._closed:
             raise RuntimeError("measurement queue is closed")
         ticket = MeasureTicket(workload, schedules)
         self._ensure_thread()
-        self._q.put((-int(priority), next(self._seq), ticket))
+        self._q.put(ticket)
         return ticket
 
     def close(self) -> None:
         self._closed = True
         if self._thread is not None:
-            self._q.put((float("inf"), next(self._seq), None))
+            self._q.put(None)
             self._thread.join(timeout=5.0)
             self._thread = None
 
@@ -307,70 +258,45 @@ def _clipped_length(intervals: Sequence[tuple[float, float]],
 class _Entry:
     """One in-flight submission; ordering is the _fifo deque's position."""
 
-    __slots__ = ("key", "batch", "ticket", "priority")
+    __slots__ = ("key", "batch", "ticket")
 
-    def __init__(self, key, batch, ticket, priority=0):
+    def __init__(self, key, batch, ticket):
         self.key, self.batch, self.ticket = key, batch, ticket
-        self.priority = priority
 
 
 class MeasureScheduler:
     """Hold measurement batches from several submitters in flight at once.
 
-    ``submit(key, workload, schedules, priority=0)`` pushes one batch for
-    submitter ``key`` (a driver index, a baseline slot, ...);
-    ``collect_next()`` blocks for the next reconcilable batch and returns
+    ``submit(key, workload, schedules)`` pushes one batch for submitter
+    ``key`` (a driver index, a baseline slot, ...) onto a
+    :class:`SerialMeasureQueue` over ``runner``; ``collect_next()`` blocks
+    for the next reconcilable batch and returns
     ``(key, batch, latencies, wait_s, measure_s)``. Ordering guarantees:
 
     - **per-key FIFO** — a key's batches always come back in its own
       submission order (what deterministic trace replay requires);
-    - **completion- and priority-aware across keys** — if any in-flight
-      ticket has already completed, the highest-priority (then
-      earliest-*submitted*) completed one is returned without blocking, so
-      its submitter can be topped up immediately; only when nothing is
-      ready does the call block on the oldest outstanding work. Which key
-      is picked is a wall-clock observation, but it can never change any
-      single key's reconcile order — per-key trajectories stay
-      bit-identical to the single-FIFO schedule.
-
-    ``priority`` is forwarded to backends that declare
-    ``supports_priority`` (the serial queue and the board farm), so a
-    high-priority batch also jumps the *backend's* queue, preempting bulk
-    work at shard granularity.
-
-    ``multi_queue=None`` (auto) uses the runner's native ``submit_batch``
-    when it has one (a :class:`~repro.core.board_farm.BoardFarm`); pass
-    ``False`` to force the single-FIFO :class:`SerialMeasureQueue` even
-    then (the comparison baseline). ``True`` *requests* the native path but
-    degrades to the serial queue when the runner has none — check the
-    resulting ``multi_queue`` attribute for the effective mode.
+    - **completion-aware across keys** — the earliest-submitted completed
+      ticket that heads its key's queue is returned first. The queue
+      measures in submission order, so this is submission order.
 
     The scheduler records every ticket's real measuring interval and every
     interval the consuming thread spent *blocked* in ``collect_next`` —
     attributed to the key whose batch the wait produced — so
     :meth:`overlap_s`, :meth:`measure_span_s`, and :meth:`wait_span_s` are
     span-accurate both globally and per key, and :meth:`busy_fraction`
-    derives the farm-utilization signal the adaptive depth policy consumes
+    derives the utilization signal the adaptive depth policy consumes
     without any policy-side clock read.
     """
 
-    def __init__(self, runner, multi_queue: bool | None = None):
-        native = callable(getattr(runner, "submit_batch", None))
-        self.multi_queue = native if multi_queue is None \
-            else bool(multi_queue and native)
-        if self.multi_queue:
-            self._backend, self._owns_backend = runner, False
-        else:
-            self._backend, self._owns_backend = SerialMeasureQueue(runner), True
-        self.max_inflight = max(1, int(getattr(self._backend,
-                                               "max_inflight", 1)))
-        self._priority_backend = bool(getattr(self._backend,
-                                              "supports_priority", False))
+    # one measurement thread: one batch makes progress at a time
+    max_inflight = 1
+
+    def __init__(self, runner):
+        self._backend = SerialMeasureQueue(runner)
         self._fifo: deque[_Entry] = deque()  # global submission order
-        self._any_done = threading.Event()  # set whenever any ticket lands
         self._measure_ivs: dict[Any, list[tuple[float, float]]] = {}
         self._wait_ivs: dict[Any, list[tuple[float, float]]] = {}
-        # schedules refused before reaching the backend because the static
+        # schedules refused before reaching the runner because the static
         # analyzer proved them infeasible (their slots return INVALID
         # without burning measurement time); see _screen
         self.static_rejected = 0
@@ -379,11 +305,9 @@ class MeasureScheduler:
     def _screen(self, workload: Workload,
                 schedules: Sequence[Schedule]) -> list[bool] | None:
         """Per-schedule statically-provably-invalid verdicts, or None when
-        screening doesn't apply (the backend screens natively, carries no
-        hardware config, or nothing would be rejected)."""
-        if getattr(self._backend, "static_screens", False):
-            return None  # e.g. BoardFarm refuses invalid work itself
-        hw = getattr(self._backend, "hw", None)
+        screening doesn't apply (the runner carries no hardware config, or
+        nothing would be rejected)."""
+        hw = self._backend.hw
         if hw is None:
             return None
         report = static_lib.feasibility(workload, hw)
@@ -392,85 +316,64 @@ class MeasureScheduler:
         try:
             verdicts = [bool(report.check_schedule(s)) for s in schedules]
         except Exception:
-            return None  # unscreenable schedules: let the backend decide
+            return None  # unscreenable schedules: let the runner decide
         return verdicts if any(verdicts) else None
 
-    def _submit_backend(self, workload: Workload,
-                        schedules: list[Schedule],
-                        priority: int) -> MeasureTicket:
-        if self._priority_backend:
-            return self._backend.submit_batch(workload, schedules,
-                                              priority=priority)
-        return self._backend.submit_batch(workload, schedules)
-
     def submit(self, key: Any, workload: Workload,
-               schedules: Sequence[Schedule],
-               priority: int = 0) -> MeasureTicket:
+               schedules: Sequence[Schedule]) -> MeasureTicket:
         schedules = list(schedules)
         verdicts = self._screen(workload, schedules)
         if verdicts is None:
-            ticket = self._submit_backend(workload, list(schedules), priority)
+            ticket = self._backend.submit_batch(workload, list(schedules))
         else:
             # ship only the statically-defensible subset; the rejected
-            # slots come back INVALID without occupying the backend at all
+            # slots come back INVALID without occupying the runner at all
             keep = [i for i, bad in enumerate(verdicts) if not bad]
             self.static_rejected += len(schedules) - len(keep)
             inner = None
             if keep:
-                inner = self._submit_backend(
-                    workload, [schedules[i] for i in keep], priority)
+                inner = self._backend.submit_batch(
+                    workload, [schedules[i] for i in keep])
             ticket = _ScreenedTicket(workload, schedules, inner, keep)
-        ticket.subscribe(self._any_done)
-        self._fifo.append(_Entry(key, schedules, ticket, priority))
+        self._fifo.append(_Entry(key, schedules, ticket))
         return ticket
 
-    def inflight(self, key: Any = None) -> int:
-        if key is None:
-            return len(self._fifo)
-        return sum(1 for e in self._fifo if e.key == key)
+    def inflight(self) -> int:
+        return len(self._fifo)
 
     def _next_ready(self) -> "_Entry | None":
-        """Highest-priority, then earliest-submitted, completed entry that
-        is also its key's oldest in-flight entry (the per-key FIFO
-        eligibility rule — a key's later completions wait for its head)."""
+        """Earliest-submitted completed entry that is also its key's oldest
+        in-flight entry (the per-key FIFO eligibility rule — a key's later
+        completions wait for its head)."""
         blocked: set = set()
-        best: _Entry | None = None
         for entry in self._fifo:
             if entry.key in blocked:
                 continue
             # only a key's oldest in-flight entry is ever eligible,
             # completed or not
             blocked.add(entry.key)
-            if entry.ticket.done() and (best is None
-                                        or entry.priority > best.priority):
-                best = entry  # fifo scan: earliest wins within a priority
-        return best
+            if entry.ticket.done():
+                return entry
+        return None
 
     # ---- collection ------------------------------------------------------------
     def collect_next(self) -> tuple[Any, list[Schedule], list[float],
                                     float, float]:
         """Block for the next reconcilable batch (see class docstring for
-        the ordering contract); raises whatever the backend failed the
-        ticket with (e.g. ``FarmDead``)."""
+        the ordering contract); raises whatever the runner raised while
+        measuring it."""
         if not self._fifo:
             raise RuntimeError("collect_next() with nothing in flight")
         t0 = time.monotonic()
-        # Wait until some key's HEAD ticket completes, then take the
-        # highest-priority earliest-submitted such entry — never block on
-        # the global head while a later ticket's submitter could be topped
-        # up. Only a key's oldest in-flight entry is eligible (per-key
-        # FIFO: a driver whose second batch finished before its first must
-        # wait for the first), and the clear-then-rescan pattern makes a
-        # racing completion at worst one poll-timeout late.
-        while True:
-            entry = self._next_ready()
-            if entry is not None:
-                break
-            self._any_done.clear()
-            entry = self._next_ready()
-            if entry is not None:
-                break
-            self._any_done.wait(timeout=0.1)
+        # Wait until some key's head ticket completes, then take the
+        # earliest-submitted such entry (per-key FIFO). The queue completes
+        # tickets in submission order, so the oldest one not yet done is
+        # the next to land.
+        while (entry := self._next_ready()) is None:
+            pending = next((e.ticket for e in self._fifo
+                            if not e.ticket.done()), None)
+            if pending is not None:
+                pending.wait()
         self._fifo.remove(entry)
         try:
             latencies = entry.ticket.result()
@@ -479,7 +382,7 @@ class MeasureScheduler:
             if t1 > t0:
                 # the blocked interval is attributed to the key whose batch
                 # the wait produced — per-driver wait spans stay meaningful
-                # in an interleaved session (satellite: wait_span_s(key=))
+                # in an interleaved session (wait_span_s(key=))
                 self._wait_ivs.setdefault(entry.key, []).append((t0, t1))
             iv = entry.ticket.interval()
             if iv is not None:
@@ -523,18 +426,16 @@ class MeasureScheduler:
 
     def busy_fraction(self, window_s: float = 2.0) -> float:
         """Mean measuring concurrency over the trailing window, relative to
-        the backend's ``max_inflight`` capacity — the utilization signal
+        the queue's ``max_inflight`` capacity — the utilization signal
         the adaptive depth policy consumes.
 
         Derived **entirely from recorded span intervals**: "now" is the
         latest recorded interval edge (or an in-flight ticket's start), not
-        a clock read, so the signal is reproducible under a scripted clock
-        and the policy layer on top of it stays free of wall-clock reads
-        (enforced by ``tools/lint_invariants.py``). In-flight tickets count
-        as busy from their real dispatch start to the derived now. Returns
-        0.0 before any measurement has started; capped at 1.0 (ticket
-        concurrency can exceed the board count transiently when shards
-        interleave)."""
+        a clock read, so the policy layer on top of it stays free of
+        wall-clock reads (enforced by ``tools/lint_invariants.py``).
+        In-flight tickets count as busy from their real start to the
+        derived now. Returns 0.0 before any measurement has started; capped
+        at 1.0."""
         done = self._intervals()
         open_ivs = [(e.ticket.t_start, None) for e in self._fifo
                     if e.ticket.t_start is not None and not e.ticket.done()]
@@ -552,8 +453,7 @@ class MeasureScheduler:
 
     # ---- lifecycle -------------------------------------------------------------
     def close(self) -> None:
-        if self._owns_backend:
-            self._backend.close()
+        self._backend.close()
 
     def __enter__(self) -> "MeasureScheduler":
         return self
@@ -574,8 +474,8 @@ class AdaptiveDepthPolicy:
 
     - **grows** a driver's depth by one — beyond the requested
       ``base_depth``, up to ``min(max_depth, max_inflight + 1)`` — when the
-      backend's busy-fraction over the trailing ``window_s`` sits below
-      ``target_utilization`` (boards are starving at the current depth
+      queue's busy-fraction over the trailing ``window_s`` sits below
+      ``target_utilization`` (the runner idles at the current depth
       boundary) — but never while mean reconciliation lag is already over
       ``lag_threshold``, so lag-shrink and idle-grow cannot saw against
       each other at the base depth;
@@ -583,17 +483,15 @@ class AdaptiveDepthPolicy:
       reconciliation lag (batches it proposed against constant-liar
       predictions that were still uncorrected when this batch reconciled)
       exceeds ``lag_threshold`` — deep speculation on stale predictions
-      degrades search quality faster than it fills boards;
+      degrades search quality faster than it fills the queue;
     - changes at most once per ``cooldown`` reconciles per driver, so one
       noisy window reading cannot saw the depth.
 
     Determinism: the policy reads only the scheduler's recorded span
     intervals (see :meth:`MeasureScheduler.busy_fraction`) and per-driver
     reconcile counts — never a clock (``tools/lint_invariants.py`` forbids
-    wall-clock reads inside ``*Policy``/``*Ledger`` classes). Given a
-    scripted clock (simulated boards with scripted delays) an adaptive run
-    replays reproducibly; with the policy absent the scheduler loop is
-    untouched.
+    wall-clock reads inside ``*Policy``/``*Ledger`` classes). With the
+    policy absent the scheduler loop is untouched.
     """
 
     def __init__(self, base_depth: int, max_depth: int = 8,
@@ -639,7 +537,7 @@ class AdaptiveDepthPolicy:
                 scheduler.busy_fraction(self.window_s) \
                 < self.target_utilization:
             self._set(key, depth + 1)
-        elif depth > cap:  # backend shrank (board deaths): clamp down
+        elif depth > cap:  # the capacity hint fell: clamp down
             self._set(key, cap)
 
     def _set(self, key: Any, depth: int) -> None:

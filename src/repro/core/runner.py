@@ -16,49 +16,27 @@ Here the runners are:
   over {MXU compute, HBM traffic} with per-grid-step overhead and MXU
   utilization derating (the QEMU analogue). Uncalibrated against the chip.
 
-A further runner, :class:`~repro.core.measure_pool.SubprocessRunner`, wraps
-the interpret path in a persistent worker-process pool with a true
-per-candidate timeout kill — the isolation a wedged (not merely crashing)
-build needs; see ``measure_pool.py``. Another,
-:class:`~repro.core.board_farm.BoardFarm`, shards each batch across
-several measurement boards (the paper's RPC board farm) with fault-tolerant
-work-stealing dispatch; see ``board_farm.py``. Both measure in child
-processes, so both refuse to start in a process whose JAX backend is a TPU
-(:func:`refuse_child_measurement_on_tpu`).
-
 All satisfy the same ``Runner`` protocol; ``tuner.tune`` is agnostic. The
 ``overlap_capable`` class attribute tells the tuner whether measurement on
 this runner has real latency worth hiding behind search: runners that
-declare it ``True`` opt into the pipelined (speculative) tuner loop and
-interleaved sessions, while instantaneous runners keep the exact
+declare it ``True`` (``InterpretRunner``) opt into the pipelined
+(speculative) tuner loop and interleaved sessions, which measure through
+the single thread of a :class:`~repro.core.measure_scheduler.
+SerialMeasureQueue`; the others (``DeviceRunner``, ``AnalyticRunner``) are
+searched synchronously, on the calling thread, and keep the exact
 synchronous search trajectory (see ``tuner.effective_pipeline_depth``).
+A session's fixed-library baselines go through that queue on every
+runner.
 
-Async submission protocol (optional, duck-typed)
-------------------------------------------------
-A runner may additionally expose ``submit_batch(workload, schedules)``
-returning a :class:`~repro.core.measure_scheduler.MeasureTicket` (a future:
-``done()``/``result()``), plus a ``max_inflight`` hint — how many submitted
-batches can make *physical* progress concurrently. The
-:class:`~repro.core.measure_scheduler.MeasureScheduler` then holds many
-batches from many tuning drivers in flight on the runner at once (a
-:class:`~repro.core.board_farm.BoardFarm` implements this natively with a
-cross-batch work-stealing dispatcher). Runners without it — everything in
-this module — are wrapped in the scheduler's default priority-ordered
-measurement thread (:class:`~repro.core.measure_scheduler.
-SerialMeasureQueue`) and need no changes; their ``max_inflight`` is 1:
-only one batch measures at a time, whatever is queued behind it.
-
-The ``max_inflight`` hint does double duty: besides sizing the scheduler's
-capacity, ``tuner.effective_pipeline_depth`` clamps a requested speculation
-depth to ``max_inflight + 1`` (one batch per concurrently-progressing slot
-plus one being evolved) — deeper requests would only park batches in the
-backend's queue while the search speculates against stale predictions —
-and the :class:`~repro.core.measure_scheduler.AdaptiveDepthPolicy` treats
-the same bound as its growth ceiling. Runners that declare no hint are
-taken at the requested depth. Backends that additionally declare
-``supports_priority`` accept ``submit_batch(..., priority=)`` and serve
-higher-priority batches first (see ``measure_scheduler.py``); the hint is
-purely about *capacity* and is unaffected by priorities.
+The optional ``max_inflight`` hint says how many submitted batches make
+physical progress concurrently: 1 for every runner here.
+``tuner.effective_pipeline_depth`` clamps a requested speculation depth to
+``max_inflight + 1`` (one batch measuring plus one being evolved) — deeper
+requests would only park batches in the queue while the search speculates
+against stale predictions — and the
+:class:`~repro.core.measure_scheduler.AdaptiveDepthPolicy` treats the same
+bound as its growth ceiling. Runners that declare no hint are taken at the
+requested depth.
 
 Caching and dedup (the content-addressed layer)
 -----------------------------------------------
@@ -84,9 +62,8 @@ concrete lowerings — never object identity):
   are bit-identical with the cache enabled. Invalidated only by
   ``build_cache.clear_build_cache()``.
 - **Batch-level measurement dedup** is a ``dedup`` knob (default False)
-  on :class:`InterpretRunner`, :class:`AnalyticRunner`,
-  :class:`~repro.core.measure_pool.SubprocessRunner`, and
-  :class:`~repro.core.board_farm.BoardFarm`: same-signature candidates
+  on :class:`InterpretRunner` and :class:`AnalyticRunner`: same-signature
+  candidates
   within one batch measure once and the latency fans out by submission
   position. This *is* a semantic choice on noisy runners (position i
   reports position j's sample instead of its own draw), hence off by
@@ -122,16 +99,10 @@ class Runner(Protocol):
     # wall-clock latency the tuner can hide search work behind.
     # overlap_capable: bool
     # Optional (duck-typed): how many submitted batches make physical
-    # progress concurrently — the MeasureScheduler capacity hint, and the
-    # bound effective_pipeline_depth clamps speculation depth to (+1).
-    # Absent = capacity unknown: the scheduler assumes 1, the depth clamp
+    # progress concurrently — the bound effective_pipeline_depth clamps
+    # speculation depth to (+1). Absent = capacity unknown: the depth clamp
     # is skipped.
     # max_inflight: int
-    # Optional async submission protocol (see module docstring):
-    # def submit_batch(self, workload, schedules) -> MeasureTicket: ...
-    # Optional (duck-typed, defaults False): submit_batch accepts a
-    # priority= keyword and serves higher-priority batches first.
-    # supports_priority: bool
 
     def run(self, workload: Workload, schedule: Schedule) -> float:
         """Latency in seconds; inf if the candidate is invalid."""
@@ -211,8 +182,7 @@ class InterpretRunner:
 
         At most ``workers`` threads are created, pulling candidate indices
         from a shared queue — thread creation is bounded by the pool size,
-        not the batch size (a farm-scale batch used to spawn one thread
-        per candidate up front). With ``dedup`` on, only the first
+        not the batch size. With ``dedup`` on, only the first
         occurrence of each trace signature is built and timed; duplicates
         receive its latency by position.
 
@@ -222,10 +192,7 @@ class InterpretRunner:
         concurrency wave, not per candidate, so stalls never accumulate
         unboundedly — expires; the remaining workers keep draining the
         queue. Workers are daemon threads, so a wedged build can never
-        block interpreter exit either. When wedged builds are a real
-        risk, use :class:`~repro.core.measure_pool.SubprocessRunner`
-        instead: its process-pool workers give a true per-candidate
-        timeout *kill* (the slot is reclaimed immediately, not abandoned).
+        block interpreter exit either.
         """
         schedules = list(schedules)
         if len(schedules) <= 1:
@@ -387,16 +354,6 @@ def default_backend() -> str:
     import jax
 
     return jax.default_backend()
-
-
-def refuse_child_measurement_on_tpu(what: str) -> None:
-    """Runners that measure in child processes cannot share a TPU with this
-    process: it holds the chip, and a child that needs it fails or hangs."""
-    if default_backend() == "tpu":
-        raise RuntimeError(
-            f"{what} measures in child processes, but this process's JAX "
-            "backend is a TPU and one process holds the chip: measure in "
-            "process with DeviceRunner instead")
 
 
 def attached_device():

@@ -122,7 +122,7 @@ class Schedule:
         ignoring version, provenance, and candidate sets. This is the
         identity every dedup layer keys on — the tuner's in-flight sets,
         the database's record dedup and cross-session measured-latency
-        memo, the batch dedup knobs on runners and the board farm, and
+        memo, the batch dedup knobs on runners, and
         (one concretization later, as ``KernelParams.signature()``) the
         build cache. Value-derived by construction — never ``id()`` or a
         default ``repr`` (``tools/lint_invariants.py`` enforces this for

@@ -17,50 +17,38 @@ across runs. A :class:`TuningSession` closes that gap:
 - **shared budget** — a single trial budget is split across the unique
   workloads, weighted by their contribution to model latency
   (``count * flops``), with a per-workload floor;
-- **overlap** — on runners with real measurement latency (``overlap_capable``,
-  e.g. the interpret or subprocess runners) the session drives all workloads'
-  :class:`~repro.core.tuner.TuneDriver` state machines through one
-  :class:`~repro.core.measure_scheduler.MeasureScheduler`, so one
-  workload's candidates are evolved while another's batch is on the
-  "board". On a backend with a native async submission protocol (a
-  :class:`~repro.core.board_farm.BoardFarm`) the scheduler holds **every
-  driver's batches in flight concurrently** — an idle board steals shards
-  from any in-flight batch, so the farm stays busy across workload and
-  batch boundaries instead of draining one FIFO batch at a time
-  (``multi_queue=False`` forces the old single-FIFO measurement thread,
-  the comparison baseline the farm benchmarks report against).
+- **overlap** — on runners with real measurement latency
+  (``overlap_capable``, e.g. the interpret runner) the session drives all
+  workloads' :class:`~repro.core.tuner.TuneDriver` state machines through
+  one :class:`~repro.core.measure_scheduler.MeasureScheduler`, so one
+  workload's candidates are evolved while another's batch is measured.
   ``pipeline_depth`` additionally lets a single driver keep several
   batches in flight (speculative evolution against predicted latencies —
   see ``tuner.py``). Interleaving stays deterministic — each driver
   reconciles its own batches in submission order and its propose points
-  depend only on its own reconcile count, so per-workload histories are
-  bit-identical between the multi-queue and single-FIFO paths — but
-  trades away *within-session* warm-start chaining: every workload's
-  transfer seeds are drawn from the database as it stood when the session
-  began. Instantaneous runners (the analytic model) keep the serial path
-  and its chaining.
+  depend only on its own reconcile count — but trades away
+  *within-session* warm-start chaining: every workload's transfer seeds
+  are drawn from the database as it stood when the session began. Runners
+  that are not overlap-capable (the analytic model, ``DeviceRunner`` on the
+  chip) keep the serial path and its chaining.
 - **adaptation** (all off by default, so fixed-seed histories stay
   bit-identical to the non-adaptive session) — ``adaptive_depth=True``
   hands the interleaved executor an
   :class:`~repro.core.measure_scheduler.AdaptiveDepthPolicy`: each
   driver's effective speculation depth grows beyond ``pipeline_depth`` (up
-  to ``max_depth`` and the backend's ``max_inflight`` hint) while the
-  farm's busy-fraction over a ``depth_window_s`` sliding window sits below
-  ``target_utilization``, and shrinks back when reconciliation lag exceeds
-  its threshold — heterogeneous farms stop idling at depth boundaries.
-  ``stop_policy="entropy"`` watches each driver's mean and per-decision
-  proposal entropy plus its best-latency plateau length
-  (``entropy_threshold`` / ``plateau_patience``), curtails searches whose
-  proposals have converged, and reallocates ``reallocate_fraction`` of the
-  released trials to still-improving drivers that exhaust their own budget
-  — one shared :class:`BudgetLedger` carries the balance across the
-  interleaved session. ``priority`` tags every batch of the session for
-  priority-aware backends (a board farm preempts lower-priority backlog at
-  shard granularity). Adaptive runs stay reproducible given a scripted
-  clock: the depth policy reads only the scheduler's recorded span
-  intervals, never wall-clock (``tools/lint_invariants.py`` enforces
-  this), and curtail/extend decisions fire at a driver's own reconcile
-  points on its own deterministic state.
+  to ``max_depth`` and the runner's ``max_inflight`` hint) while the
+  queue's busy-fraction over a ``depth_window_s`` sliding window sits
+  below ``target_utilization``, and shrinks back when reconciliation lag
+  exceeds its threshold. ``stop_policy="entropy"`` watches each driver's
+  mean and per-decision proposal entropy plus its best-latency plateau
+  length (``entropy_threshold`` / ``plateau_patience``), curtails searches
+  whose proposals have converged, and reallocates ``reallocate_fraction``
+  of the released trials to still-improving drivers that exhaust their own
+  budget — one shared :class:`BudgetLedger` carries the balance across the
+  interleaved session. The depth policy reads only the scheduler's
+  recorded span intervals, never wall-clock (``tools/lint_invariants.py``
+  enforces this), and curtail/extend decisions fire at a driver's own
+  reconcile points on its own deterministic state.
 - **reporting** — per-workload progress lines plus a session-level
   latency/speedup summary committed to the database. Measure/search
   overlap and the measurement span are *span-accurate*: the scheduler
@@ -69,10 +57,10 @@ across runs. A :class:`TuningSession` closes that gap:
   and per-driver wait/overlap attribution uses each driver's own wait
   intervals (``wait_span_s(key=)``), not the global union. Adaptation
   surfaces as ``TuneResult.depth_trace`` per workload and
-  early-stop/reallocation/preemption counters in ``SessionResult.summary``.
-  Fixed-library baselines are measured as one scheduled wave — every
-  workload's baseline in flight together — not N serial dispatch round
-  trips.
+  early-stop/reallocation counters in ``SessionResult.summary``.
+  Fixed-library baselines are submitted as one wave to a
+  :class:`~repro.core.measure_scheduler.MeasureScheduler` and measured in
+  workload order.
 
 Sessions are also the engine of **traffic-driven continuous tuning**
 (``core/traffic.py``): a :class:`~repro.core.traffic.ContinuousTuner`
@@ -254,18 +242,13 @@ class SessionResult:
     # span-accurate measurement wall-clock: union of the real measuring
     # intervals (concurrent batches not double-counted); 0 when unknown
     measure_span_s: float = 0.0
-    multi_queue: bool = False  # batches from many drivers in flight at once
     model: str = ""  # model/config name, for cross-session trend reports
-    # per-board utilization / requeue counters when the runner is a board
-    # farm (board_farm.BoardFarm.farm_summary); None otherwise
-    board_stats: dict | None = None
     # ---- adaptation observability (PR 8) ----
     adaptive_depth: bool = False  # depth policy was active
     stop_policy: str = "none"  # budget policy the session ran under
     stopped_early: int = 0  # drivers curtailed by the stop policy
     released_trials: int = 0  # trials returned by curtailed drivers
     reallocated_trials: int = 0  # released trials re-granted to others
-    preemptions: int = 0  # farm dispatches that jumped lower-priority work
     # process-wide build-cache counter deltas over this session (see
     # core/build_cache.py); None when never snapshotted (old payloads)
     build_cache: dict | None = None
@@ -323,17 +306,14 @@ class SessionResult:
             "pipeline_depth": self.pipeline_depth,
             "measure_time_s": self.measure_time_s,
             "measure_span_s": self.measure_span_s,
-            "multi_queue": self.multi_queue,
             "overlap_s": self.overlap_s,
             "overlap_fraction": self.overlap_fraction,
             "proposal_entropy": self.mean_proposal_entropy,
-            "board_stats": self.board_stats,
             "adaptive_depth": self.adaptive_depth,
             "stop_policy": self.stop_policy,
             "stopped_early": self.stopped_early,
             "released_trials": self.released_trials,
             "reallocated_trials": self.reallocated_trials,
-            "preemptions": self.preemptions,
             "build_cache": self.build_cache,
             "measured_memo": self.measured_memo,
             "failures": self.failures,
@@ -402,15 +382,11 @@ class TuningSession:
 
     ``interleave=None`` (auto) overlaps measurement and search across
     workloads whenever the runner declares ``overlap_capable``; set it
-    explicitly to force either path. ``multi_queue=None`` (auto) lets the
-    scheduler hold every driver's batches in flight concurrently whenever
-    the runner exposes a native async ``submit_batch`` (a board farm);
-    ``False`` forces the single-FIFO measurement thread (the comparison
-    baseline — per-workload results are bit-identical either way).
-    ``pipeline_depth`` is the per-workload in-flight batch bound (see
-    ``tuner.tune``). ``learn_proposals`` turns the per-decision proposal
-    learning on (default) — each search is then additionally warm-started
-    from the blended posteriors prior same-op-family searches stored in the
+    explicitly to force either path. ``pipeline_depth`` is the
+    per-workload in-flight batch bound (see ``tuner.tune``).
+    ``learn_proposals`` turns the per-decision proposal learning on
+    (default) — each search is then additionally warm-started from the
+    blended posteriors prior same-op-family searches stored in the
     database; ``pretrain_cost_model`` folds the database's records into
     each search's cost model before its first generation.
 
@@ -422,8 +398,7 @@ class TuningSession:
     ``stop_policy="entropy"`` plus ``entropy_threshold``/
     ``plateau_patience``/``reallocate_fraction`` configure the
     :class:`EntropyStopPolicy` over a shared :class:`BudgetLedger`
-    (requires ``learn_proposals``); ``priority`` tags every batch for
-    priority-aware backends.
+    (requires ``learn_proposals``).
     """
 
     hw: HardwareConfig
@@ -434,7 +409,6 @@ class TuningSession:
     batch: int = 8
     pipeline_depth: int = 1
     interleave: bool | None = None
-    multi_queue: bool | None = None
     learn_proposals: bool = True
     pretrain_cost_model: bool = False
     # consult the static feasibility analyzer so provably-invalid
@@ -450,7 +424,6 @@ class TuningSession:
     entropy_threshold: float = 0.995
     plateau_patience: int = 12
     reallocate_fraction: float = 1.0
-    priority: int = 0
     # settle candidates the database already measured (same runner name)
     # from the stored latency instead of re-measuring — the cross-session
     # memo (database.measured_latency). Off by default: reuse changes
@@ -479,14 +452,12 @@ class TuningSession:
     def _measure_baselines(self, unique) -> list[float]:
         """Fixed-library baselines for every unique workload through one
         scheduled wave: all baselines are submitted before any is awaited,
-        so a board farm measures them in parallel instead of N serial
-        dispatch round trips (per-workload attribution is by position)."""
+        and each ticket is read back by its workload's position."""
         from repro.core.dispatch import fixed_library_schedule
 
         pairs = [(wl, fixed_library_schedule(wl, self.hw))
                  for _, wl in unique]
-        scheduler = MeasureScheduler(self.runner,
-                                     multi_queue=self.multi_queue)
+        scheduler = MeasureScheduler(self.runner)
         try:
             tickets = [scheduler.submit(i, wl, [s])
                        for i, (wl, s) in enumerate(pairs)]
@@ -535,14 +506,13 @@ class TuningSession:
         return (results, sum(r.overlap_s for r in results),
                 sum(r.measure_time_s for r in results), {})
 
-    def _tune_interleaved(self, unique, budgets, seed, depth,
-                          scheduler) -> tuple[list[TuneResult], float, float]:
+    def _tune_interleaved(self, unique, budgets, seed,
+                          depth) -> tuple[list[TuneResult], float, float]:
         """All drivers feed one MeasureScheduler: while workload A's batch
-        measures, workloads B, C, ... evolve and submit — and on a
-        multi-queue backend every driver's batches are *measured*
-        concurrently too. Each driver reconciles its own batches in
-        submission order, so per-workload results are deterministic for a
-        given seed regardless of completion order. Session-level overlap
+        measures, workloads B, C, ... evolve and submit. Each driver
+        reconciles its own batches in submission order, so per-workload
+        results are deterministic for a given seed regardless of completion
+        order. Session-level overlap
         and measurement span come from the scheduler's real busy/wait
         intervals (span-accurate under concurrency, unlike the old
         summed-totals estimate), with per-driver wait/overlap attribution
@@ -563,13 +533,12 @@ class TuningSession:
                              prior_distributions=self._priors_for(wl),
                              pretrain_cost_model=self.pretrain_cost_model,
                              static_analysis=self.static_analysis,
-                             priority=self.priority,
                              reuse_measured=self.reuse_measured)
             for i, ((count, wl), trials) in enumerate(zip(unique, budgets))]
         depth_policy = None
-        # adaptive depth can grow from base depth 1 — that is exactly the
-        # heterogeneous-farm win — but never on a runner with nothing to
-        # overlap (analytic runners stay clamped at depth 1, bit-identical)
+        # adaptive depth can grow from base depth 1, but never on a runner
+        # with nothing to overlap (analytic runners stay clamped at depth
+        # 1, bit-identical)
         if self.adaptive_depth and getattr(self.runner, "overlap_capable",
                                            False):
             depth_policy = AdaptiveDepthPolicy(
@@ -583,8 +552,9 @@ class TuningSession:
             stop = EntropyStopPolicy(
                 ledger, entropy_threshold=self.entropy_threshold,
                 plateau_patience=self.plateau_patience, log=self.log)
-        tuner.run_scheduled(drivers, self.runner, depth, scheduler=scheduler,
-                            depth_policy=depth_policy, on_reconcile=stop)
+        scheduler = tuner.run_scheduled(drivers, self.runner, depth,
+                                        depth_policy=depth_policy,
+                                        on_reconcile=stop)
         results = [d.finish(pipeline_depth=depth) for d in drivers]
         extras = {
             "adaptive_depth": depth_policy is not None,
@@ -615,15 +585,6 @@ class TuningSession:
         interleave = (self.interleave if self.interleave is not None
                       else getattr(self.runner, "overlap_capable", False)
                       and len(unique) > 1)
-        # The scheduler is the authority on the effective queue mode (a
-        # multi_queue=True request degrades to single-FIFO on runners
-        # without the native submission protocol); constructing it here is
-        # cheap (no threads until the first submit) and what is logged and
-        # reported can then never diverge from what actually ran.
-        scheduler = (MeasureScheduler(self.runner,
-                                      multi_queue=self.multi_queue)
-                     if interleave else None)
-        multi_queue = scheduler.multi_queue if scheduler else False
         # Same clamp tune() applies: speculation depth > 1 only makes sense
         # against a runner with real measurement latency.
         depth = tuner.effective_pipeline_depth(self.runner,
@@ -631,13 +592,11 @@ class TuningSession:
         self._log(f"session: {len(ops)} ops -> {len(unique)} unique "
                   f"workloads, {sum(budgets)} trials on {self.runner.name}"
                   f"/{self.hw.name}"
-                  + (f" (interleaved, depth {depth}"
-                     + (", multi-queue" if multi_queue else "") + ")"
-                     if interleave else ""))
+                  + (f" (interleaved, depth {depth})" if interleave else ""))
 
         if interleave:
             results, overlap_s, span_s, extras = self._tune_interleaved(
-                unique, budgets, seed, depth, scheduler)
+                unique, budgets, seed, depth)
         else:
             # adaptation is an interleaved-executor concern: the serial
             # path has no scheduler to adapt and no shared ledger
@@ -657,23 +616,18 @@ class TuningSession:
             for _, wl in unique:
                 for reason, n in failures_fn(wl).items():
                     failures[reason] = failures.get(reason, 0) + n
-        summary_fn = getattr(self.runner, "farm_summary", None)
-        board_stats = summary_fn() if callable(summary_fn) else None
         result = SessionResult(
             hw=self.hw, runner_name=self.runner.name, reports=reports,
             total_trials=sum(r.trials for r in reports),
             wall_time_s=time.perf_counter() - t_start,
             interleaved=interleave, pipeline_depth=depth,
             measure_time_s=measure_s, overlap_s=overlap_s,
-            measure_span_s=span_s,
-            multi_queue=multi_queue, model=model,
-            board_stats=board_stats,
+            measure_span_s=span_s, model=model,
             adaptive_depth=extras.get("adaptive_depth", False),
             stop_policy=self.stop_policy if interleave else "none",
             stopped_early=extras.get("stopped_early", 0),
             released_trials=extras.get("released_trials", 0),
             reallocated_trials=extras.get("reallocated_trials", 0),
-            preemptions=(board_stats or {}).get("preemptions", 0),
             build_cache=stats_delta(build_cache_stats(), bc_before),
             measured_memo=sum(r.measured_memo for r in results),
             failures=failures)

@@ -50,8 +50,9 @@ interprets the program once per (workload, hardware) — categorical variants
 enumerated exactly, tile splits tracked through the divisor/interval domain
 ``tile_candidates`` spans — and proves, before any sampling, which decision
 values can participate in at least one legal completion; the tuner,
-database, and measurement farm consult those feasible sets so provably-dead
-candidates are never proposed, warm-started from, or shipped to a board.
+database, and measurement scheduler consult those feasible sets so
+provably-dead candidates are never proposed, warm-started from, or
+measured.
 The dynamic half is the residual per-candidate check: ``concretize``
 replays either trace layout into :class:`KernelParams` — the static
 parameters a Pallas kernel is built from — and runs the composable

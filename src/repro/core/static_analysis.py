@@ -2,9 +2,9 @@
 
 The dynamic validation pipeline (``space.apply_postprocessors``) rejects
 illegal traces one candidate at a time, *inside* the propose loop — every
-rejection is a sampling attempt wasted, and on a real board farm a
-statically-doomed candidate that slips through to measurement burns the
-scarcest resource there is. This module turns those runtime rejections into
+rejection is a sampling attempt wasted, and on the chip a
+statically-doomed candidate that slips through to measurement burns a
+compile. This module turns those runtime rejections into
 facts established **once per (workload, hardware), before any sampling**,
 by abstract-interpreting the :class:`~repro.core.space.SpaceProgram`:
 
@@ -38,10 +38,9 @@ Three layers consume the report:
 - :class:`~repro.core.database.TuningDatabase` verifies incoming traces
   against the feasible table and quarantines stale ones instead of warm-
   starting searches from garbage;
-- :class:`~repro.core.board_farm.BoardFarm` and the
-  :class:`~repro.core.measure_scheduler.MeasureScheduler` refuse to ship
-  statically-invalid work, settling it as ``INVALID`` without burning a
-  board slot.
+- the :class:`~repro.core.measure_scheduler.MeasureScheduler` refuses to
+  ship statically-invalid work, settling it as ``INVALID`` without
+  occupying the measurement thread.
 
 The dynamic postprocessors stay the ground truth: ``--suite static`` and
 the property tests assert the analyzer's verdicts agree with exhaustive
@@ -301,7 +300,7 @@ def analyze(workload: Workload, hw: HardwareConfig,
     With ``program=None`` (the normal case) the registered
     ``space_for(workload, hw)`` program is analyzed and the report is
     memoized per (workload key, hardware name) — "once per (workload,
-    hardware)", however many tuner/database/farm layers consult it. An
+    hardware)", however many tuner/database/scheduler layers consult it. An
     explicit ``program`` (tests, custom spaces) is analyzed fresh with the
     abstract VMEM pre-pass disabled (its soundness argument only covers the
     registered program shapes).
@@ -327,7 +326,7 @@ def analyze(workload: Workload, hw: HardwareConfig,
 
 def feasibility(workload: Workload, hw: HardwareConfig) -> SpaceReport | None:
     """Memoized :func:`analyze` that returns None instead of raising —
-    the form the tuner/database/farm integration layers call (an op family
+    the form the tuner/database/scheduler integration layers call (an op family
     without a registered space simply has no static verdicts)."""
     try:
         return analyze(workload, hw)

@@ -2,10 +2,10 @@
 
 Per iteration: (1) generate candidates by probabilistic sampling /
 evolutionary mutation of schedule traces, (2) build + measure the candidates
-*as a batch* on the runner (FPGA/board in the paper; interpret-mode or
-analytic model here — see ``Runner.run_batch``), (3) feed the measured
-latencies back into the cost model that ranks the next generation. The best
-measured schedule is committed to the database.
+*as a batch* on the runner (FPGA/board in the paper; the TPU, interpret
+mode or the analytic model here — see ``Runner.run_batch``), (3) feed the
+measured latencies back into the cost model that ranks the next
+generation. The best measured schedule is committed to the database.
 
 A search can be *warm-started* from schedules recorded in a previous run
 (same workload, or a near-miss shape/hardware — the paper's Fig. 4 transfer
@@ -27,32 +27,26 @@ The learned posteriors persist to the database from ``finish()``.
 
 Measure/search scheduling
 -------------------------
-On real hardware, measurement — not search — dominates tuning wall-time
-(9-12 s per candidate on the paper's FPGA targets). ``tune`` therefore
-supports an asynchronous pipeline (``pipeline_depth > 1``): generation N is
-submitted to the measurement backend and generation N+1 is evolved
-immediately against the cost model's *predicted* latencies for the
-in-flight candidates (a constant-liar strategy), reconciling when the
-measurements land.
+A runner is measured one of two ways:
 
-Submission goes through a :class:`~repro.core.measure_scheduler.
-MeasureScheduler`, which holds **multiple batches from multiple drivers in
-flight concurrently**: runners with a native async ``submit_batch`` (a
-:class:`~repro.core.board_farm.BoardFarm`) keep every board busy across
-batch — and workload — boundaries, while plain synchronous runners are
-wrapped in the scheduler's single-FIFO measurement thread and behave
-exactly like the old one-queue pipeline.
+- **synchronously** — ``runner.run_batch`` on the calling thread, one batch
+  per generation. This is the path of every runner that does not declare
+  ``overlap_capable``: the analytic model, and ``DeviceRunner``, which holds
+  the chip in this process;
+- **pipelined** (``pipeline_depth > 1`` on an ``overlap_capable`` runner,
+  such as ``InterpretRunner``) — generation N is submitted to a
+  :class:`~repro.core.measure_scheduler.MeasureScheduler`, whose single
+  measurement thread measures it while generation N+1 is evolved against
+  the cost model's *predicted* latencies for the in-flight candidates (a
+  constant-liar strategy), reconciling when the measurements land.
 
 The pipeline is **deterministic by construction**: each driver's batches
 are reconciled in that driver's own submission order (per-driver FIFO), and
 a driver's propose/reconcile points depend only on its *own* reconcile
-count — so which driver happens to reconcile first (a completion-order
-observation under the multi-queue scheduler) can never leak into any
-driver's trajectory, and a given seed replays the same per-driver history
-regardless of farm shape or runner speed. Runners that measure
-instantaneously (the analytic model) declare ``overlap_capable = False``;
-for them the effective depth is clamped to 1 — there is no latency to
-hide, and the pipelined path then reproduces the synchronous trajectory
+count — so a given seed replays the same per-driver history regardless of
+how long measurement takes. On a runner that is not ``overlap_capable``
+the effective depth is clamped to 1 — there is no latency to hide, and
+the scheduled path then reproduces the synchronous trajectory
 bit-identically.
 
 The mechanics live in :class:`TuneDriver`, an explicit propose/reconcile
@@ -100,10 +94,6 @@ class TuneResult:
     pipeline_depth: int = 1  # effective depth the search ran at
     measure_time_s: float = 0.0  # total time the runner spent measuring
     overlap_s: float = 0.0  # measurement time hidden behind search work
-    # per-board utilization / requeue counters when the runner is a board
-    # farm (see board_farm.BoardFarm.farm_summary); None for single-target
-    # runners
-    board_stats: dict | None = None
     # normalized posterior entropy per decision at the end of the search
     # (1.0 = still uniform, -> 0 = proposal converged); {} when proposal
     # learning was disabled
@@ -171,8 +161,7 @@ def effective_pipeline_depth(runner: Runner, requested: int) -> int:
     pipelined execution bit-identical to the synchronous trajectory.
 
     An overlap-capable runner that also declares a ``max_inflight``
-    capacity hint (the serial measurement queue and ``MeasurePool``-backed
-    runners report 1; a board farm its board count) is clamped to
+    capacity hint (``InterpretRunner`` reports 1) is clamped to
     ``max_inflight + 1`` — one batch per concurrently-progressing slot plus
     one being evolved against the constant liar. Depth beyond that only
     parks batches in the backend's queue, deepening speculation on stale
@@ -215,18 +204,12 @@ class TuneDriver:
                  prior_distributions: Mapping[str, Mapping] | None = None,
                  pretrain_cost_model: bool = False,
                  static_analysis: bool = True,
-                 priority: int = 0,
                  reuse_measured: bool = False):
         self.workload, self.hw, self.runner = workload, hw, runner
         self.trials = trials
         self.batch = batch
         self.database = database
         self.log = log
-        # scheduling priority class: run_scheduled forwards it with every
-        # submit, so this driver's batches preempt lower-priority backlog
-        # on priority-aware backends (results are unaffected — see
-        # measure_scheduler module docstring)
-        self.priority = int(priority)
         # wall-time span of this driver's own activity: first propose() to
         # last reconcile() — in an interleaved session drivers are all
         # constructed up front, so stamping construction time here would
@@ -283,7 +266,7 @@ class TuneDriver:
         # latency changes which candidates get fresh measurements): _take
         # settles candidates the database already measured at equal
         # fidelity (same runner name) straight into the history, spending
-        # a trial but never a board slot. Within-session duplicates never
+        # a trial but never a measurement slot. Within-session duplicates never
         # reach the memo — _take's own signature dedup catches them first.
         self.reuse_measured = bool(reuse_measured) and database is not None
         self.measured_memo = 0  # trials settled from the database memo
@@ -499,7 +482,6 @@ class TuneDriver:
     def finish(self, pipeline_depth: int = 1) -> TuneResult:
         if self._in_flight:
             raise RuntimeError("finish() with batches still in flight")
-        summary = getattr(self.runner, "farm_summary", None)
         failures = getattr(self.runner, "failures", None)
         # authoritative wall-time span: first propose() -> last reconcile()
         # (zero if the driver never ran — construction time is not activity)
@@ -522,7 +504,6 @@ class TuneDriver:
             self.history, len(self.history), wall,
             warm_started=self.warm_started, pipeline_depth=pipeline_depth,
             measure_time_s=self.measure_time_s, overlap_s=overlap,
-            board_stats=summary() if callable(summary) else None,
             proposal_entropy=entropy, static_pruned=self.static_pruned,
             depth_trace=list(self.depth_trace),
             stopped_early=self.stopped_early,
@@ -546,9 +527,7 @@ def timed_run_batch(runner: Runner, driver: TuneDriver,
 
 
 def run_scheduled(drivers: Sequence[TuneDriver], runner: Runner,
-                  depth: int, multi_queue: bool | None = None,
-                  scheduler: MeasureScheduler | None = None,
-                  depth_policy=None,
+                  depth: int, depth_policy=None,
                   on_reconcile: Callable[[int, TuneDriver], None] | None = None
                   ) -> MeasureScheduler:
     """Drive one or many :class:`TuneDriver` state machines against a
@@ -556,14 +535,10 @@ def run_scheduled(drivers: Sequence[TuneDriver], runner: Runner,
 
     Every driver is topped up to its effective depth in-flight batches
     (fixed round-robin fill order), then the next reconcilable batch is
-    collected: per-driver FIFO always, highest-priority then
-    earliest-completed first across drivers — so on a multi-queue backend
-    (a board farm) a driver whose batch finished early is refilled
-    immediately instead of queueing behind another driver's slower batch,
-    and the backend never starves while any driver has work. A driver's
-    propose/reconcile points depend only on its own reconcile count, so
-    per-driver histories are bit-identical to the single-FIFO schedule for
-    a fixed seed (see the module docstring).
+    collected (per-driver FIFO). A driver's propose/reconcile points depend
+    only on its own reconcile count, so per-driver histories are
+    bit-identical for a fixed seed however long measurement takes (see the
+    module docstring).
 
     ``depth_policy`` (an
     :class:`~repro.core.measure_scheduler.AdaptiveDepthPolicy`, default
@@ -580,13 +555,9 @@ def run_scheduled(drivers: Sequence[TuneDriver], runner: Runner,
 
     Returns the scheduler (already closed) so callers can read its
     span-accurate measure/wait/overlap accounting; each driver's
-    ``overlap_span_s`` is stamped from it before returning. Callers that
-    need the scheduler's effective mode up front (its ``multi_queue``
-    attribute is the authority on whether the native path is in use) may
-    construct it themselves and pass it as ``scheduler``.
+    ``overlap_span_s`` is stamped from it before returning.
     """
-    if scheduler is None:
-        scheduler = MeasureScheduler(runner, multi_queue=multi_queue)
+    scheduler = MeasureScheduler(runner)
     counts = [0] * len(drivers)
     try:
         while True:
@@ -609,8 +580,7 @@ def run_scheduled(drivers: Sequence[TuneDriver], runner: Runner,
                     batch = driver.propose()
                     if batch is None:
                         break
-                    scheduler.submit(i, driver.workload, batch,
-                                     priority=getattr(driver, "priority", 0))
+                    scheduler.submit(i, driver.workload, batch)
                     counts[i] += 1
                     submitted = True
             if scheduler.inflight():
@@ -636,14 +606,6 @@ def run_scheduled(drivers: Sequence[TuneDriver], runner: Runner,
     return scheduler
 
 
-def run_pipelined(drivers: Sequence[TuneDriver], runner: Runner,
-                  depth: int) -> None:
-    """Single-FIFO compatibility wrapper over :func:`run_scheduled`
-    (``multi_queue=False``): all drivers feed one measurement thread, the
-    pre-scheduler behaviour benchmarks compare against."""
-    run_scheduled(drivers, runner, depth, multi_queue=False)
-
-
 def tune(workload: Workload, hw: HardwareConfig, runner: Runner,
          trials: int = 64, seed: int = 0,
          database: TuningDatabase | None = None,
@@ -658,7 +620,6 @@ def tune(workload: Workload, hw: HardwareConfig, runner: Runner,
          static_analysis: bool = True,
          adaptive_depth: bool = False,
          max_depth: int = 8,
-         priority: int = 0,
          reuse_measured: bool = False) -> TuneResult:
     """Tune one workload. ``pipeline_depth`` bounds how many proposed batches
     may be in flight at once (1 = fully synchronous; see module docstring for
@@ -666,8 +627,7 @@ def tune(workload: Workload, hw: HardwareConfig, runner: Runner,
     lets an :class:`~repro.core.measure_scheduler.AdaptiveDepthPolicy` grow
     the effective depth up to ``max_depth`` where the backend would
     otherwise idle (off by default: fixed-seed histories then stay
-    bit-identical to the fixed-depth executor); ``priority`` tags this
-    search's batches for priority-aware backends; ``reuse_measured`` (off
+    bit-identical to the fixed-depth executor); ``reuse_measured`` (off
     by default) settles candidates the database already measured at equal
     fidelity from the stored latency instead of re-measuring them
     (``TuneResult.measured_memo`` counts them); the ``learn_*`` /
@@ -680,7 +640,6 @@ def tune(workload: Workload, hw: HardwareConfig, runner: Runner,
                         prior_distributions=prior_distributions,
                         pretrain_cost_model=pretrain_cost_model,
                         static_analysis=static_analysis,
-                        priority=priority,
                         reuse_measured=reuse_measured)
     depth = effective_pipeline_depth(runner, pipeline_depth)
     if pipeline_depth <= 1:

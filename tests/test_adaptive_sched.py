@@ -1,14 +1,14 @@
-"""Adaptive measurement-scheduling suite (depth policy, priorities, budget).
+"""Adaptive measurement-scheduling suite (depth policy, budget).
 
-Covers the adaptation layer on top of the multi-queue scheduler:
+Covers the adaptation layer on top of the measurement scheduler:
 :class:`AdaptiveDepthPolicy` decisions on scripted scheduler state
 (grow / lag-shrink / backend cap / cooldown), the span-derived
 ``busy_fraction`` and per-key ``wait_span_s`` accounting, the
-``max_inflight`` speculation-depth clamp, farm priority preemption with
-aging anti-starvation, the :class:`BudgetLedger`/:class:`EntropyStopPolicy`
-pair, and the determinism contracts: priorities and adaptation-off leave
-per-driver histories bit-identical, and a curtailed search's history is a
-deterministic prefix of its uncurtailed history.
+``max_inflight`` speculation-depth clamp, the
+:class:`BudgetLedger`/:class:`EntropyStopPolicy` pair, and the determinism
+contracts: adaptation-off leaves per-driver histories bit-identical, and a
+curtailed search's history is a deterministic prefix of its uncurtailed
+history.
 """
 
 import time
@@ -20,14 +20,13 @@ try:
 except ImportError:  # optional dev dep: property tests skip, the rest run
     from _hypothesis_stub import given, settings, st
 
-from repro.core import (AdaptiveDepthPolicy, AnalyticRunner, BudgetLedger,
-                        EntropyStopPolicy, MeasureScheduler, TuningDatabase,
-                        TuningSession, V5E, tune)
+from repro.core import (INTERPRET, AdaptiveDepthPolicy, AnalyticRunner,
+                        BudgetLedger, EntropyStopPolicy, InterpretRunner,
+                        MeasureScheduler, TuningDatabase, TuningSession, V5E,
+                        tune)
 from repro.core import tuner as tuner_lib
 from repro.core import workload as W
-from repro.core.board_farm import _WorkItem
 
-from _sim_boards import die_fault, make_farm
 from _test_runners import SlowAnalytic
 
 
@@ -109,7 +108,7 @@ def test_depth_policy_clamps_down_when_backend_shrinks():
     for _ in range(6):
         pol.on_collect("k", sched, lag=0)
     assert pol.depth("k") == 5
-    sched.max_inflight = 1  # boards died: the capacity hint fell
+    sched.max_inflight = 1  # the capacity hint fell
     pol.on_collect("k", sched, lag=0)
     assert pol.depth("k") == 2  # one step straight to the new cap
 
@@ -180,12 +179,12 @@ def test_wait_span_attributed_per_key_across_cadences():
 # ------------------------------------------------------------- depth clamp ----
 
 def test_effective_depth_clamped_by_declared_inflight_hint():
-    farm = make_farm(3)
-    try:
-        assert tuner_lib.effective_pipeline_depth(farm, 8) == 4
-        assert tuner_lib.effective_pipeline_depth(farm, 2) == 2
-    finally:
-        farm.close()
+    # InterpretRunner measures one batch at a time (max_inflight 1): one
+    # batch measuring plus one being evolved
+    runner = InterpretRunner(INTERPRET)
+    assert tuner_lib.effective_pipeline_depth(runner, 8) == 2
+    assert tuner_lib.effective_pipeline_depth(runner, 2) == 2
+    assert tuner_lib.effective_pipeline_depth(runner, 1) == 1
 
 
 def test_effective_depth_kept_when_hint_is_absent():
@@ -199,167 +198,56 @@ def test_effective_depth_one_for_instantaneous_runner():
 
 
 def test_tune_reports_clamped_depth_and_trace():
-    farm = make_farm(1, delay_s=0.001)
-    try:
-        res = tune(WL_B, V5E, farm, trials=4, seed=0, pipeline_depth=4)
-        assert res.pipeline_depth == 2  # max_inflight 1 -> clamp to 2
-        assert res.depth_trace[0] == (0, 2)  # fixed depth: single entry
-        assert len(res.depth_trace) == 1
-    finally:
-        farm.close()
+    res = tune(W.vmacc(8, 128), INTERPRET, InterpretRunner(INTERPRET,
+                                                           repeats=1),
+               trials=4, seed=0, pipeline_depth=4)
+    assert res.pipeline_depth == 2  # max_inflight 1 -> clamp to 2
+    assert res.depth_trace[0] == (0, 2)  # fixed depth: single entry
+    assert len(res.depth_trace) == 1
     sync = tune(WL_B, V5E, AnalyticRunner(V5E), trials=4, seed=0,
                 pipeline_depth=4)
     assert sync.pipeline_depth == 1 and sync.depth_trace == [(0, 1)]
 
 
 def test_adaptive_tune_records_depth_growth():
-    farm = make_farm(4, delay_s=[0.01, 0.02, 0.03, 0.04])
-    try:
-        res = tune(W.matmul(256, 512, 512, "bfloat16"), V5E, farm,
-                   trials=16, seed=0, batch=2, pipeline_depth=2,
-                   adaptive_depth=True, max_depth=4)
-        assert max(d for _, d in res.depth_trace) > 2
-        assert res.depth_trace[0] == (0, 2)
-    finally:
-        farm.close()
-
-
-# ---------------------------------------------------------------- priority ----
-
-def test_priority_batch_preempts_queued_backlog():
-    backlog_pop = _schedules(WL_A, 6)
-    hi_pop = _schedules(WL_A, 1, seed=1)
-    farm = make_farm(1, delay_s=0.03)
-    try:
-        backlog = farm.submit_batch(WL_A, backlog_pop, priority=0)
-        hi = farm.submit_batch(WL_A, hi_pop, priority=5)
-        hi_lats = hi.result()
-        assert not backlog.done()  # jumped ahead of >= 4 queued candidates
-        backlog_lats = backlog.result()
-        assert farm.preemptions >= 1
-        assert farm.farm_summary()["preemptions"] == farm.preemptions
-    finally:
-        farm.close()
-    # priorities change completion order, never results
-    ref = AnalyticRunner(V5E)
-    assert hi_lats == ref.run_batch(WL_A, hi_pop)
-    assert backlog_lats == ref.run_batch(WL_A, backlog_pop)
-
-
-def test_equal_priority_dispatch_is_plain_fifo():
-    farm = make_farm(1, delay_s=0.005)
-    try:
-        t1 = farm.submit_batch(WL_A, _schedules(WL_A, 3), priority=2)
-        t2 = farm.submit_batch(WL_A, _schedules(WL_A, 3, seed=1), priority=2)
-        t1.result(), t2.result()
-        assert farm.preemptions == 0  # equal classes: nothing ever jumped
-    finally:
-        farm.close()
-
-
-def test_aging_credit_bounds_starvation():
-    """_take_shard_locked: a long-bypassed low-priority candidate's
-    effective class rises by one per ``aging_every`` bypasses until it beats
-    fresher high-priority work — starvation is bounded, not possible."""
-    farm = make_farm(1, aging_every=2)
-    try:
-        lo = _WorkItem(None, 0, WL_A, None, priority=0, bypass=6)
-        hi = _WorkItem(None, 0, WL_A, None, priority=2, bypass=0)
-        with farm._mu:
-            farm._work.clear()
-            farm._work.extend([lo, hi])
-            taken = farm._take_shard_locked(1)
-        assert taken[0] is lo  # 0 + 6 // 2 = 3 beats 2
-        # and a jumped candidate earns its credit on the way
-        fresh_lo = _WorkItem(None, 0, WL_A, None, priority=0, bypass=0)
-        hi2 = _WorkItem(None, 0, WL_A, None, priority=2, bypass=0)
-        with farm._mu:
-            farm._work.clear()
-            farm._work.extend([fresh_lo, hi2])
-            taken = farm._take_shard_locked(1)
-        assert taken[0] is hi2 and fresh_lo.bypass == 1
-        assert farm.preemptions >= 1
-    finally:
-        farm.close()
-
-
-@settings(max_examples=8, deadline=None)
-@given(data=st.data())
-def test_property_priorities_never_change_results(data):
-    """Random farm shapes, die faults, and per-driver priorities: every
-    driver's history is bit-identical to the all-priority-0 run — priority
-    affects completion order only."""
-    n = data.draw(st.integers(min_value=2, max_value=4), label="boards")
-    delays = data.draw(st.lists(
-        st.sampled_from([0.0, 0.001, 0.003, 0.005]),
-        min_size=n, max_size=n), label="delays")
-    seed = data.draw(st.integers(min_value=0, max_value=5), label="seed")
-    priorities = data.draw(st.lists(
-        st.integers(min_value=0, max_value=3), min_size=3, max_size=3),
-        label="priorities")
-    faulty = data.draw(st.integers(min_value=-1, max_value=n - 1),
-                       label="faulty_board")
-    faults, respawns = {}, {}
-    if faulty >= 0:
-        faults[faulty] = [die_fault(batch=data.draw(
-            st.integers(min_value=0, max_value=2), label="die_batch"))]
-        respawns[faulty] = 1
-
-    def run(prios):
-        farm = make_farm(n, delay_s=delays, faults=dict(faults),
-                         respawns=dict(respawns), straggler_timeout_s=10.0)
-        try:
-            drivers = [
-                tuner_lib.TuneDriver(wl, V5E, farm, trials=6, seed=seed + i,
-                                     batch=3, priority=prios[i])
-                for i, wl in enumerate((WL_A, WL_B, WL_C))]
-            tuner_lib.run_scheduled(drivers, farm, depth=1)
-            return drivers
-        finally:
-            farm.close()
-
-    for a, b in zip(run([0, 0, 0]), run(priorities)):
-        assert a.history == b.history
-        assert a.best_schedule == b.best_schedule
+    """A search driven with the depth policy records every depth it ran
+    at. The target sits above any busy fraction, so the policy grows the
+    depth whenever its cap (the queue's capacity plus one) allows."""
+    driver = tuner_lib.TuneDriver(W.matmul(256, 512, 512, "bfloat16"), V5E,
+                                  SlowAnalytic(V5E, 0.002), trials=16,
+                                  seed=0, batch=2)
+    policy = AdaptiveDepthPolicy(1, max_depth=4, target_utilization=1.01,
+                                 cooldown=1)
+    tuner_lib.run_scheduled([driver], driver.runner, 1, depth_policy=policy)
+    res = driver.finish(pipeline_depth=1)
+    assert res.depth_trace[0] == (0, 1)
+    assert max(d for _, d in res.depth_trace) == 2
+    assert res.trials == 16
 
 
 @settings(max_examples=8, deadline=None)
 @given(data=st.data())
 def test_property_adaptation_off_replays_plain_scheduler(data):
-    """Random board counts, latency scripts, and die faults: run_scheduled
-    with an explicit ``depth_policy=None`` + default priorities is
-    bit-identical to the pre-adaptive executor across queue modes — the
-    adaptation layer is provably inert when off."""
-    n = data.draw(st.integers(min_value=2, max_value=4), label="boards")
-    delays = data.draw(st.lists(
-        st.sampled_from([0.0, 0.001, 0.003, 0.005]),
-        min_size=n, max_size=n), label="delays")
+    """Random measurement delays, seeds and depths: run_scheduled with an
+    explicit ``depth_policy=None`` and no reconcile hook is bit-identical
+    to the plain executor on an instantaneous runner — the adaptation
+    layer is inert when off, and timing never leaks into a history."""
+    delay = data.draw(st.sampled_from([0.0, 0.001, 0.003]), label="delay")
     seed = data.draw(st.integers(min_value=0, max_value=5), label="seed")
     depth = data.draw(st.integers(min_value=1, max_value=2), label="depth")
-    faulty = data.draw(st.integers(min_value=-1, max_value=n - 1),
-                       label="faulty_board")
-    faults, respawns = {}, {}
-    if faulty >= 0:
-        faults[faulty] = [die_fault(batch=data.draw(
-            st.integers(min_value=0, max_value=2), label="die_batch"))]
-        respawns[faulty] = 1
 
-    def run(multi_queue):
-        farm = make_farm(n, delay_s=delays, faults=dict(faults),
-                         respawns=dict(respawns), straggler_timeout_s=10.0)
-        try:
-            drivers = [
-                tuner_lib.TuneDriver(wl, V5E, farm, trials=6, seed=seed + i,
-                                     batch=3)
-                for i, wl in enumerate((WL_A, WL_B, WL_C))]
-            tuner_lib.run_scheduled(drivers, farm, depth,
-                                    multi_queue=multi_queue,
-                                    depth_policy=None, on_reconcile=None)
-            return drivers
-        finally:
-            farm.close()
+    def run(runner, **kw):
+        drivers = [
+            tuner_lib.TuneDriver(wl, V5E, runner, trials=6, seed=seed + i,
+                                 batch=3)
+            for i, wl in enumerate((WL_A, WL_B, WL_C))]
+        tuner_lib.run_scheduled(drivers, runner, depth, **kw)
+        return drivers
 
-    for a, b in zip(run(False), run(True)):
+    plain = run(SlowAnalytic(V5E, 0.0))
+    off = run(SlowAnalytic(V5E, delay), depth_policy=None,
+              on_reconcile=None)
+    for a, b in zip(plain, off):
         assert a.history == b.history
         assert a.best_schedule == b.best_schedule
 
@@ -522,18 +410,15 @@ def test_entropy_session_spends_fewer_trials_at_equal_or_better_best():
 
 def test_adaptive_session_summary_surfaces_adaptation():
     ops = [(1, WL_A), (1, WL_B)]
-    farm = make_farm(2, delay_s=[0.002, 0.006])
-    try:
-        res = TuningSession(V5E, farm, database=TuningDatabase(), batch=2,
-                            adaptive_depth=True, max_depth=3,
-                            depth_window_s=0.5).tune_model(
-            ops, total_trials=12, seed=0)
-        assert res.adaptive_depth
-        summary = res.summary()
-        assert summary["adaptive_depth"] is True
-        assert "preemptions" in summary
-    finally:
-        farm.close()
+    res = TuningSession(V5E, SlowAnalytic(V5E, 0.002),
+                        database=TuningDatabase(), batch=2,
+                        adaptive_depth=True, max_depth=3,
+                        depth_window_s=0.5).tune_model(
+        ops, total_trials=12, seed=0)
+    assert res.adaptive_depth
+    summary = res.summary()
+    assert summary["adaptive_depth"] is True
+    assert summary["stop_policy"] == "none"
 
 
 def test_serial_session_reports_adaptation_off():

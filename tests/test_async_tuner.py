@@ -1,15 +1,19 @@
 """Pipelined (async) tuner loop: bit-identical degradation to the
 synchronous path, timing-independent deterministic replay while
-speculating, and real measure/search overlap."""
+speculating — for every kernel family and depth — real measure/search
+overlap, and runner errors surfacing through every measurement path."""
 
 import math
+import threading
 
 import pytest
 
 from repro.core import (AnalyticRunner, InterpretRunner, TuningDatabase,
-                        INTERPRET, V5E, fixed_library_schedule, tune)
+                        TuningSession, INTERPRET, V5E,
+                        fixed_library_schedule, tune)
 from repro.core import workload as W
 
+from _test_runners import FailingAnalytic
 from _test_runners import SlowAnalytic as _SlowAnalytic
 
 
@@ -84,6 +88,93 @@ def test_warm_start_measured_first_in_pipelined_mode():
     assert res.warm_started == 1
     assert res.trials == 8
     assert res.history[0][0] == seed_schedule  # submission order preserved
+
+
+# ------------------------------------------- timing-independent replay ----
+
+FAMILY_WORKLOADS = [
+    W.matmul(256, 512, 512, "bfloat16"),
+    W.qmatmul(64, 512, 256),
+    W.gemv(2048, 4096, "bfloat16"),
+    W.vmacc(256, 512),
+    W.attention(1, 4, 2, 256, 256, 64, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("wl", FAMILY_WORKLOADS, ids=lambda wl: wl.op)
+def test_trajectory_identical_whatever_the_measurement_delay(wl, depth):
+    """At equal effective depth a fixed seed's history is bit-identical
+    whether measurement is instantaneous or takes jittered milliseconds:
+    reconcile points are algorithmic, never timed."""
+    runs = [tune(wl, V5E, _SlowAnalytic(V5E, delay, jitter, seed=depth),
+                 trials=12, seed=5, batch=3, pipeline_depth=depth)
+            for delay, jitter in ((0.0, 0.0), (0.001, 0.004))]
+    assert [r.pipeline_depth for r in runs] == [depth, depth]
+    assert runs[0].history == runs[1].history
+    assert runs[0].best_schedule == runs[1].best_schedule
+    assert runs[0].trials == runs[1].trials > 0
+
+
+# ------------------------------------------------ a runner that raises ----
+
+WL_FAIL = W.matmul(256, 512, 512, "bfloat16")
+
+
+def _error_within(fn, timeout_s=30.0):
+    """Run ``fn`` on a thread; fail the test if it has not returned or
+    raised within ``timeout_s`` (a deadlock), else return what it raised."""
+    box = {}
+
+    def target():
+        try:
+            fn()
+        except BaseException as e:  # the error under test
+            box["error"] = e
+
+    thread = threading.Thread(target=target, daemon=True)
+    thread.start()
+    thread.join(timeout_s)
+    assert not thread.is_alive(), "a failing runner deadlocked the tuner"
+    return box.get("error")
+
+
+def _session(runner, ops, interleave):
+    return TuningSession(V5E, runner, database=TuningDatabase(), batch=3,
+                         interleave=interleave).tune_model(
+        ops, total_trials=12, seed=0)
+
+
+@pytest.mark.parametrize("case", ["tune-depth1", "tune-depth2",
+                                  "tune-depth3", "interleaved-session",
+                                  "baselines-wave"])
+def test_runner_error_surfaces_without_deadlock(case):
+    """A runner that raises mid-search: the error reaches the caller on
+    the synchronous path, through the scheduler's tickets when pipelined or
+    interleaved, and from the baselines wave; no measurement thread is
+    left running."""
+    if case.startswith("tune"):
+        depth = int(case[-1])
+        runner = FailingAnalytic(V5E, fail_at=2)
+        error = _error_within(lambda: tune(
+            WL_FAIL, V5E, runner, trials=12, seed=0, batch=3,
+            pipeline_depth=depth))
+    elif case == "interleaved-session":
+        runner = FailingAnalytic(V5E, fail_at=3)
+        error = _error_within(lambda: _session(
+            runner, [(1, WL_FAIL), (1, W.vmacc(256, 512))], True))
+    else:
+        # one workload: searched synchronously, then its library baseline
+        # is measured last, as the session's only scheduled batch
+        counting = FailingAnalytic(V5E, fail_at=0)
+        _session(counting, [(1, WL_FAIL)], None)
+        runner = FailingAnalytic(V5E, fail_at=counting.batches)
+        error = _error_within(lambda: _session(runner, [(1, WL_FAIL)],
+                                               None))
+    assert isinstance(error, RuntimeError)
+    assert f"measurement failed on batch {runner.fail_at}" in str(error)
+    assert not any(t.name == "measure-serial" and t.is_alive()
+                   for t in threading.enumerate())
 
 
 @pytest.mark.slow

@@ -1,13 +1,12 @@
 """Content-addressed build/measurement dedup suite.
 
 Covers ``core/build_cache.py`` (LRU semantics under capacity pressure,
-counter accuracy, cross-workload key isolation), the ``dedup`` knobs on
-:class:`AnalyticRunner` and :class:`BoardFarm` (fan-out alignment, survival
-of requeue-from-dead, hypothesis-tested inertness on the deterministic
-analytic runner), the database's cross-session measured-latency memo plus
-the tuner's ``reuse_measured`` consumption of it, and a ``--runslow``
-interpret-path case asserting a second identical batch performs zero Pallas
-builds.
+counter accuracy, cross-workload key isolation), the ``dedup`` knob on
+:class:`AnalyticRunner` (fan-out alignment, hypothesis-tested inertness on
+the deterministic analytic runner), the database's cross-session
+measured-latency memo plus the tuner's ``reuse_measured`` consumption of it,
+and a ``--runslow`` interpret-path case asserting a second identical batch
+performs zero Pallas builds.
 """
 
 import math
@@ -27,8 +26,6 @@ from repro.core import (AnalyticRunner, BuildCache, InterpretRunner,
                         space_for, tune)
 from repro.core import workload as W
 from repro.core.build_cache import stats_delta
-
-from _sim_boards import RecordingMeasure, die_fault, make_farm
 
 
 def _unique_samples(wl, hw, n, seed=0):
@@ -153,7 +150,7 @@ def test_concretize_memo_hits_and_identity():
     assert stats["misses"] == 1 and stats["hits"] >= 1
 
 
-# ------------------------------------------------- runner / farm dedup ----
+# ------------------------------------------------------- runner dedup ----
 
 def test_analytic_dedup_fanout_alignment():
     a, b, c = POP[:3]
@@ -173,42 +170,6 @@ def test_analytic_dedup_inert_property(picks):
     on = AnalyticRunner(V5E, dedup=True).run_batch(WL, schedules)
     off = AnalyticRunner(V5E).run_batch(WL, schedules)
     assert on == off
-
-
-def test_farm_dedup_fanout_survives_requeue_from_dead():
-    """A dedup'd batch on a farm where the board holding a representative
-    dies: the item requeues to a live board, and every follower position
-    still settles with the representative's latency — results stay
-    bit-identical to a plain single-board run of the full batch."""
-    a, b, c = POP[:3]
-    schedules = [a, b, a, c, b]
-    reference = AnalyticRunner(V5E).run_batch(WL, schedules)
-
-    meas = RecordingMeasure()
-    farm = make_farm(2, delay_s=[0.0, 0.002], faults={0: [die_fault(0)]},
-                     measure_fn=meas, dedup=True)
-    got = farm.run_batch(WL, schedules)
-    assert got == reference
-    assert got[0] == got[2] and got[1] == got[4]
-    # exactly-once: three distinct signatures, three measurements total,
-    # even though five candidates were submitted and one board died
-    assert sum(meas.calls.values()) == 3
-    assert set(meas.calls.values()) == {1}
-    summary = farm.farm_summary()
-    assert summary["dedup_reused"] == 2
-    assert summary["requeues"] >= 1
-    assert "build_cache" in summary
-
-
-def test_farm_dedup_off_by_default_measures_every_position():
-    a, b, c = POP[:3]
-    schedules = [a, b, a, c, b]
-    meas = RecordingMeasure()
-    farm = make_farm(2, measure_fn=meas)
-    got = farm.run_batch(WL, schedules)
-    assert got == AnalyticRunner(V5E).run_batch(WL, schedules)
-    assert sum(meas.calls.values()) == 5  # no dedup: one measure per slot
-    assert farm.farm_summary()["dedup_reused"] == 0
 
 
 # ------------------------------------- cross-session measured-lat memo ----

@@ -1,14 +1,15 @@
 """Database bugfixes (env re-resolution, hot-swap reload, atomic save,
-strict JSON, non-finite latency rejection) and the serving-path dispatch
-cache with invalidation."""
+strict JSON, non-finite latency rejection), the serving-path dispatch
+cache with invalidation, and the cross-hardware transfer smoke."""
 
 import json
 import os
 
 import pytest
 
-from repro.core import (Schedule, TuningDatabase, V5E, best_schedule,
-                        fixed_library_schedule)
+from repro.core import (AnalyticRunner, Schedule, TuningDatabase, V5E,
+                        V5E_VMEM32, best_schedule, fixed_library_schedule,
+                        tune)
 from repro.core import workload as W
 from repro.core.database import global_database, reset_global_database
 
@@ -243,3 +244,34 @@ def test_fixed_library_schedule_is_memoized():
     from repro.core import V5E_MXU256
     assert fixed_library_schedule(wl, V5E_MXU256) is not \
         fixed_library_schedule(wl, V5E)
+
+
+# ---------------------------------------------------------- transfer ----
+
+def test_transfer_warm_start_not_worse_at_equal_budget():
+    """ROADMAP transfer-study smoke: seeding a search from a near-miss
+    record (same shape, different hardware config) at equal trial budget is
+    never worse than the cold search on at least one shape pair."""
+    pairs = [
+        # same shape carried across the hardware sweep (paper Fig. 4)
+        (W.matmul(512, 512, 512, "bfloat16"), V5E,
+         W.matmul(512, 512, 512, "bfloat16"), V5E_VMEM32),
+        # near-miss shape on the same hardware
+        (W.matmul(512, 512, 512, "bfloat16"), V5E,
+         W.matmul(512, 512, 640, "bfloat16"), V5E),
+    ]
+    wins = 0
+    for prior_wl, prior_hw, target_wl, target_hw in pairs:
+        db = TuningDatabase()
+        tune(prior_wl, prior_hw, AnalyticRunner(prior_hw), trials=24, seed=0,
+             database=db)
+        seeds = db.transfer_candidates(target_wl, target_hw.name, limit=4)
+        assert seeds  # same op family: the query must surface candidates
+        runner = AnalyticRunner(target_hw)
+        warm = tune(target_wl, target_hw, runner, trials=12, seed=1,
+                    warm_start=seeds)
+        cold = tune(target_wl, target_hw, runner, trials=12, seed=1)
+        assert warm.trials == cold.trials == 12  # equal budget
+        if warm.warm_started >= 1 and warm.best_latency <= cold.best_latency:
+            wins += 1
+    assert wins >= 1

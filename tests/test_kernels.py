@@ -238,8 +238,8 @@ def test_xla_baseline_matches_reference(op):
 
 
 # ------------------------------------------------------------- chip path ----
-# DeviceRunner and the child-process runners decide from the backend and the
-# device JAX reports; the tests steer those two seams of core/runner.py.
+# DeviceRunner decides from the device JAX reports; the tests steer that
+# seam of core/runner.py.
 
 def _fake_v5e(monkeypatch):
     from repro.core import runner as runner_lib
@@ -265,17 +265,6 @@ def test_unknown_device_kind_raises(monkeypatch):
         platform="tpu", device_kind="TPU v99"))
     with pytest.raises(ValueError, match="no hardware configuration"):
         DeviceRunner()
-
-
-def test_subprocess_measurement_refused_under_tpu_backend(monkeypatch):
-    from repro.core import LocalBoard, SubprocessRunner
-    from repro.core import runner as runner_lib
-
-    monkeypatch.setattr(runner_lib, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="one process holds the chip"):
-        SubprocessRunner(HW)
-    with pytest.raises(RuntimeError, match="one process holds the chip"):
-        LocalBoard("board0", HW)
 
 
 def test_device_runner_checks_counts_and_never_times_a_wrong_kernel(

@@ -1,31 +1,19 @@
-"""Multi-queue measurement scheduler suite.
+"""Measurement scheduler suite.
 
 Covers the MeasureScheduler/SerialMeasureQueue subsystem (per-key FIFO,
-completion-aware collection, span-accurate overlap accounting), the
-determinism contract — multi-queue interleaved sessions replay bit-identical
-to the single-FIFO path for a fixed seed, including under fault injection —
-the farm's cross-batch shards (a board dying while holding candidates from
-two different batches), batched session baselines, and the TuneDriver
-wall-time attribution fix (first propose -> last reconcile)."""
+span-accurate overlap accounting, the capacity hint), batched session
+baselines, and the TuneDriver wall-time attribution fix (first propose ->
+last reconcile)."""
 
 import math
 import time
 
-import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dev dep: property tests skip, the rest run
-    from _hypothesis_stub import given, settings, st
-
-from repro.core import (AnalyticRunner, MeasureScheduler, MeasureTicket,
-                        SerialMeasureQueue, TuningDatabase, TuningSession,
-                        V5E, tune)
+from repro.core import (INTERPRET, AnalyticRunner, InterpretRunner,
+                        MeasureScheduler, SerialMeasureQueue, TuningDatabase,
+                        TuningSession, V5E)
 from repro.core import tuner as tuner_lib
 from repro.core import workload as W
-from repro.core.runner import INVALID
 
-from _sim_boards import die_fault, make_farm
 from _test_runners import SlowAnalytic
 
 
@@ -88,32 +76,6 @@ def test_scheduler_preserves_per_key_fifo_order():
         sched.close()
 
 
-def test_scheduler_collects_completed_ticket_before_blocked_head():
-    """Completion-aware collection: when another key's batch already
-    finished, it is handed back instead of blocking on the globally oldest
-    in-flight ticket — the property that keeps drivers topped up (and
-    boards busy) on a multi-queue backend."""
-    farm = make_farm(2, delay_s=[0.3, 0.0])
-    try:
-        slow = _schedules(WL_A, 1)
-        fast = _schedules(WL_A, 1, seed=1)
-        sched = MeasureScheduler(farm)
-        assert sched.multi_queue  # native farm submission protocol
-        sched.submit("slow", WL_A, slow)
-        time.sleep(0.05)  # the slow board holds the first batch
-        sched.submit("fast", WL_A, fast)
-        t0 = time.monotonic()
-        key, _, _, _, _ = sched.collect_next()
-        fast_wait = time.monotonic() - t0
-        assert key == "fast"  # completed ticket wins over the blocked head
-        assert fast_wait < 0.25  # did not wait out the slow board
-        key2, _, _, _, _ = sched.collect_next()
-        assert key2 == "slow"
-    finally:
-        sched.close()
-        farm.close()
-
-
 def test_scheduler_overlap_is_span_accurate():
     """overlap + waited-measure <= measuring span (interval arithmetic,
     not summed totals), and a fully-waited depth-1 submit shows ~0 overlap."""
@@ -135,184 +97,48 @@ def test_scheduler_overlap_is_span_accurate():
 
 
 def test_max_inflight_hints():
-    assert MeasureScheduler(AnalyticRunner(V5E)).max_inflight == 1
-    farm = make_farm(3)
-    assert MeasureScheduler(farm).max_inflight == 3
-    # forcing single-FIFO wraps even an async-capable backend
-    forced = MeasureScheduler(farm, multi_queue=False)
-    assert not forced.multi_queue and forced.max_inflight == 1
-    forced.close()
-    farm.close()
-
-
-# ------------------------------------------- multi-queue == single-FIFO ----
-
-def _run_drivers(runner, seed, multi_queue, depth=1):
-    drivers = [
-        tuner_lib.TuneDriver(wl, V5E, runner, trials=6, seed=seed + i,
-                             batch=3)
-        for i, wl in enumerate((WL_A, WL_B, WL_C))]
-    tuner_lib.run_scheduled(drivers, runner, depth, multi_queue=multi_queue)
-    return drivers
-
-
-def test_multi_queue_histories_bit_identical_to_single_fifo():
-    """Acceptance: per-driver histories are bit-identical between the
-    multi-queue scheduler (batches from all drivers in flight on the farm
-    at once) and the single-FIFO measurement thread."""
-    fifo = _run_drivers(make_farm(3, delay_s=[0.0, 0.004, 0.002]), 7, False)
-    multi = _run_drivers(make_farm(3, delay_s=[0.0, 0.004, 0.002]), 7, True)
-    for a, b in zip(fifo, multi):
-        assert a.history == b.history
-        assert a.best_schedule == b.best_schedule
-        assert a.best_latency == b.best_latency
-
-
-@settings(max_examples=10, deadline=None)
-@given(data=st.data())
-def test_property_multi_queue_sessions_replay_single_fifo(data):
-    """Random board counts, latency scripts, fault scripts, and depths:
-    multi-queue interleaved tuning replays the single-FIFO path
-    bit-identically for a fixed seed. (Die faults preserve results by
-    requeue; garbage faults are excluded — they map whichever candidates
-    the faulty *shard* held to INVALID, which varies with shard composition
-    by design, not by scheduling.)"""
-    n = data.draw(st.integers(min_value=2, max_value=4), label="boards")
-    delays = data.draw(st.lists(
-        st.sampled_from([0.0, 0.001, 0.003, 0.005]),
-        min_size=n, max_size=n), label="delays")
-    seed = data.draw(st.integers(min_value=0, max_value=5), label="seed")
-    depth = data.draw(st.integers(min_value=1, max_value=2), label="depth")
-    faulty = data.draw(st.integers(min_value=-1, max_value=n - 1),
-                       label="faulty_board")
-    faults = {}
-    respawns = {}
-    if faulty >= 0:  # one board dies mid-run and may come back
-        faults[faulty] = [die_fault(batch=data.draw(
-            st.integers(min_value=0, max_value=2), label="die_batch"))]
-        respawns[faulty] = 1
-
-    def run(multi_queue):
-        farm = make_farm(n, delay_s=delays, faults=dict(faults),
-                         respawns=dict(respawns), straggler_timeout_s=10.0)
-        try:
-            return _run_drivers(farm, seed, multi_queue, depth=depth)
-        finally:
-            farm.close()
-
-    for a, b in zip(run(False), run(True)):
-        assert a.history == b.history
-        assert a.best_schedule == b.best_schedule
-
-
-def test_multi_queue_session_results_match_single_fifo_end_to_end():
-    """Session layer: same reports (schedules, latencies, trials, fixed
-    baselines) whether the farm is driven multi-queue or single-FIFO."""
-    ops = [(1, WL_A), (2, WL_B), (1, WL_C)]
-    results = {}
-    for mq in (False, True):
-        farm = make_farm(3, delay_s=[0.0, 0.002, 0.001])
-        results[mq] = TuningSession(
-            V5E, farm, database=TuningDatabase(),
-            multi_queue=mq).tune_model(ops, total_trials=18, seed=0)
-        farm.close()
-    assert results[True].multi_queue and not results[False].multi_queue
-    for a, b in zip(results[False].reports, results[True].reports):
-        assert a.best_schedule == b.best_schedule
-        assert a.best_latency == b.best_latency
-        assert a.trials == b.trials
-        assert a.fixed_latency == b.fixed_latency
-
-
-# ------------------------------------------------- cross-batch fault case ----
-
-def test_board_dies_holding_shards_from_two_batches():
-    """A capacity-4 board pulls a shard spanning two in-flight batches
-    (cross-batch work stealing), then dies holding it: candidates from
-    *both* tickets requeue, the respawned board finishes them, and both
-    tickets complete with reference latencies."""
-    batch_a = _schedules(WL_A, 6)
-    batch_b = _schedules(WL_A, 2, seed=1)
-    reference = AnalyticRunner(V5E).run_batch(WL_A, batch_a + batch_b)
-    farm = make_farm(1, capacity=4, delay_s=0.05,
-                     faults={0: [die_fault(batch=1)]}, respawns={0: 1},
-                     straggler_timeout_s=10.0)
-    try:
-        ta = farm.submit_batch(WL_A, batch_a)
-        tb = farm.submit_batch(WL_A, batch_b)  # queued behind A's 6
-        # shard 0 = A[0:4]; shard 1 = A[4:6] + B[0:2] -> spans both batches
-        # and dies; all four candidates requeue onto the respawned board
-        assert ta.result() == reference[:6]
-        assert tb.result() == reference[6:]
-        board = farm.boards[0]
-        assert board.stats.deaths == 1 and board.stats.respawns == 1
-        assert farm.requeues == 4  # two candidates of each batch
-        assert farm.retry_exhausted == 0
-        # the dying shard genuinely mixed both batches: each ticket has
-        # at least one requeued candidate
-        assert ta.done() and tb.done()
-    finally:
-        farm.close()
-
-
-def test_farm_ticket_fails_with_farm_dead_across_batches():
-    """All boards dead with two batches pending: every ticket fails with
-    FarmDead promptly — the scheduler loop can never wedge on a batch that
-    will not land."""
-    from repro.core import FarmDead
-
-    farm = make_farm(1, capacity=2, faults={0: [die_fault(batch=0)]},
-                     straggler_timeout_s=10.0)
-    try:
-        t0 = time.monotonic()
-        ta = farm.submit_batch(WL_A, _schedules(WL_A, 3))
-        tb = farm.submit_batch(WL_A, _schedules(WL_A, 2, seed=1))
-        with pytest.raises(FarmDead):
-            ta.result(timeout=10.0)
-        with pytest.raises(FarmDead):
-            tb.result(timeout=10.0)
-        assert time.monotonic() - t0 < 5.0
-    finally:
-        farm.close()
+    """The runners' capacity hints, and the scheduler's own: one
+    measurement thread, so capacity 1 whatever the runner declares."""
+    assert AnalyticRunner(V5E).max_inflight == 1
+    assert InterpretRunner(INTERPRET).max_inflight == 1
+    assert not hasattr(SlowAnalytic(V5E), "max_inflight")
+    for runner in (AnalyticRunner(V5E), InterpretRunner(INTERPRET),
+                   SlowAnalytic(V5E)):
+        sched = MeasureScheduler(runner)
+        assert sched.max_inflight == 1
+        sched.close()
 
 
 # ------------------------------------------------------ batched baselines ----
 
-class _RecordingAsyncRunner:
-    """Async-protocol runner that records every submission and completes
-    tickets instantly with analytic latencies."""
+class _RecordingRunner:
+    """Overlap-capable runner that records every batch it measures, with
+    analytic latencies."""
 
     overlap_capable = True
-    max_inflight = 4
-    name = "recording-async"
+    name = "recording"
 
     def __init__(self, hw):
         self.hw = hw
         self._inner = AnalyticRunner(hw)
-        self.submissions: list[tuple[str, int]] = []
+        self.batches: list[tuple[str, int]] = []
 
     def run(self, workload, schedule):
         return self._inner.run(workload, schedule)
 
     def run_batch(self, workload, schedules):
+        self.batches.append((workload.key(), len(schedules)))
         return self._inner.run_batch(workload, schedules)
-
-    def submit_batch(self, workload, schedules):
-        self.submissions.append((workload.key(), len(schedules)))
-        ticket = MeasureTicket(workload, schedules)
-        ticket._complete(self._inner.run_batch(workload, schedules))
-        return ticket
 
 
 def test_session_baselines_submitted_as_one_wave():
-    """The fixed-library baselines are all submitted before any is awaited
-    (one scheduled wave per session, not N serial dispatch round trips),
-    with per-workload attribution preserved."""
-    runner = _RecordingAsyncRunner(V5E)
+    """The fixed-library baselines are measured as one wave after the
+    search, in workload order, with per-workload attribution preserved."""
+    runner = _RecordingRunner(V5E)
     ops = [(1, WL_A), (2, WL_B), (1, WL_C)]
     res = TuningSession(V5E, runner, database=TuningDatabase()).tune_model(
         ops, total_trials=12, seed=0)
-    tail = runner.submissions[-3:]  # the baseline wave comes last
+    tail = runner.batches[-3:]  # the baseline wave comes last
     assert [n for _, n in tail] == [1, 1, 1]
     assert [k for k, _ in tail] == [WL_A.key(), WL_B.key(), WL_C.key()]
     for rep in res.reports:
@@ -322,18 +148,6 @@ def test_session_baselines_submitted_as_one_wave():
             ).fixed_library_schedule(rep.workload, V5E))
         assert rep.fixed_latency == runner_fixed or not math.isfinite(
             runner_fixed)
-
-
-def test_farm_session_baselines_counted_on_boards():
-    """Baselines ride through the farm like any batch: board completions
-    cover trials + baselines, exactly as before the batching change."""
-    ops = [(1, WL_A), (1, WL_B)]
-    farm = make_farm(2, delay_s=0.001)
-    res = TuningSession(V5E, farm, database=TuningDatabase()).tune_model(
-        ops, total_trials=8, seed=0)
-    completed = sum(b.stats.completed for b in farm.boards)
-    assert completed >= res.total_trials + len(res.reports)
-    farm.close()
 
 
 # -------------------------------------------------- wall-time attribution ----
@@ -375,29 +189,14 @@ def test_never_driven_driver_reports_zero_wall_time():
     assert driver.finish().wall_time_s == 0.0
 
 
-# ----------------------------------------------------------- tune() paths ----
-
-def test_pipelined_farm_tune_still_matches_across_queue_modes():
-    """tune(pipeline_depth=2) over a farm: the native multi-queue backend
-    reproduces the single-FIFO trajectory (single driver: global FIFO and
-    per-driver FIFO coincide)."""
-    wl = W.matmul(256, 512, 512, "bfloat16")
-    multi = tune(wl, V5E, make_farm(3, delay_s=[0.002, 0.0, 0.001]),
-                 trials=10, seed=3, pipeline_depth=2)
-    single = tune(wl, V5E, make_farm(1), trials=10, seed=3,
-                  pipeline_depth=2)
-    assert multi.history == single.history
-    assert multi.best_schedule == single.best_schedule
-    assert multi.overlap_s <= multi.measure_time_s + 1e-9
-
+# ------------------------------------------------------- session summary ----
 
 def test_session_summary_carries_span_and_queue_mode():
     ops = [(1, WL_A), (1, WL_B)]
-    farm = make_farm(2, delay_s=0.002)
-    res = TuningSession(V5E, farm, database=TuningDatabase()).tune_model(
+    res = TuningSession(V5E, SlowAnalytic(V5E, 0.002),
+                        database=TuningDatabase()).tune_model(
         ops, total_trials=8, seed=0)
     summary = res.summary()
-    assert summary["multi_queue"] is True
+    assert res.interleaved and summary["interleaved"] is True
     assert summary["measure_span_s"] > 0
     assert res.measure_span_s <= res.measure_time_s + 1e-9
-    farm.close()

@@ -1,6 +1,6 @@
 """Static feasibility analyzer: parity with the dynamic postprocessors,
 bit-identical searches when nothing prunes, database quarantine, and
-farm/scheduler refusal of statically-invalid work."""
+scheduler refusal of statically-invalid work."""
 
 import dataclasses
 import json
@@ -18,7 +18,6 @@ from repro.core import workload as W
 from repro.core import space as space_lib
 from repro.core import static_analysis as SA
 from repro.core.database import TuningDatabase
-from repro.core.board_farm import simulated_farm
 from repro.core.hardware import V5E, V5E_MXU256, V5E_VMEM32, V5E_VMEM64
 from repro.core.measure_scheduler import MeasureScheduler
 from repro.core.runner import INVALID, AnalyticRunner
@@ -287,35 +286,10 @@ def test_transfer_distributions_drop_statically_dead_values():
     assert "mnk" in priors["order"]
 
 
-# --------------------------------------------------- farm/scheduler refusal ----
+# ------------------------------------------------------ scheduler refusal ----
 
 def _stale_schedule():
     return Schedule.fixed(variant="mxu_9999", order="mnk")
-
-
-def test_board_farm_refuses_statically_invalid_work():
-    wl = W.matmul(256, 256, 256, "bfloat16")
-    prog = space_lib.space_for(wl, V5E)
-    valid = TraceSampler(0).sample(prog)
-    with simulated_farm(2, V5E) as farm:
-        lats = farm.run_batch(wl, [valid, _stale_schedule()])
-        assert math.isfinite(lats[0])
-        assert lats[1] == INVALID
-        assert farm.static_rejected == 1
-        assert farm.farm_summary()["static_rejected"] == 1
-        # a board never saw the refused candidate
-        dispatched = sum(b.stats.dispatched for b in farm.boards)
-        assert dispatched == 1
-
-
-def test_board_farm_fully_refused_batch_completes_immediately():
-    wl = W.matmul(256, 256, 256, "bfloat16")
-    with simulated_farm(1, V5E) as farm:
-        ticket = farm.submit_batch(wl, [_stale_schedule()] * 3)
-        assert ticket.done()
-        assert ticket.result() == [INVALID] * 3
-        assert farm.static_rejected == 3
-        assert sum(b.stats.dispatched for b in farm.boards) == 0
 
 
 class _RecordingRunner(AnalyticRunner):
@@ -330,10 +304,30 @@ class _RecordingRunner(AnalyticRunner):
         return super().run(workload, schedule)
 
 
-def test_scheduler_screens_serial_backends():
-    wl = W.matmul(256, 256, 256, "bfloat16")
+# One workload of each kernel family: in every family's space an
+# unregistered variant is a statically dead value.
+FAMILIES = pytest.mark.parametrize("wl", [
+    W.matmul(256, 256, 256, "bfloat16"),
+    W.qmatmul(64, 256, 256),
+    W.gemv(256, 512, "bfloat16"),
+    W.vmacc(64, 256),
+    W.attention(1, 2, 1, 128, 128, 64, "bfloat16"),
+], ids=lambda wl: wl.op)
+
+
+def _valid_schedule(wl):
     prog = space_lib.space_for(wl, V5E)
-    valid = TraceSampler(0).sample(prog)
+    sampler = TraceSampler(0)
+    for _ in range(200):
+        s = sampler.sample(prog)
+        if space_lib.concretize(wl, V5E, s).valid:
+            return s
+    raise AssertionError(f"no valid sample for {wl.key()}")
+
+
+@FAMILIES
+def test_scheduler_screens_serial_backends(wl):
+    valid = _valid_schedule(wl)
     runner = _RecordingRunner(V5E)
     with MeasureScheduler(runner) as sched:
         sched.submit(0, wl, [valid, _stale_schedule(), valid])
@@ -345,6 +339,23 @@ def test_scheduler_screens_serial_backends():
     # the backend runner only ever measured the two valid candidates
     assert len(runner.seen) == 2
     assert all(s.get("variant") != "mxu_9999" for s in runner.seen)
+
+
+@FAMILIES
+def test_scheduler_fully_refused_batch_completes_without_measurement(wl):
+    """A batch the analyzer refuses whole never reaches the measurement
+    thread: its ticket is complete at submit, charges no measuring time,
+    and collects as all-INVALID."""
+    runner = _RecordingRunner(V5E)
+    with MeasureScheduler(runner) as sched:
+        ticket = sched.submit(0, wl, [_stale_schedule()] * 3)
+        assert ticket.done()
+        assert ticket.result() == [INVALID] * 3
+        _key, batch, lats, _w, measure_s = sched.collect_next()
+        assert len(batch) == 3 and lats == [INVALID] * 3
+        assert measure_s == 0.0 and sched.measure_span_s() == 0.0
+        assert sched.static_rejected == 3
+    assert runner.seen == []
 
 
 # ----------------------------------------------------------- vmem headroom ----

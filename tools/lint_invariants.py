@@ -2,8 +2,8 @@
 """AST lint forbidding determinism hazards in the search/reconcile core.
 
 The tuning stack's central contract is bit-identical replay: a fixed seed
-must reproduce the same search history regardless of farm shape, runner
-speed, or host entropy (see ``core/tuner.py``). That contract dies quietly
+must reproduce the same search history regardless of measurement time or
+host entropy (see ``core/tuner.py``). That contract dies quietly
 — an unseeded RNG here, a wall-clock-keyed decision there — so this lint
 makes the hazards structural errors in CI instead of flaky-test archaeology:
 
